@@ -173,9 +173,9 @@ def is_compiled_with_xpu() -> bool:
 def is_compiled_with_tpu() -> bool:
     import jax
 
-    from paddle_tpu.device import is_tpu_like
+    from paddle_tpu.device import is_tpu
 
-    return any(is_tpu_like(d) for d in jax.devices())
+    return any(is_tpu(d) for d in jax.devices())
 
 
 def set_default_dtype(d):

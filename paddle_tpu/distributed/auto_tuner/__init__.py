@@ -162,6 +162,8 @@ def subprocess_trial_fn(model: ModelSpec, steps: int = 3,
 
     def run(cfg: TunerConfig) -> float:
         env = dict(os.environ)
+        # trials are CPU-only, so a parent that has initialised JAX (and
+        # holds the chip) can still start them
         env["JAX_PLATFORMS"] = "cpu"
         flags = [f for f in env.get("XLA_FLAGS", "").split()
                  if "host_platform_device_count" not in f]

@@ -107,57 +107,57 @@ def _signature(spec_trees) -> Tuple[str, ...]:
 
 # ---------------------------------------------------------------- chip peaks
 
-# Public per-chip peak specs (TFLOP/s dense bf16/fp32-equivalent, HBM GB/s).
-# The CPU row is a deliberately modest host-class nominal so smoke-bench
-# roofline numbers land in (0, 1] instead of being meaningless; real runs
-# override via BENCH_PEAK_TFLOPS / BENCH_PEAK_MEMBW_GBS.
+# device_kind (exactly as ``jax.devices()[0].device_kind`` reports it) ->
+# (peak dense bf16 TFLOP/s, peak HBM GB/s, source). A row is added when its
+# device_kind string has been SEEN on that chip, never guessed; a device that
+# is not here is an error, not a default.
 _CHIP_TABLE = {
-    "tpu v4": (275.0, 1228.0),
-    "tpu v5 lite": (197.0, 819.0),
-    "tpu v5e": (197.0, 819.0),
-    "tpu v5p": (459.0, 2765.0),
-    "tpu v6 lite": (918.0, 1640.0),
-    "tpu v6e": (918.0, 1640.0),
-    "cpu": (0.25, 25.0),
+    "TPU v5 lite": (197.0, 819.0,
+                    "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                    "bf16, 819 GB/s HBM per chip; device_kind seen on the "
+                    "chip in PR 21"),
 }
 
 
-def chip_specs(device_kind: Optional[str] = None) -> Dict[str, Any]:
-    """Peak FLOPs/bandwidth for the current (or named) chip.
+def chip_specs(device_kind: Optional[str] = None) -> Optional[Dict[str, Any]]:
+    """Peak FLOP/s and HBM bandwidth of the current (or named) chip, from
+    the one table above.
 
-    Resolution order: ``BENCH_PEAK_TFLOPS``/``BENCH_PEAK_MEMBW_GBS`` env
-    overrides > known-chip table match on ``device_kind`` > the v5e
-    default (same default ``tools/chip_ceiling.py`` reports against).
+    ``None`` on the CPU backend: a host has no peaks here, so utilisation
+    gauges are absent on CPU rather than computed. Any other device_kind
+    missing from the table raises ``KeyError``.
     """
     if device_kind is None:
-        try:
-            import jax
+        import jax
 
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = "cpu"
-    kind = str(device_kind).lower()
-    tflops, membw = _CHIP_TABLE.get("tpu v5e")
-    for key, row in _CHIP_TABLE.items():
-        if key in kind or kind in key:
-            tflops, membw = row
-            break
-    tflops = float(os.environ.get("BENCH_PEAK_TFLOPS", tflops))
-    membw = float(os.environ.get("BENCH_PEAK_MEMBW_GBS", membw))
-    return {"device_kind": str(device_kind),
-            "peak_tflops": tflops, "peak_membw_gbs": membw}
+        dev = jax.devices()[0]
+        if dev.platform == "cpu":
+            return None
+        device_kind = dev.device_kind
+    if device_kind not in _CHIP_TABLE:
+        raise KeyError(
+            f"no peak specs for device_kind {device_kind!r}: add a sourced "
+            f"row to program_inventory._CHIP_TABLE (known: "
+            f"{sorted(_CHIP_TABLE)})")
+    tflops, membw, source = _CHIP_TABLE[device_kind]
+    return {"device_kind": device_kind, "peak_tflops": tflops,
+            "peak_membw_gbs": membw, "source": source}
 
 
 def roofline_utilization(flops: float, bytes_accessed: float,
                          step_seconds: float,
-                         specs: Optional[dict] = None) -> Dict[str, Any]:
-    """MFU + bandwidth utilization of one program at a measured step time.
+                         specs: Optional[dict] = None
+                         ) -> Optional[Dict[str, Any]]:
+    """MFU + bandwidth utilization of one program at a measured step time,
+    or ``None`` where the device has no peaks (``chip_specs()`` on CPU).
 
     Raw ratios are reported alongside the clamped ``(0, 1]`` gauges: a
     raw value > 1 means the peak spec is wrong (or the step time was
     under-measured), which is itself a finding worth surfacing.
     """
     specs = specs or chip_specs()
+    if specs is None:
+        return None
     step_seconds = max(float(step_seconds), 1e-12)
     mfu_raw = float(flops) / step_seconds / (specs["peak_tflops"] * 1e12)
     bw_raw = (float(bytes_accessed) / step_seconds

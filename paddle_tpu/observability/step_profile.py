@@ -366,22 +366,25 @@ def attribute_trace(events: Sequence[dict],
         )
 
         step_s = t / execs * 1e-6
-        roof = roofline_utilization(float(fl or 0), float(by), step_s)
         rs = row["region_shares"]
         out["decode_roofline"] = {
             "program": name,
             "step_device_time_s": step_s,
             "flops": fl,
             "bytes_accessed": by,
-            "bandwidth_util": roof["bandwidth_util"],
-            "mfu": roof["mfu"],
-            "chip": roof["chip"],
             "region_bytes_est": {r: int(s * float(by))
                                  for r, s in rs.items()},
-            "bandwidth_util_by_region": {
-                r: round(s * roof["bandwidth_util"], 6)
-                for r, s in rs.items()},
         }
+        roof = roofline_utilization(float(fl or 0), float(by), step_s)
+        if roof is not None:   # None on CPU: no peaks, no utilisation
+            out["decode_roofline"].update({
+                "bandwidth_util": roof["bandwidth_util"],
+                "mfu": roof["mfu"],
+                "chip": roof["chip"],
+                "bandwidth_util_by_region": {
+                    r: round(s * roof["bandwidth_util"], 6)
+                    for r, s in rs.items()},
+            })
     return out
 
 

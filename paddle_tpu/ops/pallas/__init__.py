@@ -1,12 +1,15 @@
-"""paddle_tpu.ops.pallas — fused TPU kernels (Pallas) with XLA fallbacks.
+"""paddle_tpu.ops.pallas — fused TPU kernels (Pallas) and their gates.
 
 Public face of the kernel tier: callers import entry points from HERE
-instead of deep-importing the implementation modules. Every kernel routes
-through a platform gate (Pallas on TPU-like backends, reference XLA
-lowering elsewhere) so the same call sites run everywhere; the ``KERNELS``
-manifest records, per kernel, the entry point, the gate that decides the
-fused path, and the module holding the implementation — introspection for
-tooling and tests.
+instead of deep-importing the implementation modules. Every routed entry
+consults a gate (platform ``"tpu"`` and an eligible shape) ONCE: where the
+gate selects the Pallas kernel it runs, and a failure raises; elsewhere the
+gate selects the XLA formulation, which is also the reference the tests and
+``chip_smoke.py`` compare the kernel against. ``fused_adamw_flat`` has no
+XLA twin: it compiles for a TPU, or runs through the Pallas interpreter when
+a test asks for it. The ``KERNELS`` manifest records, per kernel, the entry
+point, the gate that decides the fused path, and the module holding the
+implementation — introspection for tooling and tests.
 
 Note the package attributes ``flash_attention`` / ``fused_adamw`` /
 ``fused_rms_norm`` remain the implementation MODULES (several callers
@@ -40,10 +43,10 @@ from paddle_tpu.ops.pallas import (  # noqa: F401  (self-imports for clarity)
     fused_rms_norm,
 )
 
-#: kernel id -> {entry, gate, module}: ``entry`` is the routed callable
-#: (safe on any backend), ``gate`` returns whether the fused Pallas path
-#: is taken (None = decided per-call on shape/platform inside the entry),
-#: ``module`` holds the implementation + its reference lowering.
+#: kernel id -> {entry, gate, module}: ``entry`` is the callable, ``gate``
+#: returns whether the fused Pallas path is taken (None = decided per-call
+#: on shape/platform inside the entry), ``module`` holds the implementation
+#: + its reference lowering.
 KERNELS = {
     "flash_attention": {
         "entry": flash_attention.flash_attention,

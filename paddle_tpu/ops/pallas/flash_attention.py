@@ -1,13 +1,12 @@
 """Flash attention (parity: phi/kernels/gpu/flash_attn_kernel.cu +
 python/paddle/nn/functional/flash_attention.py:147).
 
-TPU-native: a Pallas fused kernel (written against the MXU/VMEM model) with an
-XLA-fused jnp fallback for CPU tests / small shapes. Layout is paddle's
+TPU-native: a Pallas fused kernel (written against the MXU/VMEM model) on a
+TPU at block-divisible shapes; an XLA-fused jnp formulation (one fused
+computation, also the test reference) wherever the gate does not select the
+kernel. The gate decides once, from platform and shape: a kernel that was
+selected and fails raises. Layout is paddle's
 [batch, seqlen, num_heads, head_dim].
-
-The jnp path is itself one fused XLA computation — softmax(qk)v fuses on TPU —
-so the fallback is correct everywhere and the Pallas kernel is a perf upgrade
-gated on TPU availability + block-divisible shapes.
 """
 
 from __future__ import annotations
@@ -26,14 +25,11 @@ from paddle_tpu.tensor import Tensor
 # toggled by FLAGS_use_flash_attention (framework/flags.py)
 _FLASH_ENABLED = True
 
-# evidence trail: "pallas" | "xla" — set on every flash_attention_fwd trace
-# so tests/bench can assert the Pallas kernel is actually selected (a silent
-# platform-gate mismatch disabled it for a full round once).
+# evidence trail: "pallas" | "splash" | "xla" | "xla-traced-cu" — set on
+# every trace so tests/bench can assert which path the gate selected
 _last_path = None
-_warned_fallback = False
-_warned_fallback_splash = False
 _warned_traced_cu = False
-_warned_fallback_rms = False  # set via _warn_kernel_fallback from fused_rms_norm
+_interpret = False   # tests force the splash kernel through the interpreter
 
 
 def _dropout(x, p, training):
@@ -44,25 +40,12 @@ def _dropout(x, p, training):
     return jnp.where(keep, x / (1.0 - p), 0.0).astype(x.dtype)
 
 
-def _warn_kernel_fallback(name, flag_name):
-    """Warn ONCE per path when a TPU-class chip fails its kernel — a
-    silent fallback cost a full round of perf once."""
-    import traceback
-    import warnings
-
-    if globals()[flag_name]:
-        return
-    globals()[flag_name] = True
-    warnings.warn(f"{name} selected but FAILED; falling back to the XLA "
-                  "formulation:\n" + traceback.format_exc())
-
-
 def _use_pallas(q_shape, head_dim) -> bool:
     if not _FLASH_ENABLED:
         return False
-    from paddle_tpu.device import is_tpu_like
+    from paddle_tpu.device import is_tpu
 
-    if not is_tpu_like():
+    if not is_tpu():
         return False
     # block-divisibility: seq multiples of 128, head_dim multiple of 128 not
     # required (we pad head_dim inside the kernel wrapper if needed)
@@ -92,17 +75,11 @@ def flash_attention_fwd(q, k, v, bias=None, causal=False, scale=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if _use_pallas(q.shape, q.shape[-1]):
-        try:
-            from paddle_tpu.ops.pallas import flash_attention_tpu as ker
+        from paddle_tpu.ops.pallas import flash_attention_tpu as ker
 
-            out = ker.flash_attention(q, k, v, bias=bias, causal=causal, scale=scale)
-            _last_path = "pallas"
-            return out
-        except Exception:
-            # a TPU-like chip that can't run the kernel is a bug, not a
-            # fallback case — shout so it can't silently cost a round of perf
-            _warn_kernel_fallback("Pallas flash-attention",
-                                  "_warned_fallback")
+        _last_path = "pallas"
+        return ker.flash_attention(q, k, v, bias=bias, causal=causal,
+                                   scale=scale)
     _last_path = "xla"
     return _attention_reference(q, k, v, bias, causal, scale)
 
@@ -139,14 +116,14 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
 
 
 def _use_splash_varlen(tq, tk, d) -> bool:
-    """Gate for the Pallas SPLASH kernel on the varlen path: TPU-class
-    chip, self-attention packing (tq == tk), block-divisible total length,
+    """Gate for the Pallas SPLASH kernel on the varlen path: a TPU,
+    self-attention packing (tq == tk), block-divisible total length,
     MXU-friendly head dim."""
     if not _FLASH_ENABLED:
         return False
-    from paddle_tpu.device import is_tpu_like
+    from paddle_tpu.device import is_tpu
 
-    return (is_tpu_like() and tq == tk and tq % 128 == 0
+    return (is_tpu() and tq == tk and tq % 128 == 0
             and d in (64, 128, 256))
 
 
@@ -162,7 +139,7 @@ def _splash_varlen(q, k, v, seg_q, seg_k, causal, scale):
     T, H, D = q.shape
     mask_cls = _sm.CausalMask if causal else _sm.FullMask
     mask = _sm.MultiHeadMask([mask_cls((T, T)) for _ in range(H)])
-    kernel = _sk.make_splash_mha_single_device(mask)
+    kernel = _sk.make_splash_mha_single_device(mask, interpret=_interpret)
     seg = _sk.SegmentIds(q=seg_q.astype(jnp.int32),
                          kv=seg_k.astype(jnp.int32))
     # splash computes softmax(q @ k^T) with segment/causal masking and NO
@@ -186,11 +163,11 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
     ``query/key/value``: [total_tokens, num_heads, head_dim] — sequences
     packed back-to-back; ``cu_seqlens_*``: [batch+1] int32 cumulative
     lengths. Attention is segment-masked so tokens only attend within
-    their own sequence. On TPU-class chips with self-attention packing the
-    Pallas SPLASH kernel runs it block-sparsely (masked blocks skipped);
-    elsewhere an XLA-fused dense-mask formulation is the fallback (also
-    the decode path, whose causal convention aligns unequal q/k packings
-    to sequence ends)."""
+    their own sequence. On a TPU with self-attention packing the Pallas
+    SPLASH kernel runs it block-sparsely (masked blocks skipped); elsewhere
+    the gate selects an XLA-fused dense-mask formulation (also the decode
+    path, whose causal convention aligns unequal q/k packings to sequence
+    ends)."""
     if scale is None:
         scale = 1.0 / math.sqrt(query.shape[-1])
     # splash needs PROVABLY identical q/k packings (its CausalMask is
@@ -232,13 +209,8 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
             # dense path. dropout: attention-dropout applies to the PROBS,
             # which splash never materializes — train-with-dropout keeps
             # the dense formulation for exact reference semantics.
-            try:
-                out = _splash_varlen(q, k, v, seg_q, seg_k, causal, scale)
-                _last_path = "splash"
-                return out
-            except Exception:
-                _warn_kernel_fallback("splash varlen kernel",
-                                      "_warned_fallback_splash")
+            _last_path = "splash"
+            return _splash_varlen(q, k, v, seg_q, seg_k, causal, scale)
         logits = jnp.einsum("qhd,khd->hqk", q.astype(jnp.float32),
                             k.astype(jnp.float32)) * scale
         mask = seg_q[:, None] == seg_k[None, :]
@@ -263,8 +235,7 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
         if traced_cu and splash_eligible:
             # splash was skipped because traced cu_seqlens couldn't be
             # PROVEN equal — make that observable (benches watch
-            # _last_path; the notice fires once, on its own flag so it
-            # never suppresses the real kernel-FAILED warning). Note the
+            # _last_path; the notice fires once). Note the
             # packings may be GENUINELY different (then dense is the only
             # correct path) — we can't tell under tracing, so the advice
             # is conditional.

@@ -6,15 +6,18 @@ attention with a custom VJP, i.e. the flash algorithm scheduled for
 MXU/VMEM). Layout at this boundary is paddle's [batch, seq, heads, head_dim];
 the kernel runs [batch, heads, seq, head_dim].
 
-Block sizes: block_q 1024 / block_k 512 (clamped to the sequence) measured
-fastest on-chip for the GPT-2 shapes (99k vs 96k tokens/s end-to-end against
-512/512; 1024/1024 overflows VMEM-friendly tiling and drops to 66k) — larger
-q blocks amortize the KV loop while k stays within VMEM at head_dim 64-256.
+Block sizes: block_q 1024 / block_k 512, each clamped to the largest block
+that divides the sequence — larger q blocks amortize the KV loop while k
+stays within VMEM at head_dim 64-256. They compile and match the XLA
+reference on a v5e at batch 2 x seq 1024 x 16 heads x 128
+(``chip_smoke.py``, PR 21); their speed against other block sizes on this
+round's chip: not measured. The caps stay overridable for sweeps
+(``PADDLE_TPU_FLASH_FWD_BLOCKS`` / ``PADDLE_TPU_FLASH_BWD_BLOCKS``).
 """
 
 from __future__ import annotations
 
-import functools
+import os
 
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.flash_attention import (
@@ -30,10 +33,7 @@ def _largest_dividing_block(n: int, cap: int) -> int:
     return min(n, cap)
 
 
-import os
-
-# forward blocks: measured fastest for GPT-2 shapes (module docstring);
-# backward (dkv/dq) blocks tuned separately — overridable for sweeps
+# backward (dkv/dq) block caps, set apart from the forward ones
 _BWD_CAPS = None
 
 
@@ -64,8 +64,6 @@ def _fwd_caps():
     global _FWD_CAPS
     if _FWD_CAPS is None:
         env = os.environ.get("PADDLE_TPU_FLASH_FWD_BLOCKS", "")
-        # r4 S=2048 sweep (GPT-2s b6 fused-CE end-to-end): 1024/512 stays
-        # fastest (see NOTES_r4); the caps remain overridable for sweeps
         _FWD_CAPS = (1024, 512)
         if env:
             try:

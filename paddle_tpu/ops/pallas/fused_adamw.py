@@ -12,8 +12,9 @@ AdamW update on the VPU and writes param/m/v back through input/output
 aliasing (true in-place, zero extra HBM traffic). The op is memory-bound:
 one fused pass reads 5N and writes 3N floats — the theoretical floor.
 
-On non-TPU backends the same kernel runs through the Pallas interpreter
-(slow, for tests); callers should gate with `use_fused_adamw()`.
+The kernel compiles for a TPU only. The Pallas interpreter is something a
+test asks for — ``interpret=True`` on the call, or this module's
+``_interpret`` switch for the ``FusedAdamW`` optimizer paths.
 """
 
 from __future__ import annotations
@@ -23,20 +24,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is optional on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _DEFAULT_BLOCK_ROWS = 512  # 512*128 fp32 = 256 KiB per buffer in VMEM
+_interpret = False         # tests force interpret mode for FusedAdamW
 
 
 def use_fused_adamw() -> bool:
-    from paddle_tpu.device import is_tpu_like
+    from paddle_tpu.device import is_tpu
 
-    return is_tpu_like()
+    return is_tpu()
 
 
 def _adamw_kernel(beta1, beta2, eps,
@@ -103,8 +101,8 @@ def fused_adamw_flat(p, g, m, v, wd, lr, b1pow, b2pow, *,
     kernel = functools.partial(_adamw_kernel, float(beta1), float(beta2),
                                float(eps))
     row_spec = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
-    scalar_spec = pl.BlockSpec(memory_space=(
-        pltpu.SMEM if (pltpu is not None and not interpret) else None))
+    scalar_spec = pl.BlockSpec(
+        memory_space=None if interpret else pltpu.SMEM)
 
     out_p, out_m, out_v, out_b1, out_b2 = pl.pallas_call(
         kernel,

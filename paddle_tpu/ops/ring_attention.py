@@ -31,18 +31,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.8 name
 
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # pragma: no cover — jax < 0.8
-    from jax.experimental.shard_map import shard_map as _legacy
+def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
+    """``jax.shard_map`` with this repo's positional-mesh call shape
+    (replication checking off unless asked for)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       check_rep=check_rep)
 
 _NEG = -1e30
 

@@ -21,7 +21,9 @@ includes which faults were live.
 ``classify_error`` is the transient-vs-fatal triage the retry machinery
 uses: injected faults carry their own kind; programming errors
 (ValueError/TypeError/...) and pool exhaustion are fatal (propagate,
-never retry); device-runtime flake markers and I/O errors are transient.
+never retry); so is anything raised while a program compiles or executes
+for the first time (a retry fails the same way); I/O errors and
+device-runtime errors whose status can clear at run time are transient.
 
 Stdlib-only on purpose: checkpoint writers and the serving hot loop both
 import this module, and an injection hook must never pull jax.
@@ -292,8 +294,10 @@ _FATAL_NAMES = frozenset({
     "KVPoolExhausted", "QueueFull", "SchedulerOverloaded",
 })
 
-# substrings of device-runtime errors that indicate a retryable flake
-# (XLA status codes surface in the message text).
+# status codes and transport messages that can clear on a retry. The device
+# runtime raises ``jax.errors.JaxRuntimeError`` with the XLA status code
+# leading the message; any other status (INTERNAL, INVALID_ARGUMENT, a
+# Mosaic compile failure, ...) is fatal.
 _TRANSIENT_MARKERS = ("RESOURCE_EXHAUSTED", "UNAVAILABLE",
                       "DEADLINE_EXCEEDED", "ABORTED", "socket closed",
                       "connection reset")
@@ -302,17 +306,22 @@ _TRANSIENT_MARKERS = ("RESOURCE_EXHAUSTED", "UNAVAILABLE",
 def classify_error(exc: BaseException) -> str:
     """``"transient"`` (bounded retry) or ``"fatal"`` (propagate).
 
+    ``exc.program_start`` is set by the jit entry (``StaticFunction``) on
+    anything raised from a call that traced — the program was compiling or
+    executing for the first time, so a compile failure or an out-of-memory
+    there is deterministic and fatal, whatever its status code.
+
     Unknown errors default to fatal — a retry loop that eats exceptions it
     does not understand is exactly the swallowed-exception anti-pattern
     ``graft_lint``'s ``swallowed-exception`` rule exists to reject."""
     if isinstance(exc, InjectedFault):
         return "transient" if exc.kind == "transient" else "fatal"
-    name = type(exc).__name__
-    if name in _FATAL_NAMES:
+    if getattr(exc, "program_start", False):
+        return "fatal"
+    if type(exc).__name__ in _FATAL_NAMES:
         return "fatal"
     if isinstance(exc, OSError):
         return "transient"                # I/O flake: retryable
-    if "XlaRuntimeError" in name or any(
-            m in str(exc) for m in _TRANSIENT_MARKERS):
+    if any(m in str(exc) for m in _TRANSIENT_MARKERS):
         return "transient"
     return "fatal"

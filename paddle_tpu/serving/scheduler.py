@@ -164,6 +164,15 @@ def _drain_worker(sched_ref):
         del sched, entry
 
 
+def _backend_donates() -> bool:
+    """Whether the compiled steps donate their cache arguments: on every
+    backend but XLA:CPU (reasons at the call in ``__init__``). A function
+    so that a CPU test can force the branches the chip takes."""
+    import jax
+
+    return jax.default_backend() != "cpu"
+
+
 class ContinuousBatchingScheduler:
     """Iteration-level scheduler around one causal-LM's compiled slot step.
 
@@ -203,9 +212,7 @@ class ContinuousBatchingScheduler:
         # depths for the bit-identical-tokens guarantee to hold. CPU
         # therefore never donates here: transient double pool residency
         # bought overlap AND one executable for every dispatch_depth.
-        import jax
-
-        self._donate = jax.default_backend() != "cpu"
+        self._donate = _backend_donates()
         # ``sharding`` (duck-typed: serving.sharded.TensorParallelSharding
         # or anything with prepare_model/make_step/shard_pools/describe) —
         # one replica spans a device mesh. Weights are committed to the
@@ -2325,8 +2332,6 @@ class ContinuousBatchingScheduler:
             out["decode_program"] = {"name": entry.name,
                                      "error": an.get("error")}
             return out
-        roof = roofline_utilization(an["flops"], an["bytes_accessed"],
-                                    step_s)
         out["decode_program"] = dict(
             name=entry.name, signature=list(entry.signature),
             **{k: an[k] for k in ("flops", "bytes_accessed",
@@ -2334,6 +2339,14 @@ class ContinuousBatchingScheduler:
                                   "output_bytes", "alias_bytes")
                if k in an})
         out["decode_device_step_seconds"] = step_s
+        self.metrics.registry.gauge(
+            "decode_device_step_seconds",
+            "sampled decode device step time", unit="seconds").set(step_s)
+        roof = roofline_utilization(an["flops"], an["bytes_accessed"],
+                                    step_s)
+        if roof is None:
+            # CPU: no peaks, so no utilisation keys and no gauge
+            return out
         out["decode_bandwidth_util"] = roof["bandwidth_util"]
         out["decode_bandwidth_util_raw"] = roof["bandwidth_util_raw"]
         out["decode_mfu"] = roof["mfu"]
@@ -2342,9 +2355,6 @@ class ContinuousBatchingScheduler:
             "decode_bandwidth_util",
             "decode-program bytes/s over chip peak memory bandwidth"
         ).set(roof["bandwidth_util"])
-        self.metrics.registry.gauge(
-            "decode_device_step_seconds",
-            "sampled decode device step time", unit="seconds").set(step_s)
         return out
 
     # ---- in-step profiling (named-region attribution) ------------------

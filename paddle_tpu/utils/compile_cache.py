@@ -1,17 +1,27 @@
-"""Version-stamped JAX persistent compilation cache.
+"""Where JAX's persistent compilation cache lives — decided in one place.
 
-NOTES r7: a ``build/jax_cache`` populated by an older framework/jax build
-replayed AOT executables with WRONG NUMERICS into the serving tests, and the
-only cure was knowing to ``rm -rf`` it by hand. This module makes the cache
-self-invalidating: the directory carries a ``CACHE_KEY.json`` stamp of the
-framework + jax/jaxlib versions that filled it, and ``ensure_compile_cache_dir``
-wipes the contents whenever the stamp no longer matches the running build.
+``compile_cache_dir()`` is the one helper every entry point uses
+(``chip_smoke.py``, ``bench.py``'s children, ``tests/conftest.py``):
+
+- where ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is the cache;
+  this code does not create, stamp or wipe it, and sets no other;
+- where it is not set, the cache is the fixed ``<checkout>/build/jax_cache``
+  (the path is part of the cache key, so it never carries a temporary name,
+  a pid or a time), and the variable is set so that JAX — imported after
+  this call — and every child process use the same directory.
+
+The repo's own default directory is version-stamped: it carries a
+``CACHE_KEY.json`` of the framework + jax/jaxlib versions that filled it,
+and ``ensure_compile_cache_dir`` wipes the entries when the stamp no longer
+matches the running build (a cache filled by an older build once replayed
+executables with wrong numerics into the serving tests). Only that default
+directory is ever wiped.
 
 Deliberately import-light: no ``jax`` import (versions come from package
 metadata), no ``paddle_tpu`` import (the framework version is parsed out of
-``paddle_tpu/version/__init__.py`` as text) — so ``tests/conftest.py`` and
-``bench.py`` can run it BEFORE any env-var pinning or backend init, via
-``importlib.util.spec_from_file_location`` on this file.
+``paddle_tpu/version/__init__.py`` as text) — a parent process that must not
+touch the chip, or a conftest that has not pinned its platform yet, loads
+this file with ``importlib.util.spec_from_file_location``.
 """
 
 from __future__ import annotations
@@ -21,6 +31,24 @@ import os
 import re
 
 STAMP_NAME = "CACHE_KEY.json"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+
+def default_cache_dir() -> str:
+    """``<checkout>/build/jax_cache``."""
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(checkout, "build", "jax_cache")
+
+
+def compile_cache_dir(env=None) -> str:
+    """The cache directory for this process and its children (module
+    docstring). ``env`` is the mapping to read and update — ``os.environ``
+    by default, or the environment being built for a child."""
+    env = os.environ if env is None else env
+    if not env.get(ENV_VAR):
+        env[ENV_VAR] = ensure_compile_cache_dir(default_cache_dir())
+    return env[ENV_VAR]
 
 
 def _framework_version() -> str:
@@ -54,7 +82,9 @@ def cache_key() -> dict:
 
 
 def ensure_compile_cache_dir(path: str) -> str:
-    """Create/validate ``path`` as a stamped compilation cache dir.
+    """Create/validate ``path`` as a stamped compilation cache dir — the
+    repo's own default directory (``compile_cache_dir``), never one the
+    caller's environment named.
 
     A missing or mismatching ``CACHE_KEY.json`` wipes every cache entry in
     the directory and writes a fresh stamp, so stale AOT replays from an
@@ -96,17 +126,3 @@ def ensure_compile_cache_dir(path: str) -> str:
         except OSError:
             pass
     return path
-
-
-def load_by_path():
-    """How callers that must not import ``paddle_tpu`` (conftest before env
-    pinning, bench.py's jax-free parent) are expected to load this module —
-    documented here so the idiom stays greppable::
-
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "_pt_compile_cache", ".../paddle_tpu/utils/compile_cache.py")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-    """
-    raise NotImplementedError("see docstring; this is documentation only")

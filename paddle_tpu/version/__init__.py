@@ -111,14 +111,8 @@ def nccl():
 
 
 def tpu():
-    """TPU-class platform name when a chip is attached (non-reference
+    """``"tpu"`` when a chip is attached, else ``"False"`` (non-reference
     extension — this build's accelerator)."""
-    try:
-        import jax
+    from paddle_tpu.device import is_tpu
 
-        from paddle_tpu.device import is_tpu_like
-
-        d = jax.devices()[0]
-        return d.platform if is_tpu_like(d) else "False"
-    except Exception:
-        return "False"
+    return "tpu" if is_tpu() else "False"

@@ -9,7 +9,15 @@ import paddle_tpu as paddle
 import paddle_tpu.nn as nn
 import paddle_tpu.optimizer as opt
 from paddle_tpu.incubate.optimizer import FusedAdamW
+from paddle_tpu.ops.pallas import fused_adamw as _kernel
 from paddle_tpu.ops.pallas.fused_adamw import fused_adamw_flat, pad_flat
+
+
+@pytest.fixture(autouse=True)
+def _interpreted_kernel(monkeypatch):
+    """The kernel compiles for a TPU only; on this CPU tier the FusedAdamW
+    paths run it through the Pallas interpreter because the test asks."""
+    monkeypatch.setattr(_kernel, "_interpret", True)
 
 
 def _np_adamw(p, g, m, v, lr, b1p, b2p, beta1, beta2, eps, wd):

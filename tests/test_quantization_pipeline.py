@@ -123,7 +123,7 @@ def test_ptq_calibration_produces_scales_and_converts():
 
 
 def test_hist_observer_robust_to_outliers():
-    """NOTES_r2 gap: histogram calibration — one extreme outlier must not
+    """Histogram calibration — one extreme outlier must not
     blow up the scale the way absmax does."""
     import numpy as np
 

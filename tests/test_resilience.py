@@ -164,6 +164,102 @@ def test_classify_error():
     assert classify_error(OSError("io")) == "transient"
 
 
+def test_classify_error_device_runtime_errors():
+    """The installed runtime raises jax.errors.JaxRuntimeError with the XLA
+    status code leading the message. A status that can clear at run time is
+    retried; any other is fatal; and ANYTHING raised while a program
+    compiles or runs for the first time is fatal — a retry fails the same
+    way, and it used to end as quietly failed requests."""
+    import jax
+
+    Err = jax.errors.JaxRuntimeError
+    oom = "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting " \
+          "to allocate 4.00G. That was not possible. There are 3.75G free."
+    assert classify_error(Err(oom)) == "transient"
+    assert classify_error(Err("UNAVAILABLE: device busy")) == "transient"
+    assert classify_error(
+        Err("INTERNAL: Mosaic failed to compile TPU kernel")) == "fatal"
+    assert classify_error(Err("INVALID_ARGUMENT: bad shape")) == "fatal"
+    first = Err(oom)
+    first.program_start = True
+    assert classify_error(first) == "fatal"
+    # an eager allocation failure surfaces as ValueError on the chip
+    # (seen in PR 21): fatal by name, whatever it says
+    assert classify_error(ValueError(oom)) == "fatal"
+
+
+def test_static_function_marks_errors_from_a_programs_first_run():
+    """StaticFunction sets ``program_start`` on what a TRACING call raises
+    (compile failure, out-of-memory on the first execution) and on nothing
+    a cached program raises later."""
+    import jax
+
+    from paddle_tpu.jit.api import StaticFunction
+
+    def body(x):               # the body runs at trace time only
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error")
+
+    sf = StaticFunction(body, name="test.program_start")
+    x = paddle.to_tensor(np.ones((4,), np.float32))
+    with pytest.raises(jax.errors.JaxRuntimeError) as ei:
+        sf(x)
+    assert ei.value.program_start is True
+    assert classify_error(ei.value) == "fatal"
+
+    # a program that compiled: its later calls do not trace, so whatever
+    # they raise carries no mark and is triaged by its status alone
+    ok = StaticFunction(lambda x: x * 2, name="test.program_cached")
+    np.testing.assert_array_equal(np.asarray(ok(x).numpy()), 2.0)
+    traces = ok._traces
+    ok(x)
+    assert ok._traces == traces == 1
+
+
+def test_first_run_failure_is_fatal_not_a_quietly_failed_request(model,
+                                                                 monkeypatch):
+    """End to end through the scheduler: an out-of-memory from the prefill
+    program's FIRST execution propagates out of ``run()`` instead of being
+    retried ``max_step_faults`` times and retired as ``failed``."""
+    import jax
+
+    sched = _sched(model)
+    sf = sched._step_fn._sf
+    real = sf._run_impl
+
+    def oom_while_tracing(*a, **k):
+        sf._traces += 1                      # what a cache miss does
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm")
+
+    monkeypatch.setattr(sf, "_run_impl", oom_while_tracing)
+    sched.add_request(_prompts(1)[0], max_new_tokens=3)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        sched.run()
+    assert sched.metrics.requests_failed == 0
+    assert any(k for k in sched.metrics.faults_snapshot())  # noted as fatal
+
+    # the SAME status from a program that has run before stays retryable
+    monkeypatch.setattr(sf, "_run_impl", real)
+    sched2 = _sched(model)
+    sched2.add_request(_prompts(1)[0], max_new_tokens=3)
+    _drain(sched2)                           # programs compiled and proven
+    sf2 = sched2._step_fn._sf
+    real2, fired = sf2._run_impl, []
+
+    def oom_once(*a, **k):
+        if not fired:
+            fired.append(1)
+            raise jax.errors.JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: Error allocating device buffer")
+        return real2(*a, **k)
+
+    monkeypatch.setattr(sf2, "_run_impl", oom_once)
+    rid = sched2.add_request(_prompts(1)[0], max_new_tokens=3)
+    outs = _drain(sched2)
+    assert fired and outs[rid].finish_reason in ("length", "eos")
+
+
 # --------------------------------- per-site recovery with token identity
 
 @pytest.mark.parametrize("site,rule", [

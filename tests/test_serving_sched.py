@@ -370,3 +370,42 @@ def test_fault_backoff_releases_engine_lock(model):
     assert release_times and release_times[0] <= absorbed_at
     assert absorbed_at - t0 < 0.9, (
         f"backoff held the engine lock for {absorbed_at - t0:.2f}s")
+
+
+# ------------------------------------------- the branches the chip takes
+
+def test_donation_forced_on_is_token_identical(monkeypatch):
+    """``_donate`` is False on XLA:CPU, so the scheduler's three
+    ``if self._donate:`` branches (decode, admission prefill, chunk pump)
+    are what every TPU run takes and what no CPU test used to. Force
+    donation on — plain, dispatch-ahead, prefix cache, chunked prefill,
+    speculation — and require the undonated plain run's tokens (every one
+    of those features is token-identical to it by contract)."""
+    from paddle_tpu.serving import scheduler as sched_mod
+
+    paddle.seed(7)
+    model = GPTForCausalLM(gpt_tiny(num_layers=1))   # six schedulers: small
+    rng = np.random.default_rng(3)
+    shared = rng.integers(0, 1000, 16)            # a cacheable prefix
+    pattern = rng.integers(0, 1000, 5)            # n-gram proposals fire
+    prompts = [np.concatenate([shared, rng.integers(0, 1000, 5)]),
+               np.concatenate([pattern, pattern, pattern]),
+               np.concatenate([shared, rng.integers(0, 1000, 9)]),
+               rng.integers(0, 1000, 40),         # several chunks
+               np.concatenate([shared, rng.integers(0, 1000, 5)])]
+
+    def run(donate, **over):
+        monkeypatch.setattr(sched_mod, "_backend_donates", lambda: donate)
+        sched = ContinuousBatchingScheduler(model, SchedulerConfig(
+            max_num_seqs=3, max_seq_len=128, block_size=8, **over))
+        assert sched._donate is donate
+        outs = sched.generate(prompts, max_new_tokens=6)
+        assert sched.metrics.requests_failed == 0
+        assert not sched.metrics.faults_snapshot()
+        sched.shutdown()
+        return [[int(t) for t in o] for o in outs]
+
+    undonated = run(False)
+    for over in ({}, dict(dispatch_depth=2), dict(enable_prefix_caching=True),
+                 dict(prefill_chunk_size=16), dict(spec_k=3)):
+        assert run(True, **over) == undonated, over

@@ -143,15 +143,29 @@ def test_attribute_trace_fixture_end_to_end():
     assert out["programs"]["prefill"]["events"] == 0
 
 
-def test_attribute_trace_roofline_decomposition():
+def test_attribute_trace_roofline_decomposition(monkeypatch):
+    # on CPU there are no peaks: the byte decomposition is there, the
+    # utilisation is ABSENT rather than computed against an invented peak
     out = attribute_trace(_fixture_events(), _fixture_programs())
     roof = out["decode_roofline"]
     assert roof["program"] == "decode"
     assert roof["flops"] == 1.0e6 and roof["bytes_accessed"] == 2.0e6
-    assert 0.0 < roof["bandwidth_util"] <= 1.0
+    for key in ("bandwidth_util", "mfu", "chip", "bandwidth_util_by_region"):
+        assert key not in roof
     rs = out["programs"]["decode"]["region_shares"]
     for r, share in rs.items():
         assert roof["region_bytes_est"][r] == int(share * 2.0e6)
+
+    # with a chip's row from the peaks table the utilisation decomposes
+    from paddle_tpu.observability import program_inventory as pi
+
+    v5e = pi.chip_specs("TPU v5 lite")
+    monkeypatch.setattr(pi, "chip_specs", lambda *a: v5e)
+    roof = attribute_trace(_fixture_events(),
+                           _fixture_programs())["decode_roofline"]
+    assert 0.0 < roof["bandwidth_util"] <= 1.0
+    assert roof["chip"]["device_kind"] == "TPU v5 lite"
+    for r, share in rs.items():
         assert roof["bandwidth_util_by_region"][r] == pytest.approx(
             share * roof["bandwidth_util"], abs=1e-5)
     # estimates decompose the measured step: never exceed the whole
@@ -269,9 +283,11 @@ def test_capture_live_attributes_decode_regions(profiled_sched):
     assert sum(shares.values()) == pytest.approx(summary["coverage"],
                                                  abs=1e-3)
     assert summary["coverage"] >= 0.5, summary
+    # a CPU capture decomposes bytes by region but states no utilisation
     roof = summary.get("decode_roofline")
-    assert roof and 0.0 < roof["bandwidth_util"] <= 1.0
-    assert roof["bandwidth_util_by_region"]
+    assert roof and roof["region_bytes_est"]
+    assert "bandwidth_util" not in roof
+    assert "bandwidth_util_by_region" not in roof
 
 
 def test_capture_feeds_endpoint_and_postmortem(profiled_sched):

@@ -356,7 +356,30 @@ def test_compile_cache_stamp_and_invalidate(tmp_path):
     assert json.load(open(stamp)) == cc.cache_key()
 
 
-def test_bench_probe_attempts_env(monkeypatch):
+def test_compile_cache_dir_respects_the_callers_directory(tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set the cache lives there and
+    this code neither stamps nor wipes it; unset, it is the fixed
+    <checkout>/build/jax_cache, exported for jax and every child."""
+    cc = _load_compile_cache_module()
+    theirs = tmp_path / "their_cache"
+    theirs.mkdir()
+    (theirs / "entry").write_text("aot")
+    env = {cc.ENV_VAR: str(theirs)}
+    assert cc.compile_cache_dir(env) == str(theirs)
+    assert env == {cc.ENV_VAR: str(theirs)}
+    assert sorted(os.listdir(theirs)) == ["entry"]   # no stamp, no wipe
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    default = os.path.join(repo, "build", "jax_cache")
+    assert cc.default_cache_dir() == default
+    env = {}
+    assert cc.compile_cache_dir(env) == default
+    assert env == {cc.ENV_VAR: default}
+    assert json.load(open(os.path.join(default, cc.STAMP_NAME))) \
+        == cc.cache_key()
+
+
+def _load_bench_module():
     import importlib.util
 
     path = os.path.join(os.path.dirname(os.path.dirname(
@@ -364,14 +387,52 @@ def test_bench_probe_attempts_env(monkeypatch):
     spec = importlib.util.spec_from_file_location("_bench_under_test", path)
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
-    monkeypatch.delenv("FLAGS_bench_probe_attempts", raising=False)
-    assert bench._probe_attempts() == 1  # fast-fail default
-    monkeypatch.setenv("FLAGS_bench_probe_attempts", "5")
-    assert bench._probe_attempts() == 5
-    monkeypatch.setenv("FLAGS_bench_probe_attempts", "bogus")
-    assert bench._probe_attempts() == 1
-    monkeypatch.setenv("FLAGS_bench_probe_attempts", "0")
-    assert bench._probe_attempts() == 1  # at least one probe
+    return bench
+
+
+def test_bench_parent_is_jax_free_and_has_no_probe():
+    """One process for each chip: importing bench.py (what the parent
+    does) pulls in neither jax nor paddle_tpu, and the probe / CPU
+    fallback machinery is gone."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys, importlib.util as u\n"
+            f"s = u.spec_from_file_location('b', {repo + '/bench.py'!r})\n"
+            "m = u.module_from_spec(s); s.loader.exec_module(m)\n"
+            "bad = [n for n in ('jax', 'paddle_tpu') if n in sys.modules]\n"
+            "assert not bad, bad\n"
+            "assert not [n for n in dir(m) if 'probe' in n.lower()]\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+
+
+def test_bench_exits_nonzero_when_a_bench_fails(monkeypatch, capsys):
+    """A failing bench child still lets the rest run (its error line is
+    printed), and the whole run then exits non-zero."""
+    bench = _load_bench_module()
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.setattr(bench, "_BENCHES",
+                        {"no_such_bench": None, "bench_lint": None})
+    calls = []
+    real_run = __import__("subprocess").run
+
+    def fake_run(cmd, **kw):
+        calls.append(cmd[-1])
+        if cmd[-1] == "bench_lint":      # keep the test quick: a stub ok
+            cmd = [cmd[0], "-c", "print('{\"metric\": \"stub\"}')"]
+        return real_run(cmd, **kw)
+
+    monkeypatch.setattr(__import__("subprocess"), "run", fake_run)
+    with pytest.raises(SystemExit) as ei:
+        bench.main()
+    assert calls == ["no_such_bench", "bench_lint"]   # the rest still ran
+    assert ei.value.code not in (0, None)
+    assert "no_such_bench" in str(ei.value.code)
+    out = capsys.readouterr().out
+    assert '"metric": "stub"' in out and '"error"' in out
 
 
 # ------------------------------------------------------- loop integrations

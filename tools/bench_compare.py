@@ -37,7 +37,7 @@ Exit status: 0 when no directional metric regressed beyond tolerance,
 1 when at least one did, 2 on usage/IO errors. Timing metrics on shared
 CI hosts are noisy, hence the deliberately loose default tolerance
 (25% relative); tighten per-metric conclusions by re-running, not by
-trusting one sample (NOTES_r3: never believe a single slow bench).
+trusting one sample (never believe a single slow bench).
 
 Typical wiring: regenerate ``BENCH_*.json`` on your branch, then compare
 against the committed artifact from the previous PR::

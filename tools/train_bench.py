@@ -171,8 +171,9 @@ def _run_phase(on_tpu: bool, *, steps: int, warmup: int, depth: int,
         if "flops" in an:
             roof = roofline_utilization(an["flops"], an["bytes_accessed"],
                                         wall / steps)
-            mfu, bw_util = roof["mfu"], roof["bandwidth_util"]
-            chip = roof["chip"]
+            if roof is not None:   # None on CPU: no peaks, no utilisation
+                mfu, bw_util = roof["mfu"], roof["bandwidth_util"]
+                chip = roof["chip"]
     return {
         "prefetch_depth": depth,
         "donate_inputs": donate_inputs,
@@ -315,8 +316,11 @@ def run_bench(on_tpu: bool = False, steps: int = 20, warmup: int = 3,
             f"prefetch did not collapse the input stall: "
             f"{hot['input_stall_s']} s over {hot['wall_s']} s wall")
         mfu = art["train_mfu"]
-        assert mfu is not None and 0.0 < mfu <= 1.0, (
-            f"train_mfu must be attributable and in (0, 1]: {mfu}")
+        if on_tpu:
+            assert mfu is not None and 0.0 < mfu <= 1.0, (
+                f"train_mfu must be attributable and in (0, 1]: {mfu}")
+        else:
+            assert mfu is None, f"a CPU run has no peaks to divide by: {mfu}"
         if profile["enabled"]:
             for g in ("forward", "backward", "optimizer"):
                 assert profile["group_shares"].get(g, 0.0) > 0.0, (
