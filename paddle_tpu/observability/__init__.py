@@ -13,12 +13,19 @@ serving, and distributed code:
   abstract shapes — and ``mark_steady()`` turns any further compile into a
   loud ``RecompileStorm`` warning. The TPU failure mode this exists for is
   silent recompilation.
-- **Trace spans** live in ``paddle_tpu.profiler`` (``RecordEvent``); the
-  training step, optimizer update, collectives, dataloader, and serving
-  scheduler all emit them, and ``Profiler.export_report()`` merges host
-  spans with metric snapshots into one artifact. Every literal span name is
-  registered (owner + category) in ``span_manifest.py``; the
-  ``tools/check_spans.py`` lint keeps the manifest and the code in sync.
+- **Trace spans** are ``paddle_tpu.profiler.RecordEvent``; the training
+  step, optimizer update, collectives, dataloader, and serving scheduler
+  all emit them. A span is in two places: the profiler's in-process ring
+  while a ``Profiler`` records (``Profiler.export_report()`` merges those
+  with metric snapshots into one artifact), and, as an annotation of
+  JAX's profiler, in the ``.xplane.pb`` of any open profiler session, on
+  the clock of the device's events. The scheduler's
+  ``step()`` is cut into phases this way (``serving.step`` > ``sweep`` /
+  ``admit`` / ``stage`` / ``launch`` / ``sampling_sync`` / ``commit`` /
+  ``account``), which is how device-idle time is given to host phases.
+  Every literal span name is registered (owner + category) in
+  ``span_manifest.py``; the ``tools/check_spans.py`` lint keeps the
+  manifest and the code in sync.
 - **Request lifecycle tracing** (``request_trace.py``): per-request linked
   spans keyed by ``request_id`` across the serving scheduler — queued →
   admit (prefix match + prefill) → running → preempted/resumed → done —
@@ -27,8 +34,9 @@ serving, and distributed code:
 - **Serving stall attribution + flight recorder** (``serving_stall.py``):
   ``serving_host_stall_seconds{phase=...}`` mirrors ``train_stall.py`` for
   the serving hot loop (admission / radix_match / block_accounting /
-  streaming / sampling_sync), plus a per-step ring buffer dumped on demand
-  or on alarm (``TTFTBreachStorm``, ``EvictionThrash``).
+  streaming / sampling_sync); ``ServingStall.timed(phase)`` opens the span
+  ``serving.<phase>`` round the code it meters. Plus a per-step ring buffer
+  dumped on demand or on alarm (``TTFTBreachStorm``, ``EvictionThrash``).
 - **Device memory ledger** (``device_memory.py``): every framework-owned
   device allocation site (KV pool, prefix-pinned blocks, weights,
   optimizer slots, fp32 masters, prefetch double-buffers, checkpoint

@@ -55,6 +55,7 @@ from typing import Dict, List, Optional
 
 from paddle_tpu.observability.annotations import guarded_by
 from paddle_tpu.observability.metrics import MetricsRegistry, get_registry
+from paddle_tpu.profiler import RecordEvent
 
 __all__ = [
     "AlarmMonitors",
@@ -125,11 +126,15 @@ class ServingStall:
 
     @contextmanager
     def timed(self, phase: str):
+        """Add the block's host time to ``phase``, under the span
+        ``serving.<phase>``: the counter and the span in the profiler's
+        trace are opened here together, so they cover the same code."""
         t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(phase, time.perf_counter() - t0)
+        with RecordEvent(f"serving.{phase}"):
+            try:
+                yield
+            finally:
+                self.record(phase, time.perf_counter() - t0)
 
     def seconds(self, phase: str) -> float:
         return self._phase[phase].value

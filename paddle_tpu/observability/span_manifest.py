@@ -38,8 +38,22 @@ SPAN_MANIFEST = {
     "serving.prefill": {"owner": "serving", "category": "Forward"},
     "serving.decode_step": {"owner": "serving", "category": "Forward"},
     "serving.preempt": {"owner": "serving", "category": "UserDefined"},
-    "serving.spec_propose": {"owner": "serving", "category": "UserDefined"},
     "serving.prefix_match": {"owner": "serving", "category": "UserDefined"},
+    # the phases of one scheduler step(): a child lies inside its parent and
+    # siblings do not overlap, so a device-idle instant has one innermost
+    # owner (perfbench/harness/phases.py). serving.sampling_sync,
+    # serving.spec_propose and serving.drain are ServingStall.timed()'s
+    # (DYNAMIC_SPANS), as is serving.block_accounting round the capacity
+    # loops; the literal one is the admission's allocation and table row
+    "serving.step": {"owner": "serving", "category": "UserDefined"},
+    "serving.sweep": {"owner": "serving", "category": "UserDefined"},
+    "serving.admit": {"owner": "serving", "category": "UserDefined"},
+    "serving.block_accounting": {"owner": "serving",
+                                 "category": "UserDefined"},
+    "serving.stage": {"owner": "serving", "category": "UserDefined"},
+    "serving.launch": {"owner": "serving", "category": "UserDefined"},
+    "serving.commit": {"owner": "serving", "category": "UserDefined"},
+    "serving.account": {"owner": "serving", "category": "UserDefined"},
     "serving.reload_weights": {"owner": "serving",
                                "category": "UserDefined"},
     # sharded serving (tensor-parallel mesh placement at replica build)
@@ -66,4 +80,7 @@ SPAN_MANIFEST = {
 # spans. One entry per non-literal RecordEvent(...) call site.
 DYNAMIC_SPANS = {
     "paddle_tpu/distributed/collective.py": "comm.",
+    # ServingStall.timed(phase) opens serving.<phase> round the code whose
+    # time it adds to serving_host_stall_seconds{phase}
+    "paddle_tpu/observability/serving_stall.py": "serving.",
 }

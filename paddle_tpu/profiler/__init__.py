@@ -4,9 +4,13 @@ chrometracing_logger.cc).
 
 TPU-native: device-side tracing delegates to the XLA/XPlane profiler
 (jax.profiler.start_trace — the CUPTI analogue), viewable in TensorBoard /
-Perfetto; host-side RecordEvent spans are kept in an in-process ring and
-exported as a Chrome trace JSON, with summary statistics mirroring
-profiler_statistic.py."""
+Perfetto. A host-side RecordEvent span goes to two sinks: an in-process ring
+on ``time.perf_counter`` (only while a ``Profiler`` records), exported as a
+Chrome trace JSON with summary statistics mirroring profiler_statistic.py;
+and a ``jax.profiler.TraceAnnotation`` of the same name, so that every span
+is also in any open profiler session's ``.xplane.pb`` on the clock of the
+device's events (``Profiler.device_trace_dir`` says where this module's own
+sessions write theirs)."""
 
 from __future__ import annotations
 
@@ -18,6 +22,8 @@ import threading
 import time
 from enum import Enum
 from typing import Callable, Iterable, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class ProfilerTarget(Enum):
@@ -72,18 +78,29 @@ _recorder = _HostEventRecorder()
 
 
 class RecordEvent:
-    """RAII/context host span (platform/profiler/event_tracing.h parity)."""
+    """RAII/context host span (platform/profiler/event_tracing.h parity).
+
+    One span, two sinks: the ring above while a ``Profiler`` records, and a
+    ``TraceAnnotation`` of the same name, which lands in the trace of
+    whatever profiler session is open (this module's, the benchmark's, a
+    ``StepProfiler``'s) and is a no-op with none. ``begin`` and ``end`` run
+    on one thread."""
 
     def __init__(self, name: str, event_type: TracerEventType = TracerEventType.UserDefined):
         self.name = name
         self.event_type = event_type
         self._t0 = None
+        self._annotation = None
 
     def begin(self):
         self._t0 = time.perf_counter()
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
 
     def end(self):
         if self._t0 is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
             _recorder.record(self.name, self.event_type, self._t0,
                              time.perf_counter())
             self._t0 = None
@@ -146,7 +163,7 @@ class Profiler:
     def __init__(self, *, targets: Optional[Iterable] = None, scheduler=None,
                  on_trace_ready=None, record_shapes=False, profile_memory=False,
                  timer_only=False, emit_nvtx=False, custom_device_types=None,
-                 with_flops=False):
+                 with_flops=False, device_trace_dir: Optional[str] = None):
         self._scheduler = scheduler if callable(scheduler) else None
         if isinstance(scheduler, (tuple, list)):
             lo, hi = scheduler
@@ -157,7 +174,11 @@ class Profiler:
         self.step_num = 0
         self._state = ProfilerState.CLOSED
         self._device_tracing = False
-        self._trace_dir = None
+        # where the XLA profiler session of each recording window writes its
+        # .xplane.pb (<dir>/plugins/profile/<time>/): device events and every
+        # RecordEvent span, on one clock. A temporary directory, made when
+        # the first window opens, unless the caller names one.
+        self.device_trace_dir = device_trace_dir
         self._last_events = []
         self._exported_path = None
         self._step_times = []
@@ -183,10 +204,15 @@ class Profiler:
 
             import tempfile
 
-            self._trace_dir = self._trace_dir or tempfile.mkdtemp(
+            self.device_trace_dir = self.device_trace_dir or tempfile.mkdtemp(
                 prefix="paddle_tpu_xplane_")
+            # the spans are TraceAnnotations, so Python call tracing adds
+            # nothing but megabytes and host time
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
             try:
-                jax.profiler.start_trace(self._trace_dir)
+                jax.profiler.start_trace(self.device_trace_dir,
+                                         profiler_options=opts)
                 self._device_tracing = True
             except Exception:
                 self._device_tracing = False

@@ -835,32 +835,35 @@ class ContinuousBatchingScheduler:
             cow = matched < len(hit_blocks) * bs
             need_blocks = -(-P // bs) - len(hit_blocks) + (1 if cow else 0)
             t0 = pc()
-            try:
-                inject("serving.block_alloc")
-                fresh = (self.allocator.allocate(need_blocks * bs)
-                         if need_blocks > 0 else [])
-            except KVPoolExhausted:
-                if hit_blocks:
-                    self.prefix_cache.unpin(hit_blocks)
-                break                        # running seqs keep precedence
-            except Exception as exc:
-                # nothing allocated yet: drop the pins and triage. A
-                # transient fault leaves the request queued (retried next
-                # step) until its K-consecutive-fault budget runs out.
-                if hit_blocks:
-                    self.prefix_cache.unpin(hit_blocks)
-                site = self._fault_site(exc, "serving.block_alloc")
-                if classify_error(exc) == "fatal":
-                    self.metrics.observe_fault(site, "fatal")
-                    raise
-                self._note_fault(site)
-                if self._fault_budget_exhausted(nxt):
-                    self.queue.pop()
-                    self.metrics.observe_fault(site, "request_failed")
-                    self.metrics.requests_failed += 1
-                    finished.append(self._finalize_off_grid(nxt, "failed"))
-                    continue
-                break
+            with RecordEvent("serving.block_accounting"):
+                try:
+                    inject("serving.block_alloc")
+                    fresh = (self.allocator.allocate(need_blocks * bs)
+                             if need_blocks > 0 else [])
+                except KVPoolExhausted:
+                    if hit_blocks:
+                        self.prefix_cache.unpin(hit_blocks)
+                    break                    # running seqs keep precedence
+                except Exception as exc:
+                    # nothing allocated yet: drop the pins and triage. A
+                    # transient fault leaves the request queued (retried
+                    # next step) until its K-consecutive-fault budget runs
+                    # out.
+                    if hit_blocks:
+                        self.prefix_cache.unpin(hit_blocks)
+                    site = self._fault_site(exc, "serving.block_alloc")
+                    if classify_error(exc) == "fatal":
+                        self.metrics.observe_fault(site, "fatal")
+                        raise
+                    self._note_fault(site)
+                    if self._fault_budget_exhausted(nxt):
+                        self.queue.pop()
+                        self.metrics.observe_fault(site, "request_failed")
+                        self.metrics.requests_failed += 1
+                        finished.append(
+                            self._finalize_off_grid(nxt, "failed"))
+                        continue
+                    break
             block_s += pc() - t0
             req = self.queue.pop()
             trace = self.tracer.get(req.request_id)
@@ -870,20 +873,22 @@ class ContinuousBatchingScheduler:
                     trace.event("resumed",
                                 preemptions=req.num_preemptions)
             t0 = pc()
-            blocks = list(hit_blocks)
-            if cow:
-                new_b = fresh.pop(0)
-                self._pools = copy_block_in_pools(
-                    self._pools, blocks[-1], new_b)
-                self.allocator.decref(blocks[-1])   # drop pin on the original
-                blocks[-1] = new_b
-            blocks += fresh
-            req.blocks = blocks
-            req.slot = slot
-            req.state = RequestState.RUNNING
-            S = P - matched                  # uncached suffix to prefill
-            row = np.full((1, self.config.max_blocks_per_seq), -1, np.int32)
-            row[0, :len(blocks)] = blocks
+            with RecordEvent("serving.block_accounting"):
+                blocks = list(hit_blocks)
+                if cow:
+                    new_b = fresh.pop(0)
+                    self._pools = copy_block_in_pools(
+                        self._pools, blocks[-1], new_b)
+                    self.allocator.decref(blocks[-1])  # drop pin on original
+                    blocks[-1] = new_b
+                blocks += fresh
+                req.blocks = blocks
+                req.slot = slot
+                req.state = RequestState.RUNNING
+                S = P - matched              # uncached suffix to prefill
+                row = np.full((1, self.config.max_blocks_per_seq), -1,
+                              np.int32)
+                row[0, :len(blocks)] = blocks
             block_s += pc() - t0
             if self._chunk_step is not None:
                 # chunked admission: pack the slot MID-PREFILL (frontier =
@@ -918,23 +923,16 @@ class ContinuousBatchingScheduler:
             try:
                 inject("serving.prefill")
                 with RecordEvent("serving.prefill"), paddle.no_grad():
-                    if self._donate:
-                        caches = [PagedCacheSlot(
-                            kp, vp, paddle.to_tensor(row),
-                            paddle.to_tensor(np.array([matched], np.int32)))
-                            for kp, vp in self._pools]
-                    else:
-                        rt = paddle.to_tensor(row)
-                        mt = paddle.to_tensor(np.array([matched], np.int32))
-                        caches = [PagedCacheSlot(kp, vp, rt, mt)
-                                  for kp, vp in self._pools]
-                    next_ids, stats, caches = self._step_fn(
-                        paddle.to_tensor(ids_np),
-                        paddle.to_tensor(np.arange(matched, matched + Pb,
-                                                   dtype=np.int32)),
-                        caches,
-                        paddle.to_tensor(np.array([S - 1], np.int32)))
-                    self._store_pools(caches)
+                    with RecordEvent("serving.stage"):
+                        args = (paddle.to_tensor(ids_np),
+                                paddle.to_tensor(np.arange(
+                                    matched, matched + Pb, dtype=np.int32)),
+                                self._caches(
+                                    row, np.array([matched], np.int32)),
+                                paddle.to_tensor(np.array([S - 1], np.int32)))
+                    with RecordEvent("serving.launch"):
+                        next_ids, stats, caches = self._step_fn(*args)
+                        self._store_pools(caches)
             except Exception as exc:
                 # the request is popped and holds blocks but is NOT packed
                 # into the grid: release everything (free() drops fresh
@@ -999,17 +997,19 @@ class ContinuousBatchingScheduler:
                 arr, _stats_np, sync_s = self._fetch_tokens(next_ids)
                 if trace is not None:
                     trace.subspan("sampling_sync", sync_s)
-                tok = int(arr[0])
-                self._next_tok[slot] = tok
-                t0 = pc()
-                req.emit(tok)
-                stream_s = pc() - t0
-                self._events.append((req.request_id, tok))
-                self.metrics.generated_tokens += 1
-                if req.eos_token_id is not None and tok == req.eos_token_id:
-                    finished.append(self._retire(slot, "eos"))
-                elif req.num_generated >= req.max_new_tokens:
-                    finished.append(self._retire(slot, "length"))
+                with RecordEvent("serving.commit"):
+                    tok = int(arr[0])
+                    self._next_tok[slot] = tok
+                    t0 = pc()
+                    req.emit(tok)
+                    stream_s = pc() - t0
+                    self._events.append((req.request_id, tok))
+                    self.metrics.generated_tokens += 1
+                    if (req.eos_token_id is not None
+                            and tok == req.eos_token_id):
+                        finished.append(self._retire(slot, "eos"))
+                    elif req.num_generated >= req.max_new_tokens:
+                        finished.append(self._retire(slot, "length"))
             # attribute this admission's host time (device prefill excluded)
             self.stall.record("radix_match", radix_s)
             self.stall.record("block_accounting", block_s)
@@ -1061,23 +1061,15 @@ class ContinuousBatchingScheduler:
             try:
                 inject("serving.prefill")
                 with RecordEvent("serving.prefill"), paddle.no_grad():
-                    if self._donate:
-                        caches = [PagedCacheSlot(
-                            kp, vp, paddle.to_tensor(row),
-                            paddle.to_tensor(posv))
-                            for kp, vp in self._pools]
-                    else:
-                        rt = paddle.to_tensor(row)
-                        mt = paddle.to_tensor(posv)
-                        caches = [PagedCacheSlot(kp, vp, rt, mt)
-                                  for kp, vp in self._pools]
-                    next_ids, caches = self._chunk_step(
-                        paddle.to_tensor(ids_np),
-                        paddle.to_tensor(np.arange(off, off + C,
-                                                   dtype=np.int32)),
-                        caches,
-                        paddle.to_tensor(np.array([n - 1], np.int32)))
-                    self._store_pools(caches)
+                    with RecordEvent("serving.stage"):
+                        args = (paddle.to_tensor(ids_np),
+                                paddle.to_tensor(np.arange(
+                                    off, off + C, dtype=np.int32)),
+                                self._caches(row, posv),
+                                paddle.to_tensor(np.array([n - 1], np.int32)))
+                    with RecordEvent("serving.launch"):
+                        next_ids, caches = self._chunk_step(*args)
+                        self._store_pools(caches)
             except Exception as exc:
                 site = self._fault_site(exc, "serving.prefill")
                 if classify_error(exc) == "fatal":
@@ -1146,17 +1138,19 @@ class ContinuousBatchingScheduler:
                 arr, _stats_np, sync_s = self._fetch_tokens(next_ids)
                 if trace is not None:
                     trace.subspan("sampling_sync", sync_s)
-                tok = int(arr[0])
-                self._next_tok[slot] = tok
-                t0 = pc()
-                req.emit(tok)
-                self.stall.record("streaming", pc() - t0)
-                self._events.append((req.request_id, tok))
-                self.metrics.generated_tokens += 1
-                if req.eos_token_id is not None and tok == req.eos_token_id:
-                    finished.append(self._retire(slot, "eos"))
-                elif req.num_generated >= req.max_new_tokens:
-                    finished.append(self._retire(slot, "length"))
+                with RecordEvent("serving.commit"):
+                    tok = int(arr[0])
+                    self._next_tok[slot] = tok
+                    t0 = pc()
+                    req.emit(tok)
+                    self.stall.record("streaming", pc() - t0)
+                    self._events.append((req.request_id, tok))
+                    self.metrics.generated_tokens += 1
+                    if (req.eos_token_id is not None
+                            and tok == req.eos_token_id):
+                        finished.append(self._retire(slot, "eos"))
+                    elif req.num_generated >= req.max_new_tokens:
+                        finished.append(self._retire(slot, "length"))
         return finished
 
     @holds_lock("_elock")
@@ -1257,7 +1251,8 @@ class ContinuousBatchingScheduler:
         self.metrics.decode_steps += 1
         if stats_np is not None:
             self._note_telemetry(stats_np)
-        finished += self._commit_decode(pairs, arr, metered=True)
+        with RecordEvent("serving.commit"):
+            finished += self._commit_decode(pairs, arr, metered=True)
         return finished
 
     # ---- speculative decoding (serving/spec/) --------------------------
@@ -1292,8 +1287,7 @@ class ContinuousBatchingScheduler:
                 return finished
             props = np.zeros((S, k), np.int32)
             plen = np.zeros(S, np.int32)
-            with self.stall.timed("spec_propose"), \
-                    RecordEvent("serving.spec_propose"):
+            with self.stall.timed("spec_propose"):
                 for s, req in pairs:
                     p = self._proposer.propose(req.resume_ids, k)
                     if p is not None and len(p):
@@ -1328,7 +1322,8 @@ class ContinuousBatchingScheduler:
             break
         self.metrics.decode_steps += 1
         self._spec_steps += 1
-        finished += self._commit_spec(pairs, arr, plen)
+        with RecordEvent("serving.commit"):
+            finished += self._commit_spec(pairs, arr, plen)
         # committed state is complete and exact — rebuild the next
         # dispatch's inputs from host state rather than the carry
         self._carry = None
@@ -1347,17 +1342,20 @@ class ContinuousBatchingScheduler:
         S, k = self.config.max_num_seqs, int(self.config.spec_k)
         inject("serving.decode_step")
         with RecordEvent("serving.decode_step"), paddle.no_grad():
-            ids = np.zeros((S, k + 1), np.int32)
-            ids[:, 0] = self._next_tok
-            ids[:, 1:] = props
-            pos = (self._disp_pos[:, None]
-                   + np.arange(k + 1, dtype=np.int32)[None, :])
-            np.clip(pos, 0, self.max_seq_len - 1, out=pos)
-            caches = self._caches(self._disp_table(), self._disp_pos.copy())
-            out, caches = self._spec_step(
-                paddle.to_tensor(ids),
-                paddle.to_tensor(pos.astype(np.int32)), caches)
-            self._store_pools(caches)
+            with RecordEvent("serving.stage"):
+                ids = np.zeros((S, k + 1), np.int32)
+                ids[:, 0] = self._next_tok
+                ids[:, 1:] = props
+                pos = (self._disp_pos[:, None]
+                       + np.arange(k + 1, dtype=np.int32)[None, :])
+                np.clip(pos, 0, self.max_seq_len - 1, out=pos)
+                args = (paddle.to_tensor(ids),
+                        paddle.to_tensor(pos.astype(np.int32)),
+                        self._caches(self._disp_table(),
+                                     self._disp_pos.copy()))
+            with RecordEvent("serving.launch"):
+                out, caches = self._spec_step(*args)
+                self._store_pools(caches)
         return out
 
     @holds_lock("_elock")
@@ -1487,19 +1485,23 @@ class ContinuousBatchingScheduler:
         t0 = pc()
         inject("serving.decode_step")
         with RecordEvent("serving.decode_step"), paddle.no_grad():
-            ids = self._decode_ids()
-            pos = self._disp_pos.reshape(S, 1).astype(np.int32)
-            # fresh copy: _disp_pos is mutated in place right below, and a
-            # long-lived host buffer crossing the jax boundary while a
-            # dispatched-but-unexecuted step still refers to it is exactly
-            # the stale-transfer hazard async dispatch exposes
-            caches = self._caches(self._disp_table(), self._disp_pos.copy())
-            t_call = pc()
-            next_ids, stats, caches = self._step_fn(
-                ids, paddle.to_tensor(pos), caches,
-                paddle.to_tensor(np.zeros(S, np.int32)))
-            call_s = pc() - t_call
-            self._store_pools(caches)
+            with RecordEvent("serving.stage"):
+                # fresh copy: _disp_pos is mutated in place right below,
+                # and a long-lived host buffer crossing the jax boundary
+                # while a dispatched-but-unexecuted step still refers to it
+                # is exactly the stale-transfer hazard async dispatch
+                # exposes
+                args = (self._decode_ids(),
+                        paddle.to_tensor(
+                            self._disp_pos.reshape(S, 1).astype(np.int32)),
+                        self._caches(self._disp_table(),
+                                     self._disp_pos.copy()),
+                        paddle.to_tensor(np.zeros(S, np.int32)))
+            with RecordEvent("serving.launch"):
+                t_call = pc()
+                next_ids, stats, caches = self._step_fn(*args)
+                call_s = pc() - t_call
+                self._store_pools(caches)
         for s, _req in pairs:
             self._disp_pos[s] += 1
             self._disp_emitted[s] += 1
@@ -1905,37 +1907,36 @@ class ContinuousBatchingScheduler:
         iteration are collected from the drain thread — a request can
         finish up to ``depth`` iterations after its last token was
         dispatched, never later than the next barrier."""
-        was_training = self.model.training
-        self.model.eval()
-        t0 = _time.perf_counter()
-        pre_prefill = self.metrics.prefill_tokens
-        pre_gen = self.metrics.generated_tokens
-        pre_preempt = self.metrics.preemptions
-        pre_hit = (self.prefix_cache._hit_tokens
-                   if self.prefix_cache is not None else 0)
-        self._step_evicted = 0
-        self._step_chunked_tokens = 0
-        self._step_faults = {}
-        done = self._sweep_expired()
-        level = self._apply_degradation()
-        try:
-            with self._elock:
-                if self.dispatch_depth == 0:
-                    done += self._admit()
-                    done += self._prefill_chunks()
-                    if self._spec_step is not None:
-                        done += self._spec_decode_once()
-                    else:
-                        done += self._decode_once()
-                else:
-                    self._raise_drain_exc()
-                    done += self._admit()
-                    done += self._prefill_chunks()
+        with RecordEvent("serving.step"):
+            was_training = self.model.training
+            self.model.eval()
+            t0 = _time.perf_counter()
+            pre_prefill = self.metrics.prefill_tokens
+            pre_gen = self.metrics.generated_tokens
+            pre_preempt = self.metrics.preemptions
+            pre_hit = (self.prefix_cache._hit_tokens
+                       if self.prefix_cache is not None else 0)
+            self._step_evicted = 0
+            self._step_chunked_tokens = 0
+            self._step_faults = {}
+            with RecordEvent("serving.sweep"):
+                done = self._sweep_expired()
+                level = self._apply_degradation()
+            try:
+                with self._elock:
+                    if self.dispatch_depth:
+                        self._raise_drain_exc()
+                    with RecordEvent("serving.admit"):
+                        done += self._admit()
+                        done += self._prefill_chunks()
                     if self._spec_step is not None:
                         # speculation's accepted length is data the next
                         # step's positions depend on: the verify path is
-                        # synchronous (it drains in-flight work first)
+                        # synchronous at every depth (it drains in-flight
+                        # work first)
                         done += self._spec_decode_once()
+                    elif self.dispatch_depth == 0:
+                        done += self._decode_once()
                     elif (not self._decode_dispatch_once()
                             and self._inflight):
                         # nothing dispatchable but steps still in flight
@@ -1944,75 +1945,76 @@ class ContinuousBatchingScheduler:
                         self._drain_all()
                     else:
                         self._backpressure()
-                done += self._collect_async_done()
-        except KVPoolExhausted as exc:
-            # allocation failure surfaces WITH forensics: the full owner
-            # census + the flight-recorder tail ride on the exception
-            # (``exc.device_memory_census``) instead of a bare message,
-            # and one correlated postmortem bundle freezes for later
-            if self.device_ledger is not None:
-                self.device_ledger.attach_forensics(
-                    exc, flight_tail=self.flight.dump(last=8))
-            self.postmortems.capture("kv_pool_exhausted", str(exc))
-            raise
-        finally:
-            if was_training:
-                self.model.train()
-        # a request can retire twice in one iteration's view (e.g. its
-        # final token drained during a sweep's cancel barrier AND was
-        # collected from the drain thread) — report each once
-        outs: List[RequestOutput] = []
-        seen = set()
-        for r in done:
-            if r.request_id not in seen:
-                seen.add(r.request_id)
-                outs.append(r.output())
-        step_s = _time.perf_counter() - t0
-        self.metrics.step_time.record(step_s)
-        if self._watchdog is not None:
-            self._watchdog.observe(step_s)
-        with self._elock:
-            in_flight = len(self._inflight)
-        self.metrics.observe_gauges(
-            queue_depth=len(self.queue),
-            running=sum(r is not None for r in self._slots),
-            allocator=self.allocator, live_tokens=self._live_tokens(),
-            dispatch_depth=self.dispatch_depth,
-            in_flight_steps=in_flight)
-        record = dict(
-            running=sum(r is not None for r in self._slots),
-            queue_depth=len(self.queue),
-            free_blocks=self.allocator.num_free_blocks,
-            prefill_tokens=self.metrics.prefill_tokens - pre_prefill,
-            generated_tokens=self.metrics.generated_tokens - pre_gen,
-            preemptions=self.metrics.preemptions - pre_preempt,
-            cache_hit_tokens=((self.prefix_cache._hit_tokens
-                               if self.prefix_cache is not None else 0)
-                              - pre_hit),
-            evicted_blocks=self._step_evicted,
-            finished=len(outs))
-        # engine fields land in the flight ring ONLY at depth > 0 —
-        # synchronous-baseline dumps stay byte-stable
-        if self.dispatch_depth:
-            record["dispatch_depth"] = self.dispatch_depth
-            record["in_flight_steps"] = in_flight
-        # chunk-pump split lands ONLY when chunking is on (same rule)
-        if self._chunk_step is not None:
-            record["chunked_tokens"] = self._step_chunked_tokens
-        # armed/fired injection state and shed level land in the flight
-        # ring ONLY when active — fault-free dumps stay byte-stable
-        inj = get_injector()
-        if inj.armed:
-            record["fault_plan"] = list(inj.armed_sites)
-        if self._step_faults:
-            record["faults"] = sum(self._step_faults.values())
-            record["fault_sites"] = dict(self._step_faults)
-        if level > LEVEL_OK:
-            record["degradation"] = level
-        self.flight.record_step(**record)
-        if self.prefix_cache is not None:
-            self._alarms.observe_evictions(self._step_evicted)
-        return outs
+                    done += self._collect_async_done()
+            except KVPoolExhausted as exc:
+                # allocation failure surfaces WITH forensics: the full owner
+                # census + the flight-recorder tail ride on the exception
+                # (``exc.device_memory_census``) instead of a bare message,
+                # and one correlated postmortem bundle freezes for later
+                if self.device_ledger is not None:
+                    self.device_ledger.attach_forensics(
+                        exc, flight_tail=self.flight.dump(last=8))
+                self.postmortems.capture("kv_pool_exhausted", str(exc))
+                raise
+            finally:
+                if was_training:
+                    self.model.train()
+            with RecordEvent("serving.account"):
+                # a request can retire twice in one iteration's view (e.g. its
+                # final token drained during a sweep's cancel barrier AND was
+                # collected from the drain thread) — report each once
+                outs: List[RequestOutput] = []
+                seen = set()
+                for r in done:
+                    if r.request_id not in seen:
+                        seen.add(r.request_id)
+                        outs.append(r.output())
+                step_s = _time.perf_counter() - t0
+                self.metrics.step_time.record(step_s)
+                if self._watchdog is not None:
+                    self._watchdog.observe(step_s)
+                with self._elock:
+                    in_flight = len(self._inflight)
+                self.metrics.observe_gauges(
+                    queue_depth=len(self.queue),
+                    running=sum(r is not None for r in self._slots),
+                    allocator=self.allocator, live_tokens=self._live_tokens(),
+                    dispatch_depth=self.dispatch_depth,
+                    in_flight_steps=in_flight)
+                record = dict(
+                    running=sum(r is not None for r in self._slots),
+                    queue_depth=len(self.queue),
+                    free_blocks=self.allocator.num_free_blocks,
+                    prefill_tokens=self.metrics.prefill_tokens - pre_prefill,
+                    generated_tokens=self.metrics.generated_tokens - pre_gen,
+                    preemptions=self.metrics.preemptions - pre_preempt,
+                    cache_hit_tokens=((self.prefix_cache._hit_tokens
+                                       if self.prefix_cache is not None else 0)
+                                      - pre_hit),
+                    evicted_blocks=self._step_evicted,
+                    finished=len(outs))
+                # engine fields land in the flight ring ONLY at depth > 0 —
+                # synchronous-baseline dumps stay byte-stable
+                if self.dispatch_depth:
+                    record["dispatch_depth"] = self.dispatch_depth
+                    record["in_flight_steps"] = in_flight
+                # chunk-pump split lands ONLY when chunking is on (same rule)
+                if self._chunk_step is not None:
+                    record["chunked_tokens"] = self._step_chunked_tokens
+                # armed/fired injection state and shed level land in the flight
+                # ring ONLY when active — fault-free dumps stay byte-stable
+                inj = get_injector()
+                if inj.armed:
+                    record["fault_plan"] = list(inj.armed_sites)
+                if self._step_faults:
+                    record["faults"] = sum(self._step_faults.values())
+                    record["fault_sites"] = dict(self._step_faults)
+                if level > LEVEL_OK:
+                    record["degradation"] = level
+                self.flight.record_step(**record)
+                if self.prefix_cache is not None:
+                    self._alarms.observe_evictions(self._step_evicted)
+            return outs
 
     def _pool_pressure(self) -> float:
         """Pool pressure for the shed ladder: allocated blocks MINUS the
