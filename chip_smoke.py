@@ -305,6 +305,39 @@ def phase_kernels(rehearse: bool) -> dict:
         _close(vk, vr, 1e-3, "flash attention loss")
         for got, want, what in zip(gk, gr, ("dq", "dk", "dv")):
             _close(got, want, 3e-2, f"flash attention {what}")
+    # paged decode attention vs the gather formulation: ragged live
+    # lengths, scattered pages, an idle row (table -1, pos 0) among them.
+    # On the chip the decode program's shape, through the gate
+    from paddle_tpu.models import kv_cache as kvc
+    from paddle_tpu.ops.pallas import paged_attention as pad
+
+    B, MB, NB, dtype = (4, 5, 24, jnp.float32) if rehearse else \
+        (32, 128, 3480, jnp.bfloat16)
+    Hh, Dd, bs = (8 if rehearse else 16), 128, 16
+    pad._interpret = interpret
+    kp, vp = (normal((NB, bs, Hh, Dd), dtype) for _ in "kv")
+    q = normal((B, 1, Hh, Dd), dtype)
+    pos = rng.integers(1, MB * bs, B).astype(np.int32)
+    pos[0], pos[1] = MB * bs - 1, 0
+    table = rng.integers(0, NB, (B, MB)).astype(np.int32)
+    table[1] = -1
+    table, pos = jnp.asarray(table), jnp.asarray(pos)
+    got = timed("paged_attention_decode", jax.jit(kvc._paged_attend),
+                q, kp, vp, table, pos)
+    _check(kvc._last_path == "pallas",
+           "a paged cache step with one query token a row selects the "
+           "Pallas kernel" + (" (forced: interpret mode)" if rehearse
+                              else " on the chip"))
+    want = jax.jit(kvc._paged_attend_xla)(q, kp, vp, table, pos)
+    live = np.arange(B) != 1
+    _close(np.asarray(got, np.float32)[live],
+           np.asarray(want, np.float32)[live],
+           1e-5 if rehearse else 2e-2,
+           f"paged_attention_decode vs the gathered pages ({B} rows, "
+           f"{MB} pages a row, {jnp.dtype(dtype).name})")
+    _check(np.isfinite(np.asarray(got, np.float32)).all(),
+           "the idle row's result is finite")
+    pad._interpret = False
     for name, (setup_s, run_s) in times.items():
         _say(f"  time {name}: set-up {setup_s} s, run {run_s} s")
     return {"kernels": sorted(times)}
@@ -324,11 +357,14 @@ def _model_config(rehearse: bool, **kw):
     return cfg
 
 
-def _last_logits(forward, cfg, ids, block_size: int = 0, shard_pools=None):
+def _last_logits(forward, cfg, ids, block_size: int = 0, shard_pools=None,
+                 decode_last: bool = False):
     """Last-position logits of ``forward`` for one prompt, as fp32: the
     plain ``forward(ids)`` when ``block_size`` is 0, else
     ``forward(ids, position_ids, caches)`` through a fresh paged cache
-    (``shard_pools`` splits its pools over a mesh first)."""
+    (``shard_pools`` splits its pools over a mesh first): the whole prompt
+    in one call, or with ``decode_last`` all but its last token and then
+    that token alone, which is a decode step."""
     import numpy as np
 
     import paddle_tpu as paddle
@@ -350,9 +386,12 @@ def _last_logits(forward, cfg, ids, block_size: int = 0, shard_pools=None):
             caches = [PagedCacheSlot(kp, vp, table,
                                      paddle.zeros([1], dtype="int32"))
                       for kp, vp in pools]
-            out, _ = forward(paddle.to_tensor(ids),
-                             paddle.to_tensor(np.arange(n, dtype=np.int32)),
-                             caches)
+            for lo, hi in ((0, n - 1), (n - 1, n)) if decode_last \
+                    else ((0, n),):
+                out, caches = forward(
+                    paddle.to_tensor(ids[:, lo:hi]),
+                    paddle.to_tensor(np.arange(lo, hi, dtype=np.int32)),
+                    caches)
     last = np.asarray(out.numpy(), np.float32)[0, -1]
     _check(last.shape == (cfg.vocab_size,) and np.isfinite(last).all(),
            f"logits finite, shape {last.shape}")
@@ -388,6 +427,24 @@ def phase_serve(rehearse: bool, tp: int = 0) -> dict:
     _close(paged, plain, LOGITS_RTOL,
            "paged-cache logits against the eager forward")
     logits_err = float(np.abs(paged - plain).max())
+    # the same position as a decode step (one query token): on the chip
+    # through the Pallas kernel, which no fallback may replace in silence
+    from paddle_tpu.models import kv_cache as kvc
+
+    decoded = _last_logits(model, cfg, ids, size["block_size"],
+                           decode_last=True)
+    if rehearse:
+        _say(f"  the paged decode kernel did NOT run: gpt_tiny's head size "
+             f"{cfg.hidden_size // cfg.num_heads} is not one its gate "
+             f"accepts (a multiple of 128); path {kvc._last_path!r}")
+    else:
+        _check(kvc._last_path == "pallas",
+               "the eager decode step took the Pallas paged-attention "
+               "kernel")
+    _close(decoded, plain, LOGITS_RTOL,
+           "decode-step logits through the paged cache against the eager "
+           "forward (PR 21 read 1.2 % for the prefill-only check)")
+    decode_err = float(np.abs(decoded - plain).max())
 
     heads, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
     block_bytes = cfg.num_layers * 2 * size["block_size"] * heads * hd * 2
@@ -456,7 +513,15 @@ def phase_serve(rehearse: bool, tp: int = 0) -> dict:
                f"'length'/'eos' with their full token count (not: {bad})")
         return [[int(x) for x in outs[r].generated_ids] for r in rids]
 
-    warm = batch()                       # compiles every shape
+    # one request alone first: its prefill traces, then the decode program,
+    # so the gate's last record is the decode program's
+    sched.add_request(prompts[0], max_new_tokens=2)
+    sched.run()
+    want_path = "xla" if rehearse or tp else "pallas"
+    _check(kvc._last_path == want_path,
+           f"the scheduler's decode program took the {want_path!r} paged "
+           f"attention (no silent fallback)")
+    warm = batch()                       # compiles every other shape
     setup_s = time.perf_counter() - t0
     sched.mark_steady()
     t1 = time.perf_counter()
@@ -482,6 +547,7 @@ def phase_serve(rehearse: bool, tp: int = 0) -> dict:
     _say(f"  peak HBM (serve): {_gib(peak)}")
     digest = hashlib.sha256(json.dumps(warm).encode()).hexdigest()[:16]
     return {"tokens_sha": digest, "logits_err": logits_err,
+            "decode_logits_err": decode_err,
             "setup_s": round(setup_s, 1), "run_s": round(run_s, 1),
             "peak_hbm_bytes": peak, "pool_blocks": scfg.total_blocks,
             "compiles": compiles.report()}

@@ -10,8 +10,10 @@ python/paddle/incubate/nn/functional/ — re-designed TPU-first:
   SAME XLA program (no shape-driven recompiles); writes are per-batch
   ``lax.dynamic_update_slice`` and validity comes from a length mask.
 - The paged variant keeps K/V in a block pool indexed by per-sequence block
-  tables (vLLM-style), enabling continuous batching without moving memory;
-  gathers ride XLA's fused gather, not pointer chasing.
+  tables (vLLM-style), enabling continuous batching without moving memory.
+  A decode step (one query token a row) on a TPU reads only the live pages,
+  in place, through ``ops/pallas/paged_attention.py``; every other paged
+  call gathers the table's pages with XLA's fused gather.
 """
 
 from __future__ import annotations
@@ -93,53 +95,102 @@ def static_cache_update_attend(q, k, v, slot: StaticCacheSlot):
     return out, StaticCacheSlot(ck2, cv2, pos2)
 
 
-def _paged_cache_raw(qv, kv, vv, k_pool, v_pool, block_table, pos):
-    """Paged write + gather + masked attention (decode: s small, usually 1)."""
-    B, s, n_heads, D = qv.shape
-    block_size = k_pool.shape[1]
+def _paged_write(pool, new, block_table, pos):
+    """Scatter the s new tokens of each row into their pages: token t of
+    row b lands in pool[block_table[b, (pos[b]+t)//bs], (pos[b]+t)%bs]."""
+    s = new.shape[1]
+    block_size = pool.shape[1]
     max_blocks = block_table.shape[1]
-    L = max_blocks * block_size
+    tok_pos = pos[:, None] + jnp.arange(s)[None, :]              # [B, s]
+    blk_slot = tok_pos // block_size
+    blk = jnp.take_along_axis(block_table,
+                              jnp.clip(blk_slot, 0, max_blocks - 1),
+                              axis=1)                            # [B, s]
+    off = tok_pos % block_size                                   # [B, s]
+    flat = pool.reshape(-1, *pool.shape[2:])                     # [NB*bs, H, D]
+    idx = (blk * block_size + off).reshape(-1)                   # [B*s]
+    # unallocated (-1) or out-of-table positions must NOT wrap into
+    # another sequence's block: route them out of bounds and drop
+    valid = ((blk >= 0) & (blk_slot < max_blocks)).reshape(-1)
+    idx = jnp.where(valid, idx, flat.shape[0])
+    return flat.at[idx].set(
+        new.reshape(-1, *new.shape[2:]).astype(pool.dtype),
+        mode="drop",
+    ).reshape(pool.shape)
 
-    # scatter the s new tokens of each sequence into their pages
-    def write(pool, new):
-        # token t of batch b lands in pool[block_table[b, (pos[b]+t)//bs],
-        #                                  (pos[b]+t)%bs]
-        tok_pos = pos[:, None] + jnp.arange(s)[None, :]          # [B, s]
-        blk_slot = tok_pos // block_size
-        blk = jnp.take_along_axis(block_table,
-                                  jnp.clip(blk_slot, 0, max_blocks - 1),
-                                  axis=1)                        # [B, s]
-        off = tok_pos % block_size                               # [B, s]
-        flat = pool.reshape(-1, *pool.shape[2:])                 # [NB*bs, H, D]
-        idx = (blk * block_size + off).reshape(-1)               # [B*s]
-        # unallocated (-1) or out-of-table positions must NOT wrap into
-        # another sequence's block: route them out of bounds and drop
-        valid = ((blk >= 0) & (blk_slot < max_blocks)).reshape(-1)
-        idx = jnp.where(valid, idx, flat.shape[0])
-        return flat.at[idx].set(
-            new.reshape(-1, *new.shape[2:]).astype(pool.dtype),
-            mode="drop",
-        ).reshape(pool.shape)
 
-    # gather this sequence's pages into a contiguous [B, L, KVH, D] view
+def _paged_attend_xla(qv, k_pool, v_pool, block_table, pos):
+    """Gather every page of every row's table into a contiguous
+    [B, L, KVH, D] view, then length-masked attention: the formulation for
+    s > 1 (prefill, chunks, the verify step), for the CPU, and for the
+    sharded step (GSPMD does not partition a Pallas kernel)."""
+    B, n_heads = qv.shape[0], qv.shape[2]
+    L = block_table.shape[1] * k_pool.shape[1]
+
     def gather(pool):
         safe = jnp.maximum(block_table, 0)                       # [B, MB]
         pages = pool[safe]                                       # [B, MB, bs, H, D]
         return pages.reshape(B, L, *pool.shape[2:])
 
     with region("kv_gather"):
-        k_pool2 = write(k_pool, kv)
-        v_pool2 = write(v_pool, vv)
-        keys = gather(k_pool2)
-        values = gather(v_pool2)
-    out = _masked_attention(qv, _repeat_kv(keys, n_heads),
-                            _repeat_kv(values, n_heads), pos)
-    return out, k_pool2, v_pool2, pos + s
+        keys = gather(k_pool)
+        values = gather(v_pool)
+    return _masked_attention(qv, _repeat_kv(keys, n_heads),
+                             _repeat_kv(values, n_heads), pos)
+
+
+# evidence trail: "pallas" | "xla", set on every trace of the paged attend
+# so tests and chip_smoke.py can assert which path the gate selected
+_last_path = None
+
+
+def _use_paged_kernel(ker, qv, pool) -> bool:
+    """The one gate, decided at trace time from what the code can observe:
+    the decode kernel ``ker`` iff there is one query token a row, the
+    platform is a TPU (or a test runs the kernel through the interpreter),
+    and the shapes are ones the kernel supports. A kernel that was selected
+    and fails raises."""
+    if qv.shape[1] != 1:
+        return False
+    if not ker._interpret:
+        from paddle_tpu.device import is_tpu
+
+        if not is_tpu():
+            return False
+    return ker.supports((qv.shape[0],) + tuple(qv.shape[2:]), qv.dtype,
+                        pool.shape, pool.dtype)
+
+
+def _paged_attend(qv, k_pool, v_pool, block_table, pos):
+    """Attention of qv [B,s,H,D] over the updated pool: position t of row b
+    sees the pos[b] + t + 1 positions its table names."""
+    global _last_path
+    from paddle_tpu.ops.pallas import paged_attention as ker
+
+    if _use_paged_kernel(ker, qv, k_pool):
+        _last_path = "pallas"
+        with region("attention"):
+            out = ker.paged_attention_decode(
+                qv[:, 0], k_pool, v_pool, block_table, pos + 1)
+        return out[:, None]
+    _last_path = "xla"
+    return _paged_attend_xla(qv, k_pool, v_pool, block_table, pos)
+
+
+def _paged_cache_raw(qv, kv, vv, k_pool, v_pool, block_table, pos):
+    """Paged write, then attention over the updated pool."""
+    with region("kv_gather"):
+        k_pool2 = _paged_write(k_pool, kv, block_table, pos)
+        v_pool2 = _paged_write(v_pool, vv, block_table, pos)
+    out = _paged_attend(qv, k_pool2, v_pool2, block_table, pos)
+    return out, k_pool2, v_pool2, pos + qv.shape[1]
 
 
 def paged_cache_update_attend(q, k, v, slot: PagedCacheSlot):
-    """block_multihead_attention analogue: write into the block pool through
-    the block table, then attend over the gathered pages."""
+    """block_multihead_attention analogue: write the new tokens into the
+    block pool through the block table, then attend over the live pages
+    (one query token a row on a TPU: the Pallas kernel reads them in place)
+    or over the gathered table (everything else)."""
     out, kp2, vp2, pos2 = apply(
         "paged_cache_attention", _paged_cache_raw, q, k, v,
         slot.k_pool, slot.v_pool, slot.block_table, slot.pos)

@@ -118,6 +118,18 @@ def shard_model_params(model, mesh: Mesh, plan: str = "exact"):
             jax.device_put(p._value, NamedSharding(mesh, spec)))
 
 
+def _paged_cache_xla(q, k, v, k_pool, v_pool, block_table, pos):
+    """The paged cache step in the XLA formulation BY NAME: the write, then
+    attention over the gathered table. GSPMD does not partition a Pallas
+    kernel, and a trace cannot see a sharding, so the gate of
+    ``kv_cache._paged_attend`` could not keep its decode kernel out of here."""
+    with region("kv_gather"):
+        k_pool2 = kv_cache._paged_write(k_pool, k, block_table, pos)
+        v_pool2 = kv_cache._paged_write(v_pool, v, block_table, pos)
+    out = kv_cache._paged_attend_xla(q, k_pool2, v_pool2, block_table, pos)
+    return out, k_pool2, v_pool2, pos + q.shape[1]
+
+
 class ShardedSlotStep(SlotStep):
     """`SlotStep` lowered under a tp mesh.
 
@@ -187,7 +199,14 @@ class ShardedSlotStep(SlotStep):
         # head-sharded paged write + gather + masked attention: pool scatter
         # and block-table gather index dim 0 only, attention einsums are
         # per-head — no collective anywhere in here
-        out, new_cache = kv_cache.cache_update_attend(q, k, v, cache)
+        if isinstance(cache, kv_cache.PagedCacheSlot):
+            out, kp2, vp2, pos2 = apply(
+                "paged_cache_attention", _paged_cache_xla, q, k, v,
+                cache.k_pool, cache.v_pool, cache.block_table, cache.pos)
+            new_cache = kv_cache.PagedCacheSlot(kp2, vp2, cache.block_table,
+                                                pos2)
+        else:
+            out, new_cache = kv_cache.cache_update_attend(q, k, v, cache)
         if hasattr(new_cache, "k_pool"):
             # pin the updated pools' head shard as the program OUTPUT
             # layout — otherwise GSPMD is free to replicate them and the

@@ -1,0 +1,286 @@
+"""The Pallas paged decode-attention kernel (ops/pallas/paged_attention.py)
+and its gate in models/kv_cache.py.
+
+On the CPU the kernel runs through the Pallas interpreter (``_interpret``,
+as flash_attention's splash tests do). Oracle: ``_masked_attention`` over
+the gathered pages, the formulation the kernel replaces for ``s == 1``.
+The last tests compile the kernel for a described v5e at the benchmark's
+real shapes: what the chip's compiler would refuse is refused here.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as paddle
+from paddle_tpu.models import GPTForCausalLM, kv_cache
+from paddle_tpu.models.gpt import GPTConfig
+from paddle_tpu.ops.pallas import paged_attention as pa
+from paddle_tpu.serving import ContinuousBatchingScheduler, SchedulerConfig
+
+BS, D = 16, 128
+TOL = {"float32": 2e-6, "bfloat16": 1.6e-2}   # bf16: 2 ulp of an O(1) result
+
+
+@pytest.fixture()
+def interpreted():
+    pa._interpret = True
+    yield
+    pa._interpret = False
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_aot_replay():
+    """Serving programs compile fresh (tests/test_serving_sched.py says why)."""
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+
+
+def _heads(dtype, gqa):
+    """The least head counts the gate accepts: the KV heads fill a tile."""
+    kvh = pa.sublane_tile(dtype)
+    return (2 * kvh if gqa else kvh), kvh
+
+
+def _case(seed, batch, max_blocks, dtype, gqa, lengths):
+    rng = np.random.default_rng(seed)
+    n_heads, kvh = _heads(dtype, gqa)
+    nb = batch * max_blocks + 3
+    kp, vp = (jnp.asarray(rng.standard_normal((nb, BS, kvh, D)), dtype)
+              for _ in "kv")
+    q = jnp.asarray(rng.standard_normal((batch, 1, n_heads, D)), dtype)
+    # shuffled, non-contiguous pages; block 0 belongs to nobody
+    table = (1 + rng.permutation(nb - 1)[:batch * max_blocks]).reshape(
+        batch, max_blocks).astype(np.int32)
+    pos = np.asarray(lengths, np.int32) - 1
+    return q, kp, vp, table, pos
+
+
+def _oracle(q, kp, vp, table, pos):
+    return np.asarray(kv_cache._paged_attend_xla(
+        q, kp, vp, jnp.asarray(table), jnp.asarray(pos)), np.float32)
+
+
+def _kernel(q, kp, vp, table, pos, **kw):
+    return np.asarray(pa.paged_attention_decode(
+        q[:, 0], kp, vp, jnp.asarray(table), jnp.asarray(pos) + 1, **kw),
+        np.float32)[:, None]
+
+
+@pytest.mark.parametrize("gqa", [False, True], ids=["mha", "gqa"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("length", [1, 15, 16, 17, "page_short", "full"])
+def test_one_row_ragged_length_matches_gather(interpreted, length, dtype, gqa):
+    max_blocks = 3
+    n = {"page_short": (max_blocks - 1) * BS, "full": max_blocks * BS}.get(
+        length, length)
+    case = _case(n, 1, max_blocks, dtype, gqa, [n])
+    # two pages a group: a full table is two groups, the second half live
+    got = _kernel(*case, pages_per_group=2)
+    np.testing.assert_allclose(got, _oracle(*case), atol=TOL[dtype], rtol=0)
+
+
+@pytest.mark.parametrize("pages_per_group", [4, None], ids=["4", "default"])
+@pytest.mark.parametrize("gqa", [False, True], ids=["mha", "gqa"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_batch_of_shuffled_tables_matches_gather(interpreted, dtype, gqa,
+                                                 pages_per_group):
+    """Four rows over 17-page tables (not a multiple of the group): one
+    position, mid-page, one page short of full, full."""
+    max_blocks = 17
+    lengths = [1, 5 * BS + 7, (max_blocks - 1) * BS, max_blocks * BS]
+    case = _case(3, 4, max_blocks, dtype, gqa, lengths)
+    got = _kernel(*case, pages_per_group=pages_per_group)
+    np.testing.assert_allclose(got, _oracle(*case), atol=TOL[dtype], rtol=0)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_idle_rows_leave_live_rows_unchanged_and_finite(interpreted, dtype):
+    """An idle slot's row is all -1 with pos 0: it reads block 0, its result
+    is finite, and the live rows beside it read what they read without it."""
+    lengths = [40, 1, 3 * BS, 1]
+    q, kp, vp, table, pos = _case(5, 4, 3, dtype, False, lengths)
+    # block 0 holds what a NaN-poisoned free block would: the idle rows
+    # read it (clamped -1) but nothing of it may reach a live row
+    kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+    idle = np.array([1, 3])
+    table[idle] = -1
+    pos[idle] = 0
+    got = _kernel(q, kp, vp, table, pos)
+    live = np.array([0, 2])
+    alone = _kernel(q[live], kp, vp, table[live], pos[live])
+    np.testing.assert_array_equal(got[live], alone)
+    np.testing.assert_allclose(got[live], _oracle(
+        q[live], kp, vp, table[live], pos[live]), atol=TOL[dtype], rtol=0)
+    kp, vp = kp.at[0].set(0.0), vp.at[0].set(0.0)
+    assert np.isfinite(_kernel(q, kp, vp, table, pos)).all()
+
+
+def test_stale_scratch_never_reaches_a_result():
+    """Scratch memory starts as NaN bit patterns (the TPU interpreter's
+    ``uninitialized_memory="nan"``): pages past the live length are never
+    copied, and what the buffers held before must not reach ``P x V``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    pa._interpret = pltpu.InterpretParams(uninitialized_memory="nan")
+    try:
+        case = _case(9, 2, 5, "float32", False, [3, 2 * BS + 1])
+        got = _kernel(*case, pages_per_group=4)
+    finally:
+        pa._interpret = False
+    np.testing.assert_allclose(got, _oracle(*case), atol=TOL["float32"],
+                               rtol=0)
+
+
+# ---- the gate ---------------------------------------------------------------
+
+def _attend_args(s=1, d=D, kvh=8, n_heads=8, q_dtype="float32",
+                 pool_dtype="float32"):
+    q = jnp.zeros((2, s, n_heads, d), q_dtype)
+    pool = jnp.zeros((4, BS, kvh, d), pool_dtype)
+    return q, pool, pool, jnp.zeros((2, 2), jnp.int32), jnp.ones(
+        (2,), jnp.int32)
+
+
+@pytest.mark.parametrize("kw, forced, want", [
+    (dict(), False, "xla"),                       # the CPU is not a TPU
+    (dict(), True, "pallas"),
+    (dict(s=2), True, "xla"),                     # prefill, chunks, verify
+    (dict(d=64), True, "xla"),                    # head size not 128 k
+    (dict(kvh=4, n_heads=8), True, "xla"),        # KV heads under a tile
+    (dict(q_dtype="bfloat16"), True, "xla"),      # q and pool differ
+    (dict(q_dtype="float16", pool_dtype="float16"), True, "xla"),
+], ids=["cpu", "forced", "s2", "d64", "kvh4", "mixed", "fp16"])
+def test_gate_table(kw, forced, want):
+    pa._interpret = forced
+    try:
+        kv_cache._last_path = None
+        out = kv_cache._paged_attend(*_attend_args(**kw))
+    finally:
+        pa._interpret = False
+    assert kv_cache._last_path == want
+    assert out.shape == _attend_args(**kw)[0].shape
+
+
+def test_selected_kernel_that_raises_is_not_swallowed(interpreted,
+                                                      monkeypatch):
+    def boom(*a, **k):
+        raise RuntimeError("mosaic refused the kernel")
+
+    monkeypatch.setattr(pa, "paged_attention_decode", boom)
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        kv_cache._paged_attend(*_attend_args())
+    assert kv_cache._last_path == "pallas"
+
+
+def test_kernel_refuses_shapes_its_gate_refuses():
+    q, kp, vp, table, pos = _attend_args(d=64)
+    with pytest.raises(ValueError, match="does not support"):
+        pa.paged_attention_decode(q[:, 0], kp, vp, table, pos)
+
+
+def test_sharded_step_names_the_xla_formulation(interpreted):
+    """The tp step must reach no pallas_call (GSPMD does not partition one):
+    its cache step is the write and ``_paged_attend_xla`` by name, whatever
+    the gate would say of the same shapes."""
+    from paddle_tpu.serving.sharded import step
+
+    q, kp, vp, table, pos = _attend_args()
+    kv_cache._last_path = None
+    out, kp2, vp2, pos2 = step._paged_cache_xla(q, q, q, kp, vp, table, pos)
+    assert kv_cache._last_path is None
+    want = kv_cache._paged_cache_raw(q, q, q, kp, vp, table, pos)
+    assert kv_cache._last_path == "pallas"
+    for got, ref in zip((out, kp2, vp2, pos2), want):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=1e-6)
+    jaxpr = str(jax.make_jaxpr(step._paged_cache_xla)(
+        q, q, q, kp, vp, table, pos))
+    assert "pallas_call" not in jaxpr
+    assert "pallas_call" in str(jax.make_jaxpr(kv_cache._paged_cache_raw)(
+        q, q, q, kp, vp, table, pos))
+
+
+# ---- through the scheduler --------------------------------------------------
+
+@pytest.fixture(scope="module")
+def wide_head_model():
+    """The least GPT whose head size the gate accepts: 8 heads x 128."""
+    paddle.seed(11)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=256, hidden_size=1024, num_layers=2, num_heads=8,
+        max_position_embeddings=64))
+    model.eval()
+    return model
+
+
+def _generate(model, prompts, **cfg):
+    sched = ContinuousBatchingScheduler(model, SchedulerConfig(**cfg))
+    outs = sched.generate(prompts, max_new_tokens=8)
+    return [np.asarray(o) for o in outs], sched.metrics.snapshot()
+
+
+@pytest.mark.parametrize("cfg, preempts", [
+    (dict(max_num_seqs=4, max_seq_len=64, block_size=8), False),
+    # both admit, both cannot finish: the younger is preempted and resumed
+    (dict(max_num_seqs=2, max_seq_len=64, block_size=4, num_blocks=6), True),
+], ids=["roomy", "forced_preemption"])
+def test_scheduler_greedy_tokens_equal_the_xla_path(wide_head_model, cfg,
+                                                    preempts):
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 256, n) for n in ((10, 9) if preempts
+                                                 else (10, 9, 17, 3))]
+    kv_cache._last_path = None
+    want, _ = _generate(wide_head_model, prompts, **cfg)
+    assert kv_cache._last_path == "xla"
+    pa._interpret = True
+    try:
+        got, m = _generate(wide_head_model, prompts, **cfg)
+    finally:
+        pa._interpret = False
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert (m["preemptions"] >= 1) == preempts
+    assert m["free_blocks"] == m["total_blocks"]
+
+
+# ---- the chip's compiler, without the chip -----------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("batch, max_blocks, num_blocks, n_heads, kvh, dtype", [
+    (32, 128, 3480, 16, 16, "bfloat16"),   # the benchmark's decode program
+    (1, 17, 17, 16, 16, "bfloat16"),       # its reference check
+    (4, 17, 64, 32, 16, "bfloat16"),       # GQA
+    (4, 17, 64, 8, 8, "float32"),
+], ids=["decode_1p3b", "check_1p3b", "gqa", "float32"])
+def test_compiles_for_v5e(one_chip, batch, max_blocks, num_blocks, n_heads,
+                          kvh, dtype):
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+
+    pool = shape((num_blocks, BS, kvh, D), dtype)
+    compiled = jax.jit(pa.paged_attention_decode).lower(
+        shape((batch, n_heads, D), dtype), pool, pool,
+        shape((batch, max_blocks), "int32"),
+        shape((batch,), "int32")).compile()
+    text = compiled.as_text()
+    assert "paged_attention_decode" in text and "tpu_custom_call" in text
+    # the pool is read in place: no copy of it, no scratch of its size
+    pool_bytes = num_blocks * BS * kvh * D * jnp.dtype(dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
