@@ -277,6 +277,11 @@ class GPTForCausalLM(nn.Layer):
                 cfg.hidden_size, cfg.vocab_size, has_bias=False,
                 gather_output=False)
 
+    def cache_geometry(self):
+        """One class of layers: every layer caches the same KV heads and
+        one width for K and V (``kv_cache.uniform_cache_geometry``)."""
+        return kv_cache.uniform_cache_geometry(self.config)
+
     def forward(self, input_ids, position_ids=None, caches=None):
         if caches is not None:
             h, new_caches = self.gpt(input_ids, position_ids, caches)

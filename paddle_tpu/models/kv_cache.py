@@ -12,8 +12,16 @@ python/paddle/incubate/nn/functional/ — re-designed TPU-first:
 - The paged variant keeps K/V in a block pool indexed by per-sequence block
   tables (vLLM-style), enabling continuous batching without moving memory.
   A decode step (one query token a row) on a TPU reads only the live pages,
-  in place, through ``ops/pallas/paged_attention.py``; every other paged
-  call gathers the table's pages with XLA's fused gather.
+  in place, through ``ops/pallas/paged_attention.py`` (or its sibling
+  ``paged_attention_gqa.py`` for pools with the KV heads folded into the
+  row); every other paged call gathers the table's pages with XLA's fused
+  gather.
+- Layers may differ in what they cache (``LayerCacheGeometry``, one answer
+  from the model for the scheduler and ``DecodeEngine``): KV heads, K and V
+  row widths, and a window. A window layer's paged slot carries ``base``:
+  its table holds only the pages the window still reaches (a second class
+  of blocks in the scheduler), and a layer passes its ``window`` and
+  ``sink`` to ``cache_update_attend``.
 """
 
 from __future__ import annotations
@@ -34,41 +42,225 @@ from paddle_tpu.tensor import Tensor
 # k, v: [B, max_len, KVH, D]; pos: [B] int32 — number of tokens already cached
 StaticCacheSlot = namedtuple("StaticCacheSlot", ["k", "v", "pos"])
 
-# k_pool, v_pool: [num_blocks, block_size, KVH, D]; block_table: [B, max_blocks]
-# int32 (block ids, -1 = unallocated); pos: [B] int32
+# k_pool [num_blocks, block_size, KVH, Dk], v_pool [.., KVH, Dv] (K and V may
+# differ in width), or with the KV heads folded into the row, [num_blocks,
+# block_size, KVH * D] (``LayerCacheGeometry.fold_heads``); block_table:
+# [B, max_blocks] int32 (block ids, -1 = unallocated); pos: [B] int32.
+# ``base`` is None where column c of the table is the row's page c (a layer
+# that keeps its whole context), else [B] int32: the position of column 0's
+# first token (a multiple of the block size), for a window layer's table,
+# which holds only the pages the window still reaches.
 PagedCacheSlot = namedtuple("PagedCacheSlot", ["k_pool", "v_pool",
-                                               "block_table", "pos"])
+                                               "block_table", "pos", "base"],
+                            defaults=(None,))
+
+# What one layer caches: KV heads, the width of a K row and of a V row,
+# ``window`` (None: the whole context; w: a query sees the last w positions,
+# itself included), and ``fold_heads`` (the pools keep a token's KV heads
+# side by side in one row, ``[NB, bs, KVH * D]``: a model whose KV heads do
+# not fill a sublane tile asks for it, so that a page is whole tiles).
+LayerCacheGeometry = namedtuple(
+    "LayerCacheGeometry",
+    ["kv_heads", "k_dim", "v_dim", "window", "fold_heads"],
+    defaults=(None, False))
 
 _NEG = -1e30
 
-
-def _repeat_kv(x, n_heads):
-    """GQA: repeat KV heads up to the query head count."""
-    kvh = x.shape[2]
-    if kvh == n_heads:
-        return x
-    return jnp.repeat(x, n_heads // kvh, axis=2)
+# scores of one attention call are formed for this many bytes of float32 at
+# a time (a block of query positions), so that a wide prefill over a long
+# table fits beside a full pool
+_SCORE_BYTES = 512 * 2**20
 
 
-def _masked_attention(q, keys, values, pos):
-    """q [B,s,H,D] against keys/values [B,L,H,D] valid where
-    k_idx <= pos[b] + q_idx (causal over the static buffer)."""
+def cache_geometry(model) -> List[LayerCacheGeometry]:
+    """Each layer's cache geometry, as the model states it
+    (``model.cache_geometry()``), else one class of layers from its
+    config: ``num_key_value_heads`` (or ``num_heads``) heads of
+    ``hidden_size // num_heads`` for K and V alike. The one place the
+    scheduler and ``DecodeEngine`` size their caches from."""
+    if hasattr(model, "cache_geometry"):
+        return list(model.cache_geometry())
+    return uniform_cache_geometry(model.config)
+
+
+def uniform_cache_geometry(cfg) -> List[LayerCacheGeometry]:
+    kv_heads = getattr(cfg, "num_key_value_heads", None) or cfg.num_heads
+    head_dim = cfg.hidden_size // cfg.num_heads
+    return [LayerCacheGeometry(kv_heads, head_dim, head_dim)
+            for _ in range(cfg.num_layers)]
+
+
+def window_blocks_per_seq(window: int, block_size: int) -> int:
+    """Pages a row of a window layer holds at most: the window's span can
+    straddle one page more than it fills."""
+    return -(-window // block_size) + 1
+
+
+def pool_shapes(geom: LayerCacheGeometry, num_blocks: int, block_size: int):
+    """``(k_pool shape, v_pool shape)`` of one layer."""
+    if geom.fold_heads:
+        return ([num_blocks, block_size, geom.kv_heads * geom.k_dim],
+                [num_blocks, block_size, geom.kv_heads * geom.v_dim])
+    return ([num_blocks, block_size, geom.kv_heads, geom.k_dim],
+            [num_blocks, block_size, geom.kv_heads, geom.v_dim])
+
+
+def _attend(q, keys, values, q_pos, k_pos, window=None, sink=None):
+    """q [B,s,H,D] at positions q_pos [B,s] against keys [B,L,KVH,D] and
+    values [B,L,KVH,Dv] at positions k_pos [B,L]: key j is visible to query
+    i iff ``k_pos[j] <= q_pos[i]`` and, under a window, ``q_pos[i] -
+    k_pos[j] < window``. GQA by grouping the query heads of a KV head, never
+    by repeating K or V. ``sink [H]`` is a logit a head that joins the
+    softmax's denominator and carries no value. Scores and softmax in
+    float32, scale 1/sqrt(D), probabilities cast to q's dtype."""
     B, s, H, D = q.shape
-    L = keys.shape[1]
-    scores = jnp.einsum("bshd,blhd->bhsl", q.astype(jnp.float32),
-                        keys.astype(jnp.float32)) / math.sqrt(D)
-    k_idx = jnp.arange(L)[None, None, None, :]
-    q_idx = jnp.arange(s)[None, None, :, None]
-    mask = k_idx <= (pos[:, None, None, None] + q_idx)
-    scores = jnp.where(mask, scores, _NEG)
-    attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhsl,blhd->bshd", attn, values.astype(q.dtype))
+    L, kvh = keys.shape[1], keys.shape[2]
+    g = H // kvh
+    keys32 = keys.astype(jnp.float32)
+
+    def block(qb, qp):
+        # qb [B,c,H,D], qp [B,c]
+        c = qb.shape[1]
+        scores = jnp.einsum(
+            "bskgd,blkd->bkgsl",
+            qb.astype(jnp.float32).reshape(B, c, kvh, g, D),
+            keys32) / math.sqrt(D)
+        d = qp[:, :, None] - k_pos[:, None, :]                  # [B,c,L]
+        mask = d >= 0
+        if window is not None:
+            mask &= d < window
+        scores = jnp.where(mask[:, None, None], scores, _NEG)
+        if sink is None:
+            attn = jax.nn.softmax(scores, axis=-1)
+        else:
+            sk = sink.astype(jnp.float32).reshape(1, kvh, g, 1, 1)
+            m = jnp.maximum(scores.max(axis=-1, keepdims=True), sk)
+            p = jnp.exp(scores - m)
+            attn = p / (p.sum(axis=-1, keepdims=True) + jnp.exp(sk - m))
+        out = jnp.einsum("bkgsl,blkd->bskgd", attn.astype(q.dtype),
+                         values.astype(q.dtype))
+        return out.reshape(B, c, H, values.shape[-1])
+
+    def blocked(qb, qp):
+        """``block`` for a wide problem: the keys in blocks of the chunk's
+        own width with the running-max softmax, and only as far as the
+        last block that holds a position some query of the chunk can see
+        (a prefill at the start of its row never reads the rest of the
+        table's width)."""
+        c = qb.shape[1]
+        nb = L // c
+        q5 = qb.astype(jnp.float32).reshape(B, c, kvh, g, D)
+        first_pos = k_pos.reshape(B, nb, c).min(axis=(0, 2))
+        trips = jnp.max(jnp.where(first_pos <= qp.max(),
+                                  jnp.arange(nb) + 1, 0))
+        # the sink starts the running maximum and counts one in the sum
+        m0 = jnp.full((B, kvh, g, c), _NEG, jnp.float32)
+        l0 = jnp.zeros((B, kvh, g, c), jnp.float32)
+        if sink is not None:
+            m0 = m0.at[:].set(sink.astype(jnp.float32).reshape(1, kvh, g, 1))
+            l0 = l0 + 1.0
+
+        def body(j, carry):
+            m, l, acc = carry
+            cut = lambda x: jax.lax.dynamic_slice_in_dim(x, j * c, c, axis=1)
+            scores = jnp.einsum("bskgd,blkd->bkgsl", q5,
+                                cut(keys).astype(jnp.float32)) / math.sqrt(D)
+            d = qp[:, :, None] - cut(k_pos)[:, None, :]
+            mask = d >= 0
+            if window is not None:
+                mask &= d < window
+            mask = mask[:, None, None]
+            scores = jnp.where(mask, scores, _NEG)
+            m_new = jnp.maximum(m, scores.max(axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(mask, jnp.exp(scores - m_new[..., None]), 0.0)
+            pv = jnp.einsum("bkgsl,blkd->bkgsd", p.astype(q.dtype),
+                            cut(values).astype(q.dtype))
+            return (m_new, alpha * l + p.sum(axis=-1),
+                    alpha[..., None] * acc + pv.astype(jnp.float32))
+
+        _, l, acc = jax.lax.fori_loop(
+            0, trips, body,
+            (m0, l0, jnp.zeros((B, kvh, g, c, values.shape[-1]),
+                               jnp.float32)))
+        out = (acc / l[..., None]).astype(q.dtype)
+        return jnp.moveaxis(out, 3, 1).reshape(B, c, H, values.shape[-1])
+
+    c = s
+    while c > 1 and c % 2 == 0 and B * H * c * L * 4 > _SCORE_BYTES:
+        c //= 2
+    if c == s:
+        return block(q, q_pos)
+    n = s // c
+    out = jax.lax.map(
+        lambda a: (blocked if L % c == 0 else block)(*a),
+        (q.reshape(B, n, c, H, D).swapaxes(0, 1),
+         q_pos.reshape(B, n, c).swapaxes(0, 1)))
+    return out.swapaxes(0, 1).reshape(B, s, H, values.shape[-1])
 
 
-def _static_cache_raw(qv, kv, vv, ck, cv, pos):
+def _masked_attention(q, keys, values, pos, window=None, sink=None,
+                      base=None):
+    """q [B,s,H,D], the tokens at positions pos[b] .., against the buffer
+    keys/values [B,L,KVH,D] whose entry j holds position base[b] + j
+    (base None: j)."""
+    s, L = q.shape[1], keys.shape[1]
+    q_pos = pos[:, None] + jnp.arange(s)[None, :]
+    k_pos = jnp.arange(L)[None, :] + (0 if base is None else base[:, None])
+    k_pos = jnp.broadcast_to(k_pos, (q.shape[0], L))
+    return _attend(q, keys, values, q_pos, k_pos, window, sink)
+
+
+def _banded_attention(q, k, v, window, sink=None):
+    """Causal attention of a chunk over itself under a window: q [B,s,H,D],
+    k [B,s,KVH,D], v [B,s,KVH,Dv], all at positions 0 .. s-1 relative to the
+    chunk's start. Query blocks of ``window`` positions see their own block
+    and the one before, so no ``[H, s, s]`` scores are formed."""
+    B, s, H, D = q.shape
+    if s <= 2 * window or s % window:
+        idx = jnp.broadcast_to(jnp.arange(s)[None, :], (B, s))
+        return _attend(q, k, v, idx, idx, window, sink)
+    n = s // window
+
+    def blocks(x, shift):
+        x = x.reshape(B, n, window, *x.shape[2:])
+        if shift:
+            x = jnp.concatenate([jnp.zeros_like(x[:, :1]), x[:, :-1]], axis=1)
+        return x
+
+    kk = jnp.concatenate([blocks(k, True), blocks(k, False)], axis=2)
+    vv = jnp.concatenate([blocks(v, True), blocks(v, False)], axis=2)
+    fold = lambda x: x.reshape(B * n, *x.shape[2:])
+    # inside a pair of blocks the keys sit at 0 .. 2w-1 and the queries at
+    # w .. 2w-1; the first block has nothing before it, so its (zero) first
+    # half is put after every query
+    q_pos = jnp.broadcast_to(window + jnp.arange(window)[None, :],
+                             (B * n, window))
+    k_pos = jnp.broadcast_to(jnp.arange(2 * window)[None, :],
+                             (B, n, 2 * window))
+    first = (jnp.arange(n) == 0)[None, :, None]
+    k_pos = jnp.where(first & (k_pos < window), 4 * window, k_pos)
+    out = _attend(fold(blocks(q, False)), fold(kk), fold(vv), q_pos,
+                  k_pos.reshape(B * n, 2 * window), window, sink)
+    return out.reshape(B, s, H, v.shape[-1])
+
+
+def _causal_raw(q, k, v, *sink, window=None):
+    if window is not None:
+        return _banded_attention(q, k, v, window, sink[0] if sink else None)
+    idx = jnp.broadcast_to(jnp.arange(q.shape[1])[None, :], q.shape[:2])
+    return _attend(q, k, v, idx, idx, None, sink[0] if sink else None)
+
+
+def causal_attention(q, k, v, window=None, sink=None):
+    """Cache-free causal attention of one chunk from position 0 (a model's
+    eager forward): q [B,s,H,D], k [B,s,KVH,D], v [B,s,KVH,Dv]."""
+    args = (q, k, v) + (() if sink is None else (sink,))
+    return apply("causal_attention", _causal_raw, *args, window=window)
+
+
+def _static_cache_raw(qv, kv, vv, ck, cv, pos, *sink, window=None):
     """Write new K/V at per-batch offsets, then length-masked attention."""
-    n_heads = qv.shape[2]
-
     def write(c, new):
         def w1(cb, nb, p):
             return jax.lax.dynamic_update_slice(
@@ -78,65 +270,74 @@ def _static_cache_raw(qv, kv, vv, ck, cv, pos):
     with region("kv_gather"):
         ck2 = write(ck, kv)
         cv2 = write(cv, vv)
-    out = _masked_attention(qv, _repeat_kv(ck2, n_heads),
-                            _repeat_kv(cv2, n_heads), pos)
+    out = _masked_attention(qv, ck2, cv2, pos, window,
+                            sink[0] if sink else None)
     return out, ck2, cv2, pos + qv.shape[1]
 
 
-def static_cache_update_attend(q, k, v, slot: StaticCacheSlot):
+def static_cache_update_attend(q, k, v, slot: StaticCacheSlot, window=None,
+                               sink=None):
     """Cache-write + attend for one forward chunk (prefill or decode step).
 
-    q [B,s,H,D]; k/v [B,s,KVH,D] (already RoPE-rotated where applicable);
-    returns (out [B,s,H,D], new slot). The masked_multihead_attention
-    analogue over a dense static cache."""
+    q [B,s,H,D]; k [B,s,KVH,D], v [B,s,KVH,Dv] (already RoPE-rotated where
+    applicable); returns (out [B,s,H,Dv], new slot). The
+    masked_multihead_attention analogue over a dense static cache."""
     out, ck2, cv2, pos2 = apply(
         "static_cache_attention", _static_cache_raw, q, k, v,
-        slot.k, slot.v, slot.pos)
+        slot.k, slot.v, slot.pos, *(() if sink is None else (sink,)),
+        window=window)
     return out, StaticCacheSlot(ck2, cv2, pos2)
 
 
-def _paged_write(pool, new, block_table, pos):
+def _paged_write(pool, new, block_table, pos, base=None):
     """Scatter the s new tokens of each row into their pages: token t of
-    row b lands in pool[block_table[b, (pos[b]+t)//bs], (pos[b]+t)%bs]."""
+    row b, at position p = pos[b] + t, lands in pool[block_table[b, (p -
+    base[b]) // bs], p % bs]. A position before ``base`` (a prompt's part
+    that a window layer's table no longer reaches) is dropped."""
     s = new.shape[1]
     block_size = pool.shape[1]
     max_blocks = block_table.shape[1]
     tok_pos = pos[:, None] + jnp.arange(s)[None, :]              # [B, s]
     blk_slot = tok_pos // block_size
+    if base is not None:
+        blk_slot = blk_slot - base[:, None] // block_size
     blk = jnp.take_along_axis(block_table,
                               jnp.clip(blk_slot, 0, max_blocks - 1),
                               axis=1)                            # [B, s]
     off = tok_pos % block_size                                   # [B, s]
-    flat = pool.reshape(-1, *pool.shape[2:])                     # [NB*bs, H, D]
+    flat = pool.reshape(-1, *pool.shape[2:])                     # [NB*bs, ..]
     idx = (blk * block_size + off).reshape(-1)                   # [B*s]
     # unallocated (-1) or out-of-table positions must NOT wrap into
     # another sequence's block: route them out of bounds and drop
-    valid = ((blk >= 0) & (blk_slot < max_blocks)).reshape(-1)
+    valid = ((blk >= 0) & (blk_slot >= 0)
+             & (blk_slot < max_blocks)).reshape(-1)
     idx = jnp.where(valid, idx, flat.shape[0])
     return flat.at[idx].set(
-        new.reshape(-1, *new.shape[2:]).astype(pool.dtype),
+        new.reshape(-1, *flat.shape[1:]).astype(pool.dtype),
         mode="drop",
     ).reshape(pool.shape)
 
 
-def _paged_attend_xla(qv, k_pool, v_pool, block_table, pos):
+def _paged_attend_xla(qv, k_pool, v_pool, block_table, pos, window=None,
+                      sink=None, base=None, kv_heads=None):
     """Gather every page of every row's table into a contiguous
     [B, L, KVH, D] view, then length-masked attention: the formulation for
     s > 1 (prefill, chunks, the verify step), for the CPU, and for the
     sharded step (GSPMD does not partition a Pallas kernel)."""
-    B, n_heads = qv.shape[0], qv.shape[2]
+    B = qv.shape[0]
     L = block_table.shape[1] * k_pool.shape[1]
 
     def gather(pool):
         safe = jnp.maximum(block_table, 0)                       # [B, MB]
-        pages = pool[safe]                                       # [B, MB, bs, H, D]
+        pages = pool[safe]                                       # [B, MB, bs, ..]
+        if pool.ndim == 3:                                       # folded heads
+            return pages.reshape(B, L, kv_heads, -1)
         return pages.reshape(B, L, *pool.shape[2:])
 
     with region("kv_gather"):
         keys = gather(k_pool)
         values = gather(v_pool)
-    return _masked_attention(qv, _repeat_kv(keys, n_heads),
-                             _repeat_kv(values, n_heads), pos)
+    return _masked_attention(qv, keys, values, pos, window, sink, base)
 
 
 # evidence trail: "pallas" | "xla", set on every trace of the paged attend
@@ -144,78 +345,123 @@ def _paged_attend_xla(qv, k_pool, v_pool, block_table, pos):
 _last_path = None
 
 
-def _use_paged_kernel(ker, qv, pool) -> bool:
+def _paged_kernel(qv, k_pool, v_pool):
     """The one gate, decided at trace time from what the code can observe:
-    the decode kernel ``ker`` iff there is one query token a row, the
-    platform is a TPU (or a test runs the kernel through the interpreter),
-    and the shapes are ones the kernel supports. A kernel that was selected
-    and fails raises."""
+    the decode kernel of the pools' layout (``paged_attention`` for
+    ``[NB, bs, KVH, D]`` pools, ``paged_attention_gqa`` for pools with the
+    heads folded into the row) iff there is one query token a row, the
+    platform is a TPU (or a test runs that kernel through the interpreter),
+    and the shapes are ones that kernel supports; else ``None``. A kernel
+    that was selected and fails raises."""
+    from paddle_tpu.ops.pallas import paged_attention, paged_attention_gqa
+
+    ker = paged_attention if k_pool.ndim == 4 else paged_attention_gqa
     if qv.shape[1] != 1:
-        return False
+        return None
     if not ker._interpret:
         from paddle_tpu.device import is_tpu
 
         if not is_tpu():
-            return False
-    return ker.supports((qv.shape[0],) + tuple(qv.shape[2:]), qv.dtype,
-                        pool.shape, pool.dtype)
+            return None
+    ok = ker.supports((qv.shape[0],) + tuple(qv.shape[2:]), qv.dtype,
+                      k_pool.shape, k_pool.dtype, v_pool.shape)
+    return ker if ok else None
 
 
-def _paged_attend(qv, k_pool, v_pool, block_table, pos):
+def _paged_attend(qv, k_pool, v_pool, block_table, pos, window=None,
+                  sink=None, base=None, kv_heads=None):
     """Attention of qv [B,s,H,D] over the updated pool: position t of row b
-    sees the pos[b] + t + 1 positions its table names."""
+    sees the positions up to pos[b] + t that its table names (under a
+    window, the last ``window`` of them)."""
     global _last_path
-    from paddle_tpu.ops.pallas import paged_attention as ker
 
-    if _use_paged_kernel(ker, qv, k_pool):
+    ker = _paged_kernel(qv, k_pool, v_pool)
+    if ker is not None:
         _last_path = "pallas"
         with region("attention"):
-            out = ker.paged_attention_decode(
-                qv[:, 0], k_pool, v_pool, block_table, pos + 1)
+            if k_pool.ndim == 4:
+                out = ker.paged_attention_decode(
+                    qv[:, 0], k_pool, v_pool, block_table, pos + 1)
+            else:
+                out = ker.paged_attention_gqa_decode(
+                    qv[:, 0], k_pool, v_pool, block_table, pos + 1,
+                    window=window, sink=sink, base=base)
         return out[:, None]
     _last_path = "xla"
-    return _paged_attend_xla(qv, k_pool, v_pool, block_table, pos)
+    return _paged_attend_xla(qv, k_pool, v_pool, block_table, pos, window,
+                             sink, base, kv_heads)
 
 
-def _paged_cache_raw(qv, kv, vv, k_pool, v_pool, block_table, pos):
-    """Paged write, then attention over the updated pool."""
+def _paged_cache_raw(qv, kv, vv, k_pool, v_pool, block_table, pos, *rest,
+                     window=None, has_sink=False, has_base=False):
+    """Paged write, then attention over the updated pool. A chunk (s > 1)
+    through a window layer's own table (``base``) attends over itself: the
+    table no longer reaches the chunk's early positions, so such a chunk
+    starts its row (pos 0), which is how the scheduler prefills a windowed
+    model."""
+    rest = list(rest)
+    sink = rest.pop(0) if has_sink else None
+    base = rest.pop(0) if has_base else None
+    kv_heads = kv.shape[2]
+    if k_pool.ndim == 3:
+        fold = lambda x: x.reshape(*x.shape[:2], -1)
+        k_new, v_new = fold(kv), fold(vv)
+    else:
+        k_new, v_new = kv, vv
     with region("kv_gather"):
-        k_pool2 = _paged_write(k_pool, kv, block_table, pos)
-        v_pool2 = _paged_write(v_pool, vv, block_table, pos)
-    out = _paged_attend(qv, k_pool2, v_pool2, block_table, pos)
+        k_pool2 = _paged_write(k_pool, k_new, block_table, pos, base)
+        v_pool2 = _paged_write(v_pool, v_new, block_table, pos, base)
+    if base is not None and qv.shape[1] > 1:
+        out = _banded_attention(qv, kv.astype(qv.dtype), vv.astype(qv.dtype),
+                                window, sink)
+    else:
+        out = _paged_attend(qv, k_pool2, v_pool2, block_table, pos, window,
+                            sink, base, kv_heads)
     return out, k_pool2, v_pool2, pos + qv.shape[1]
 
 
-def paged_cache_update_attend(q, k, v, slot: PagedCacheSlot):
+def paged_cache_update_attend(q, k, v, slot: PagedCacheSlot, window=None,
+                              sink=None):
     """block_multihead_attention analogue: write the new tokens into the
     block pool through the block table, then attend over the live pages
-    (one query token a row on a TPU: the Pallas kernel reads them in place)
+    (one query token a row on a TPU: a Pallas kernel reads them in place)
     or over the gathered table (everything else)."""
+    if slot.base is not None and window is None:
+        raise ValueError("a paged cache slot with a base is a window "
+                         "layer's: the layer must state its window")
+    rest = [t for t in (sink, slot.base) if t is not None]
     out, kp2, vp2, pos2 = apply(
         "paged_cache_attention", _paged_cache_raw, q, k, v,
-        slot.k_pool, slot.v_pool, slot.block_table, slot.pos)
-    return out, PagedCacheSlot(kp2, vp2, slot.block_table, pos2)
+        slot.k_pool, slot.v_pool, slot.block_table, slot.pos, *rest,
+        window=window, has_sink=sink is not None,
+        has_base=slot.base is not None)
+    return out, PagedCacheSlot(kp2, vp2, slot.block_table, pos2, slot.base)
 
 
-def cache_update_attend(q, k, v, slot):
-    """Dispatch on cache-slot type (shared by every model's serving branch)."""
+def cache_update_attend(q, k, v, slot, window=None, sink=None):
+    """Dispatch on cache-slot type (shared by every model's serving branch).
+    ``window`` and ``sink`` are the layer's own (a sliding window of that
+    many positions; a learned logit a head in the softmax's denominator)."""
     if isinstance(slot, StaticCacheSlot):
-        return static_cache_update_attend(q, k, v, slot)
+        return static_cache_update_attend(q, k, v, slot, window, sink)
     if isinstance(slot, PagedCacheSlot):
-        return paged_cache_update_attend(q, k, v, slot)
+        return paged_cache_update_attend(q, k, v, slot, window, sink)
     raise TypeError(f"not a cache slot: {type(slot)!r}")
 
 
 def make_static_cache(num_layers: int, batch: int, max_len: int,
                       kv_heads: int, head_dim: int,
-                      dtype="bfloat16") -> List[StaticCacheSlot]:
-    """Preallocate dense decode caches (one slot per layer)."""
+                      dtype="bfloat16", geometry=None) -> List[StaticCacheSlot]:
+    """Preallocate dense decode caches (one slot per layer); ``geometry``
+    (``cache_geometry(model)``) sizes each layer's own."""
     import paddle_tpu as paddle
 
+    geometry = geometry or [LayerCacheGeometry(kv_heads, head_dim, head_dim)
+                            ] * num_layers
     slots = []
-    for _ in range(num_layers):
-        k = paddle.zeros([batch, max_len, kv_heads, head_dim], dtype=dtype)
-        v = paddle.zeros([batch, max_len, kv_heads, head_dim], dtype=dtype)
+    for g in geometry:
+        k = paddle.zeros([batch, max_len, g.kv_heads, g.k_dim], dtype=dtype)
+        v = paddle.zeros([batch, max_len, g.kv_heads, g.v_dim], dtype=dtype)
         pos = paddle.zeros([batch], dtype="int32")
         slots.append(StaticCacheSlot(k, v, pos))
     return slots

@@ -31,7 +31,9 @@ from paddle_tpu.models.kv_cache import (
     BlockAllocator,
     PagedCacheSlot,
     StaticCacheSlot,
+    cache_geometry,
     make_static_cache,
+    pool_shapes,
 )
 from paddle_tpu.observability.step_profile import region
 from paddle_tpu.tensor import Tensor
@@ -117,6 +119,10 @@ class SlotStep:
     pipeline — the async scheduler trades transient double cache
     residency for overlap there."""
 
+    # names of what the model adds to the telemetry block after its first
+    # four entries (``model.step_stats()``), known once a step has traced
+    extra_stat_names = ()
+
     def __init__(self, model, temperature: float = 0.0, top_k: int = 0,
                  donate: bool = True, telemetry: bool = True):
         self.model = model
@@ -179,6 +185,15 @@ class SlotStep:
                 stats = apply("step_telemetry", _telemetry_stats, logits,
                               gather_idx, c0.pos, blk,
                               differentiable=False, paged=paged)
+                # a model may add its own traced counts of this call (an
+                # expert layer's routed pairs): same block, same read
+                names, extra = getattr(self.model, "step_stats",
+                                       lambda: ((), None))()
+                if names:
+                    self.extra_stat_names = tuple(names)
+                    stats = apply("step_telemetry_extra",
+                                  lambda a, b: jnp.concatenate([a, b]),
+                                  stats, extra, differentiable=False)
         return next_ids, stats, new_caches
 
 
@@ -198,9 +213,12 @@ class DecodeEngine:
                  cache_dtype: str = "float32"):
         cfg = model.config
         self.model = model
-        self.num_layers = cfg.num_layers
-        self.num_kv_heads = getattr(cfg, "num_key_value_heads", None) or cfg.num_heads
-        self.head_dim = cfg.hidden_size // cfg.num_heads
+        # each layer's KV heads and K / V row widths, as the model states
+        # them (the scheduler sizes its pools from the same answer)
+        self.geometry = cache_geometry(model)
+        self.num_layers = len(self.geometry)
+        self.num_kv_heads = self.geometry[0].kv_heads
+        self.head_dim = self.geometry[0].k_dim
         self.max_seq_len = min(max_seq_len,
                                getattr(cfg, "max_position_embeddings", max_seq_len))
         self.temperature = float(temperature)
@@ -220,7 +238,7 @@ class DecodeEngine:
     def _dense_caches(self, batch: int) -> List[StaticCacheSlot]:
         return make_static_cache(self.num_layers, batch, self.max_seq_len,
                                  self.num_kv_heads, self.head_dim,
-                                 self.cache_dtype)
+                                 self.cache_dtype, geometry=self.geometry)
 
     def _paged_caches(self, batch: int, tokens_per_seq: int):
         n_blocks = self.num_blocks
@@ -234,11 +252,11 @@ class DecodeEngine:
         for i, blks in enumerate(per_seq_blocks):
             table[i, :len(blks)] = blks
         slots = []
-        for _ in range(self.num_layers):
-            kp = paddle.zeros([n_blocks, self.block_size, self.num_kv_heads,
-                               self.head_dim], dtype=self.cache_dtype)
-            vp = paddle.zeros([n_blocks, self.block_size, self.num_kv_heads,
-                               self.head_dim], dtype=self.cache_dtype)
+        for g in self.geometry:
+            # a window layer keeps its whole table here (one class of
+            # blocks): the window is the layer's mask, not a saving
+            kp, vp = (paddle.zeros(shape, dtype=self.cache_dtype)
+                      for shape in pool_shapes(g, n_blocks, self.block_size))
             # per-layer copies: cache args are donated to the compiled step,
             # and a buffer must not appear twice in a donated pytree
             slots.append(PagedCacheSlot(kp, vp, paddle.to_tensor(table),

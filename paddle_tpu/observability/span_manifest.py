@@ -50,6 +50,10 @@ SPAN_MANIFEST = {
     "serving.admit": {"owner": "serving", "category": "UserDefined"},
     "serving.block_accounting": {"owner": "serving",
                                  "category": "UserDefined"},
+    # inside serving.block_accounting: a window layer's pages that fell
+    # wholly behind the window go back to their class's free list
+    "serving.window_release": {"owner": "serving",
+                               "category": "UserDefined"},
     "serving.stage": {"owner": "serving", "category": "UserDefined"},
     "serving.launch": {"owner": "serving", "category": "UserDefined"},
     "serving.commit": {"owner": "serving", "category": "UserDefined"},

@@ -75,6 +75,9 @@ REGION_MANIFEST = {
     "attention": {"owner": "models", "category": "Forward"},
     "kv_gather": {"owner": "models", "category": "Forward"},
     "mlp": {"owner": "models", "category": "Forward"},
+    # dropless expert layer (nn/moe.py): routing and sort, grouped products
+    "moe_route": {"owner": "models", "category": "Forward"},
+    "moe_experts": {"owner": "models", "category": "Forward"},
     "logits": {"owner": "models", "category": "Forward"},
     "sampling": {"owner": "serving", "category": "Forward"},
     "telemetry": {"owner": "serving", "category": "UserDefined"},

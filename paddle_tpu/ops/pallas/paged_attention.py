@@ -54,12 +54,15 @@ def sublane_tile(dtype) -> int:
     return 8 * 4 // jnp.dtype(dtype).itemsize
 
 
-def supports(q_shape, q_dtype, pool_shape, pool_dtype) -> bool:
-    """Shapes the kernel compiles for: ``q [B, H, D]`` against a pool
-    ``[NB, bs, KVH, D]`` of q's dtype. A page must be a whole number of
+def supports(q_shape, q_dtype, pool_shape, pool_dtype,
+             v_pool_shape=None) -> bool:
+    """Shapes the kernel compiles for: ``q [B, H, D]`` against K and V pools
+    ``[NB, bs, KVH, D]`` of one shape and q's dtype. A page must be a whole number of
     tiles with the KV heads filling the sublanes, so that ``[bs, KVH, D]``
     and ``[bs * KVH, D]`` are the same bytes in HBM and in VMEM."""
     if len(q_shape) != 3 or len(pool_shape) != 4:
+        return False
+    if v_pool_shape is not None and tuple(v_pool_shape) != tuple(pool_shape):
         return False
     _, n_heads, head_dim = q_shape
     _, _, kv_heads, pool_dim = pool_shape

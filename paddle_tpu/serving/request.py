@@ -209,6 +209,8 @@ class Request:
     finish_t: Optional[float] = None
     finish_reason: Optional[str] = None
     blocks: List[int] = field(default_factory=list)   # live KV blocks
+    # live blocks of the window class, oldest first (window layers only)
+    window_blocks: List[int] = field(default_factory=list)
     slot: int = -1
     deadline_s: Optional[float] = None  # wall budget from arrival; None=∞
     consecutive_faults: int = 0       # step faults since last clean step

@@ -62,6 +62,9 @@ from paddle_tpu.models.kv_cache import (
     BlockAllocator,
     KVPoolExhausted,
     PagedCacheSlot,
+    cache_geometry,
+    pool_shapes,
+    window_blocks_per_seq,
 )
 from paddle_tpu.models.serving import SlotStep, _bucket, splice_carry
 from paddle_tpu.observability.annotations import (
@@ -194,10 +197,35 @@ class ContinuousBatchingScheduler:
         self.config = cfg = config or SchedulerConfig()
         mcfg = model.config
         self.model = model
-        self.num_layers = mcfg.num_layers
-        self.num_kv_heads = (getattr(mcfg, "num_key_value_heads", None)
-                             or mcfg.num_heads)
-        self.head_dim = mcfg.hidden_size // mcfg.num_heads
+        # what each layer caches, as the model states it: KV heads, K and V
+        # row widths, and a window for the layers that need only the last
+        # positions of a row (a second class of blocks, below)
+        self._geometry = geometry = cache_geometry(model)
+        self.num_layers = len(geometry)
+        self.num_kv_heads = geometry[0].kv_heads
+        self.head_dim = geometry[0].k_dim
+        windows = sorted({g.window for g in geometry if g.window})
+        if len(windows) > 1:
+            raise ValueError(f"window layers of one model must share one "
+                             f"window; got {windows}")
+        self._window: Optional[int] = windows[0] if windows else None
+        if self._window is not None:
+            # these assume one class of blocks whose pages live as long as
+            # their request: a window layer's table forgets a row's early
+            # pages, so a shared prefix, a chunk or a draft verified against
+            # it would read what is no longer there
+            for on, feature in (
+                    (cfg.enable_prefix_caching,
+                     "prefix caching (enable_prefix_caching)"),
+                    (cfg.prefill_chunk_size,
+                     "chunked prefill (prefill_chunk_size)"),
+                    (cfg.spec_k, "speculative decoding (spec_k)"),
+                    (sharding is not None, "the sharded step (sharding)")):
+                if on:
+                    raise ValueError(
+                        f"{feature} is not supported for a model with "
+                        f"sliding-window layers: it assumes one class of "
+                        f"KV blocks that keep a row's whole context")
         max_pos = getattr(mcfg, "max_position_embeddings", cfg.max_seq_len)
         self.max_seq_len = min(cfg.max_seq_len, max_pos)
         self.metrics = metrics or ServingMetrics()
@@ -280,14 +308,27 @@ class ContinuousBatchingScheduler:
         self._table = np.full((S, MB), -1, np.int32)
         self._pos = np.zeros(S, np.int32)
         self._next_tok = np.zeros(S, np.int32)   # token to feed next step
-        self._pools = [
-            (paddle.zeros([cfg.total_blocks, cfg.block_size,
-                           self.num_kv_heads, self.head_dim],
-                          dtype=cfg.cache_dtype),
-             paddle.zeros([cfg.total_blocks, cfg.block_size,
-                           self.num_kv_heads, self.head_dim],
-                          dtype=cfg.cache_dtype))
-            for _ in range(self.num_layers)]
+        # the window class: a row holds the pages its window still reaches
+        # (at most WB), pages wholly behind it go back to this free list
+        # while the request runs, and the pools do not grow with
+        # max_seq_len: S * WB blocks, what every slot can hold at once.
+        # ``allocator`` stays the full-context class.
+        WB = (window_blocks_per_seq(self._window, cfg.block_size)
+              if self._window else 0)
+        self.window_allocator: Optional[BlockAllocator] = None
+        if self._window:
+            self.window_allocator = BlockAllocator(S * WB, cfg.block_size)
+        self._window_blocks_per_seq = WB
+        self._wtable = np.full((S, WB), -1, np.int32)
+        self._wbase = np.zeros(S, np.int32)   # position of column 0
+        self.window_blocks_peak = 0
+        self._pools = []
+        for g in geometry:
+            n = (self.window_allocator.num_blocks if g.window
+                 else cfg.total_blocks)
+            self._pools.append(tuple(
+                paddle.zeros(shape, dtype=cfg.cache_dtype)
+                for shape in pool_shapes(g, n, cfg.block_size)))
         if sharding is not None:
             # head-shard the K/V pools over the replica's mesh (~1/tp of
             # the KV bytes per chip); block tables and positions stay tiny
@@ -357,9 +398,20 @@ class ContinuousBatchingScheduler:
         # ---- device-side observability (HBM ledger + roofline) ---------
         # Coarse owner-tagged accounting registered HERE, at the one site
         # that constructs the pools — nothing below runs per decode step.
-        pool_bytes = tree_nbytes(self._pools)
+        # (a window layer's bytes do not grow with the tokens)
+        pool_bytes = tree_nbytes([p for p, g in zip(self._pools, geometry)
+                                  if not g.window])
         self._kv_bytes_per_token = (
             pool_bytes // max(1, cfg.total_blocks * cfg.block_size))
+        if self._window:
+            reg = self.metrics.registry
+            self._window_used = reg.gauge(
+                "kv_window_blocks_used",
+                "window-class KV blocks held by running requests")
+            self._window_released = reg.counter(
+                "kv_window_blocks_released",
+                "window-class KV blocks returned to the free list while "
+                "their request was still running")
         self.device_ledger: Optional[DeviceMemoryLedger] = None
         self._device_time: Optional[DeviceTimeSampler] = None
         if cfg.enable_device_observability:
@@ -511,20 +563,75 @@ class ContinuousBatchingScheduler:
         return int(sum(self._pos[s] for s in range(len(self._slots))
                        if self._slots[s] is not None))
 
-    def _caches(self, table: np.ndarray, pos: np.ndarray):
-        """Fresh per-layer PagedCacheSlots over the shared pools. When args
+    def _caches(self, table: np.ndarray, pos: np.ndarray,
+                wtable: Optional[np.ndarray] = None,
+                wbase: Optional[np.ndarray] = None):
+        """Fresh per-layer PagedCacheSlots over the shared pools; a window
+        layer gets the window class's table (``wtable``) and the position
+        of its first column (``wbase``). When args
         are donated into the compiled step the table/pos tensors must be
         rebuilt per layer (a donated pytree must not repeat a buffer); a
         non-donating step shares ONE tensor across layers — 2 host->device
         transfers per decode step instead of 2*num_layers, which matters on
         the dispatch-ahead hot path where staging is the critical-path
         cost."""
+        up = paddle.to_tensor
+
+        def uploads(window: bool):
+            return ((up(wtable), up(pos), up(wbase)) if window
+                    else (up(table), up(pos)))
+
+        kinds = [bool(g.window) for g in self._geometry]
         if self._donate:
-            return [PagedCacheSlot(kp, vp, paddle.to_tensor(table),
-                                   paddle.to_tensor(pos))
-                    for kp, vp in self._pools]
-        t, p = paddle.to_tensor(table), paddle.to_tensor(pos)
-        return [PagedCacheSlot(kp, vp, t, p) for kp, vp in self._pools]
+            return [PagedCacheSlot(kp, vp, *uploads(w))
+                    for (kp, vp), w in zip(self._pools, kinds)]
+        shared = {w: uploads(w) for w in set(kinds)}
+        return [PagedCacheSlot(kp, vp, *shared[w])
+                for (kp, vp), w in zip(self._pools, kinds)]
+
+    def _release_blocks(self, req: Request, slot: int = -1):
+        """Return every block ``req`` holds, of both classes, and clear the
+        window class's row of ``slot``."""
+        self.allocator.free(req.blocks)
+        req.blocks = []
+        if self.window_allocator is not None:
+            self.window_allocator.free(req.window_blocks)
+            req.window_blocks = []
+            if slot >= 0:
+                self._wtable[slot] = -1
+                self._wbase[slot] = 0
+
+    def _window_span(self, pos: int) -> Tuple[int, int]:
+        """``(first, last)`` page a window layer needs when the token at
+        position ``pos`` is written and attends."""
+        bs = self.config.block_size
+        return max(0, pos - self._window + 1) // bs, pos // bs
+
+    @holds_lock("_elock")
+    def _roll_window(self, slot: int, req: Request):
+        """Move ``slot``'s window-class row on to its dispatched position:
+        pages wholly behind the window go back to the free list, and the
+        page the next token lands in is allocated (``KVPoolExhausted``
+        from here preempts, as from the full class)."""
+        first, last = self._window_span(int(self._disp_pos[slot]))
+        bs = self.config.block_size
+        blocks = req.window_blocks
+        base = int(self._wbase[slot]) // bs
+        behind = min(first - base, len(blocks))
+        if behind > 0:
+            with RecordEvent("serving.window_release"):
+                self.window_allocator.free(blocks[:behind])
+                del blocks[:behind]
+                self._window_released.inc(behind)
+        base = max(base, first)
+        changed = behind > 0
+        while base + len(blocks) <= last:
+            blocks += self.window_allocator.allocate(bs)
+            changed = True
+        if changed:
+            self._wtable[slot] = -1
+            self._wtable[slot, :len(blocks)] = blocks
+            self._wbase[slot] = base * bs
 
     def _store_pools(self, caches):
         self._pools = [(c.k_pool, c.v_pool) for c in caches]
@@ -558,8 +665,7 @@ class ContinuousBatchingScheduler:
         req = self._slots[slot]
         req.finish(reason)
         self._cache_insert_on_release(req, slot)
-        self.allocator.free(req.blocks)
-        req.blocks = []
+        self._release_blocks(req, slot)
         req.slot = -1
         req.prefill_pos = -1
         self._slots[slot] = None
@@ -701,8 +807,7 @@ class ContinuousBatchingScheduler:
         req = self._slots[slot]
         with RecordEvent("serving.preempt"):
             self._cache_insert_on_release(req, slot)
-            self.allocator.free(req.blocks)
-            req.blocks = []
+            self._release_blocks(req, slot)
             req.slot = -1
             # a mid-prefill victim resumes via a clean chunked re-prefill;
             # its completed-chunk KV was just donated to the radix tree,
@@ -753,6 +858,8 @@ class ContinuousBatchingScheduler:
                                       int(self._disp_pos[slot]), add)
                 for j in range(before, len(req.blocks)):
                     self._table[slot, j] = req.blocks[j]
+                if self._window is not None:
+                    self._roll_window(slot, req)
                 return True
             except KVPoolExhausted:
                 if self._inflight:
@@ -835,12 +942,21 @@ class ContinuousBatchingScheduler:
             cow = matched < len(hit_blocks) * bs
             need_blocks = -(-P // bs) - len(hit_blocks) + (1 if cow else 0)
             t0 = pc()
+            fresh: List[int] = []
+            wfresh: List[int] = []
             with RecordEvent("serving.block_accounting"):
                 try:
                     inject("serving.block_alloc")
                     fresh = (self.allocator.allocate(need_blocks * bs)
                              if need_blocks > 0 else [])
+                    if self._window is not None:
+                        # the pages the prompt's last window reaches
+                        wfirst, wlast = self._window_span(P - 1)
+                        wfresh = self.window_allocator.allocate(
+                            (wlast - wfirst + 1) * bs)
                 except KVPoolExhausted:
+                    if fresh:                # the window class was dry
+                        self.allocator.free(fresh)
                     if hit_blocks:
                         self.prefix_cache.unpin(hit_blocks)
                     break                    # running seqs keep precedence
@@ -889,6 +1005,13 @@ class ContinuousBatchingScheduler:
                 row = np.full((1, self.config.max_blocks_per_seq), -1,
                               np.int32)
                 row[0, :len(blocks)] = blocks
+                wrow = wbase = None
+                if self._window is not None:
+                    req.window_blocks = wfresh
+                    wrow = np.full((1, self._window_blocks_per_seq), -1,
+                                   np.int32)
+                    wrow[0, :len(wfresh)] = wfresh
+                    wbase = np.array([wfirst * bs], np.int32)
             block_s += pc() - t0
             if self._chunk_step is not None:
                 # chunked admission: pack the slot MID-PREFILL (frontier =
@@ -928,7 +1051,8 @@ class ContinuousBatchingScheduler:
                                 paddle.to_tensor(np.arange(
                                     matched, matched + Pb, dtype=np.int32)),
                                 self._caches(
-                                    row, np.array([matched], np.int32)),
+                                    row, np.array([matched], np.int32),
+                                    wrow, wbase),
                                 paddle.to_tensor(np.array([S - 1], np.int32)))
                     with RecordEvent("serving.launch"):
                         next_ids, stats, caches = self._step_fn(*args)
@@ -938,8 +1062,7 @@ class ContinuousBatchingScheduler:
                 # into the grid: release everything (free() drops fresh
                 # blocks and decrefs cache pins alike) and either requeue
                 # for a clean re-prefill or fail it past its budget.
-                self.allocator.free(req.blocks)
-                req.blocks = []
+                self._release_blocks(req)
                 req.slot = -1
                 site = self._fault_site(exc, "serving.prefill")
                 if classify_error(exc) == "fatal":
@@ -966,6 +1089,9 @@ class ContinuousBatchingScheduler:
             # is in flight (committed token lands at sync/drain below)
             self._slots[slot] = req
             self._table[slot] = row[0]
+            if self._window is not None:
+                self._wtable[slot] = wrow[0]
+                self._wbase[slot] = wbase[0]
             self._pos[slot] = P
             self._disp_pos[slot] = P
             self._disp_emitted[slot] = req.num_generated + 1
@@ -1438,18 +1564,20 @@ class ContinuousBatchingScheduler:
                 if r is not None and not r.is_prefilling
                 and int(self._disp_emitted[s]) < r.max_new_tokens]
 
-    def _disp_table(self) -> np.ndarray:
-        """Block table for the next dispatch: frozen and mid-prefill slots
-        get a masked (-1) row — the paged write kernel drops -1-table
-        writes, so their speculative K/V is discarded instead of
-        overrunning the row (or corrupting a half-built prefill)."""
+    def _disp_table(self, table: Optional[np.ndarray] = None) -> np.ndarray:
+        """Block table (the full class's, or ``table``) for the next
+        dispatch: frozen and mid-prefill slots get a masked (-1) row — the
+        paged write kernel drops -1-table writes, so their speculative K/V
+        is discarded instead of overrunning the row (or corrupting a
+        half-built prefill)."""
+        table = self._table if table is None else table
         frozen = [s for s, r in enumerate(self._slots)
                   if r is not None
                   and (r.is_prefilling
                        or int(self._disp_emitted[s]) >= r.max_new_tokens)]
         if not frozen:
-            return self._table
-        tbl = self._table.copy()
+            return table
+        tbl = table.copy()
         tbl[frozen] = -1
         return tbl
 
@@ -1494,8 +1622,11 @@ class ContinuousBatchingScheduler:
                 args = (self._decode_ids(),
                         paddle.to_tensor(
                             self._disp_pos.reshape(S, 1).astype(np.int32)),
-                        self._caches(self._disp_table(),
-                                     self._disp_pos.copy()),
+                        self._caches(
+                            self._disp_table(), self._disp_pos.copy(),
+                            *((self._disp_table(self._wtable),
+                               self._wbase.copy())
+                              if self._window is not None else ())),
                         paddle.to_tensor(np.zeros(S, np.int32)))
             with RecordEvent("serving.launch"):
                 t_call = pc()
@@ -1815,8 +1946,7 @@ class ContinuousBatchingScheduler:
                 spec["trace"] = self.tracer.export_snapshot(
                     req.request_id, t=export_t)
                 specs.append(spec)
-                self.allocator.free(req.blocks)
-                req.blocks = []
+                self._release_blocks(req, s)
                 req.slot = -1
                 self._slots[s] = None
                 self._table[s] = -1
@@ -1981,6 +2111,11 @@ class ContinuousBatchingScheduler:
                     allocator=self.allocator, live_tokens=self._live_tokens(),
                     dispatch_depth=self.dispatch_depth,
                     in_flight_steps=in_flight)
+                if self.window_allocator is not None:
+                    used = self.window_allocator.num_used_blocks
+                    self._window_used.set(used)
+                    self.window_blocks_peak = max(self.window_blocks_peak,
+                                                  used)
                 record = dict(
                     running=sum(r is not None for r in self._slots),
                     queue_depth=len(self.queue),
@@ -2376,6 +2511,13 @@ class ContinuousBatchingScheduler:
             "kv_blocks": float(stats_np[3]),
             "steps": (0 if prev is None else prev["steps"]) + 1,
         }
+        # what the model adds to the block (``model.step_stats()``): the
+        # step's value, and its sum over the steps for a mean
+        for i, name in enumerate(self._step_fn.extra_stat_names):
+            v = float(stats_np[4 + i])
+            self._last_telemetry[name] = v
+            self._last_telemetry[name + "_sum"] = v + (
+                0.0 if prev is None else prev[name + "_sum"])
 
     def telemetry_snapshot(self) -> Optional[dict]:
         """Latest drained in-program telemetry block (None until the

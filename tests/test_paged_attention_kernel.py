@@ -284,3 +284,130 @@ def test_compiles_for_v5e(one_chip, batch, max_blocks, num_blocks, n_heads,
     # the pool is read in place: no copy of it, no scratch of its size
     pool_bytes = num_blocks * BS * kvh * D * jnp.dtype(dtype).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+# ---- the sibling for folded pools (ops/pallas/paged_attention_gqa.py) --------
+
+from paddle_tpu.ops.pallas import paged_attention_gqa as pg  # noqa: E402
+
+
+@pytest.fixture()
+def gqa_interpreted():
+    pg._interpret = True
+    yield
+    pg._interpret = False
+
+
+def _folded_case(seed, batch, max_blocks, dtype, lengths, window, n_heads=8,
+                 kvh=2, dk=24, dv=16, bs=4):
+    """Folded pools (K rows wider than V rows), shuffled pages, and for a
+    window layer a table that holds only the pages its window reaches."""
+    rng = np.random.default_rng(seed)
+    nb = batch * max_blocks + 3
+    kp = jnp.asarray(rng.standard_normal((nb, bs, kvh * dk)), dtype)
+    vp = jnp.asarray(rng.standard_normal((nb, bs, kvh * dv)), dtype)
+    q = jnp.asarray(rng.standard_normal((batch, 1, n_heads, dk)), dtype)
+    table = (1 + rng.permutation(nb - 1)[:batch * max_blocks]).reshape(
+        batch, max_blocks).astype(np.int32)
+    pos = np.asarray(lengths, np.int32) - 1
+    sink = jnp.asarray(rng.standard_normal(n_heads), jnp.float32)
+    base = None
+    if window is not None:
+        base = np.maximum(pos - window + 1, 0) // bs * bs
+    return q, kp, vp, table, pos, window, sink, base, kvh
+
+
+def _folded_oracle(q, kp, vp, table, pos, window, sink, base, kvh):
+    return np.asarray(kv_cache._paged_attend_xla(
+        q, kp, vp, jnp.asarray(table), jnp.asarray(pos), window, sink,
+        None if base is None else jnp.asarray(base), kvh), np.float32)
+
+
+def _folded_kernel(q, kp, vp, table, pos, window, sink, base, kvh, **kw):
+    return np.asarray(pg.paged_attention_gqa_decode(
+        q[:, 0], kp, vp, jnp.asarray(table), jnp.asarray(pos) + 1,
+        window=window, sink=sink,
+        base=None if base is None else jnp.asarray(base), **kw),
+        np.float32)[:, None]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind, lengths, max_blocks, pages", [
+    ("full", [1, 4, 5, 23, 40], 10, 4),       # whole context, 3 groups
+    ("full", [17, 3], 6, None),               # one group holds the table
+    ("window", [1, 7, 8, 9, 31, 12], 3, 2),   # window 8: 3 pages, 2 groups
+    ("window", [40, 2, 16], 3, None),
+], ids=["full_groups", "full_one_group", "window_groups", "window_one"])
+def test_folded_kernel_matches_gather_for_both_layer_kinds(
+        gqa_interpreted, dtype, kind, lengths, max_blocks, pages):
+    window = 8 if kind == "window" else None
+    case = _folded_case(11, len(lengths), max_blocks, dtype, lengths, window)
+    if kind == "full":
+        case = case[:6] + (None,) + case[7:]       # full layers have no sink
+    got = _folded_kernel(*case, pages_per_group=pages)
+    np.testing.assert_allclose(got, _folded_oracle(*case), atol=TOL[dtype],
+                               rtol=0)
+
+
+def test_folded_kernel_idle_rows_are_finite_and_window_reads_no_more(
+        gqa_interpreted):
+    case = list(_folded_case(5, 3, 3, "float32", [20, 1, 9], 8))
+    case[3][1] = -1                                   # an idle row's table
+    case[4][1] = -1                                   # length 0
+    got = _folded_kernel(*case)
+    assert np.isfinite(got).all()
+    want = _folded_oracle(*case)
+    np.testing.assert_allclose(got[[0, 2]], want[[0, 2]], atol=2e-6)
+    # what lies behind the window is never read: poison the pool's other
+    # blocks and the result does not move
+    q, kp, vp, table = case[:4]
+    used = np.unique(table[[0, 2]])
+    poison = np.setdiff1d(np.arange(kp.shape[0]), used)
+    case[1], case[2] = kp.at[poison].set(np.nan), vp.at[poison].set(np.nan)
+    np.testing.assert_allclose(_folded_kernel(*case)[[0, 2]], got[[0, 2]],
+                               atol=0)
+
+
+@pytest.mark.parametrize("forced, s, want", [
+    (False, 1, "xla"), (True, 1, "pallas"), (True, 2, "xla")],
+    ids=["cpu", "forced", "s2"])
+def test_gate_takes_the_folded_kernel_by_the_pools_layout(forced, s, want):
+    pg._interpret = forced
+    try:
+        q = jnp.zeros((2, s, 8, 24), "float32")
+        kp, vp = jnp.zeros((4, 4, 48), "float32"), jnp.zeros((4, 4, 32),
+                                                             "float32")
+        kv_cache._last_path = None
+        out = kv_cache._paged_attend(
+            q, kp, vp, jnp.zeros((2, 2), jnp.int32), jnp.ones((2,), jnp.int32),
+            kv_heads=2)
+    finally:
+        pg._interpret = False
+    assert kv_cache._last_path == want
+    assert out.shape == (2, s, 8, 16)
+
+
+@pytest.mark.parametrize("kind, kvh, max_blocks, num_blocks, batch", [
+    ("full", 4, 256, 20000, 128),      # the MiMo-V2.5 cell's decode program
+    ("window", 8, 9, 1152, 128),
+    ("full", 4, 33, 33, 1),            # its reference check
+    ("window", 8, 9, 9, 1),
+], ids=["full_decode", "window_decode", "full_check", "window_check"])
+def test_folded_kernel_compiles_for_v5e(one_chip, kind, kvh, max_blocks,
+                                        num_blocks, batch):
+    def shape(dims, dt="bfloat16"):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+
+    window = 128 if kind == "window" else None
+    fn = lambda q, kp, vp, t, n, b, s: pg.paged_attention_gqa_decode(
+        q, kp, vp, t, n, window=window, sink=s if window else None, base=b)
+    compiled = jax.jit(fn).lower(
+        shape((batch, 64, 192)), shape((num_blocks, 16, kvh * 192)),
+        shape((num_blocks, 16, kvh * 128)),
+        shape((batch, max_blocks), "int32"), shape((batch,), "int32"),
+        shape((batch,), "int32"), shape((64,), "float32")).compile()
+    text = compiled.as_text()
+    assert f"paged_gqa_decode_{kind}" in text and "tpu_custom_call" in text
+    pool_bytes = num_blocks * 16 * kvh * 192 * 2
+    if batch > 1:      # the pools are read in place
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
