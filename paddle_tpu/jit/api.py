@@ -13,7 +13,7 @@ from __future__ import annotations
 import functools
 import time
 import os
-from typing import Callable, Optional
+from typing import Callable, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -51,17 +51,33 @@ def _jit_cache_size(jitted) -> int:
 _GLOBAL_TO_STATIC_ENABLED = True
 
 
+def _combine(picked, rest):
+    """One tree from the two ``StaticFunction._split_donated`` made: each
+    is the whole tree with the other's leaves set to None."""
+    return jax.tree_util.tree_map(lambda a, b: b if a is None else a,
+                                  picked, rest, is_leaf=lambda x: x is None)
+
+
 class StaticFunction:
     """Callable wrapping (layer?, fn) with a cached jax.jit program."""
 
     def __init__(self, fn: Callable, layer: Optional[Layer] = None,
                  full_graph: bool = True, donate_buffers: bool = False,
-                 donate_args: bool = False, name: Optional[str] = None):
+                 donate_args: Union[bool, Callable] = False,
+                 name: Optional[str] = None):
         """``donate_buffers`` donates the layer's buffer values (safe when no
         caller holds the previous values — they are replaced by the call's
-        write-back). ``donate_args`` donates the positional-argument buffers:
-        only for callers that never reuse an argument array after the call
-        (e.g. the serving decode loop threading KV caches through).
+        write-back). ``donate_args=True`` donates every positional-argument
+        buffer: only for callers that never reuse an argument array after
+        the call, and never pass one array twice (a donated pytree may not
+        repeat a buffer). A callable donates by part: it is given the
+        call's positional arguments (as arrays) and returns a tree prefix
+        of bools over them, True above the leaves to donate (the serving
+        steps donate the KV pools of ``caches`` and nothing else, so a
+        block table may be shared by every layer); the other leaves are
+        ordinary inputs that stay valid after the call and may repeat.
+        Its answer may depend on the arguments' structure only: it is
+        asked once for each structure.
         ``name`` labels this program cache in the CompileTracker."""
         self._fn = fn
         self._layer = layer
@@ -76,6 +92,10 @@ class StaticFunction:
         if donate_args:
             donate += (2,)
         self._donate_argnums = donate
+        # donation by part: the donated leaves travel as ``arg_vals`` (jit
+        # argument 2, donated), the others as ``kept_vals`` (argument 3)
+        self._donate_select = donate_args if callable(donate_args) else None
+        self._donate_flags = {}   # arguments' treedef -> one bool a leaf
         self._seen_programs = 0   # ProgramInventory capture high-water mark
         self._traces = 0          # times jax traced self._traced (cache misses)
         self._jitted = jax.jit(self._traced, static_argnames=("training",),
@@ -88,8 +108,11 @@ class StaticFunction:
         self.forward = self.__call__
 
     # The traced program: pure function of (param_vals, buffer_vals, args, key)
-    def _traced(self, param_vals, buffer_vals, arg_vals, kwarg_vals, key, training):
+    def _traced(self, param_vals, buffer_vals, arg_vals, kept_vals,
+                kwarg_vals, key, training):
         self._traces += 1
+        if kept_vals is not None:
+            arg_vals = _combine(arg_vals, kept_vals)
         params, buffers = self._state_tensors()
         tensors = params + buffers
         values = list(param_vals) + list(buffer_vals)
@@ -107,6 +130,23 @@ class StaticFunction:
             if self._layer is not None:
                 (self._layer.train() if prev_training else self._layer.eval())
         return out_vals, new_buffer_vals
+
+    def _split_donated(self, arg_vals):
+        """``(donated, kept)`` of a call's arguments under donation by
+        part: each the whole tree with the other's leaves set to None, so
+        both keep the structure a jit cache keys on. One flatten and two
+        unflattens a call (this runs on every launch); the selector is
+        asked once for each structure."""
+        leaves, treedef = jax.tree_util.tree_flatten(arg_vals)
+        flags = self._donate_flags.get(treedef)
+        if flags is None:
+            flags = self._donate_flags[treedef] = tuple(jax.tree.leaves(
+                jax.tree.broadcast(self._donate_select(*arg_vals),
+                                   arg_vals)))
+        return (treedef.unflatten([l if f else None
+                                   for l, f in zip(leaves, flags)]),
+                treedef.unflatten([None if f else l
+                                   for l, f in zip(leaves, flags)]))
 
     def _state_tensors(self):
         if self._layer is None:
@@ -196,19 +236,22 @@ class StaticFunction:
                     from jax.experimental import checkify as _checkify
 
                     # checkify erases the signature, so `training` must be
-                    # marked static POSITIONALLY (arg 5 of the bound method)
+                    # marked static POSITIONALLY (arg 6 of the bound method)
                     self._jitted_checked = jax.jit(
                         _checkify.checkify(self._traced,
                                            errors=_checkify.float_checks),
-                        static_argnums=(5,))
+                        static_argnums=(6,))
                 err, (out_vals, new_buffer_vals) = self._jitted_checked(
-                    param_vals, buffer_vals, arg_vals, kwarg_vals, key,
-                    training)
+                    param_vals, buffer_vals, arg_vals, None, kwarg_vals,
+                    key, training)
                 err.throw()
             else:
+                kept_vals = None
+                if self._donate_select is not None:
+                    arg_vals, kept_vals = self._split_donated(arg_vals)
                 out_vals, new_buffer_vals = self._jitted(
-                    param_vals, buffer_vals, arg_vals, kwarg_vals, key,
-                    training)
+                    param_vals, buffer_vals, arg_vals, kept_vals,
+                    kwarg_vals, key, training)
                 # ProgramInventory capture: cache growth means this call
                 # compiled a fresh program — record its specs (shape-only;
                 # donated leaves are aval-readable shells by now) so cost
@@ -219,7 +262,8 @@ class StaticFunction:
                     self._seen_programs = n_now
                     get_program_inventory().capture(
                         self._tracker_name, "static_function", self._jitted,
-                        (param_vals, buffer_vals, arg_vals, kwarg_vals, key),  # graft-lint: disable=donation-alias
+                        (param_vals, buffer_vals, arg_vals, kept_vals,  # graft-lint: disable=donation-alias
+                         kwarg_vals, key),
                         {"training": training},
                         donate_argnums=self._donate_argnums)
             for b, v in zip(buffers, new_buffer_vals):
@@ -239,7 +283,7 @@ class StaticFunction:
             it = iter(dpv)
             pv = [next(it) if i in diff_set else param_vals[i]
                   for i in range(len(params))]
-            return self._jitted_nodonate(pv, buffer_vals, av, kv, key,
+            return self._jitted_nodonate(pv, buffer_vals, av, None, kv, key,
                                          training)
 
         (out_vals, new_buffer_vals), vjp_fn = jax.vjp(

@@ -54,6 +54,36 @@ PagedCacheSlot = namedtuple("PagedCacheSlot", ["k_pool", "v_pool",
                                                "block_table", "pos", "base"],
                             defaults=(None,))
 
+# the fields of a cache slot that are KV buffers: what a compiled serving
+# step donates, updates in place and gives back
+_POOL_FIELDS = ("k_pool", "v_pool", "k", "v")
+
+
+def donate_pools(ids, position_ids, caches, *rest):
+    """The one donation rule of the compiled serving steps, as
+    ``StaticFunction(donate_args=...)`` asks for it: of a launch's
+    arguments ``(ids, position_ids, caches, ...)`` the K and V pools of
+    every cache slot are donated (they are threaded through the step and
+    updated in place, one resident copy), and nothing else is: a block
+    table, a position vector or a base may be one array shared by every
+    layer, and stays valid after the call."""
+    return (False, False,
+            [type(c)(*(f in _POOL_FIELDS for f in c._fields))
+             for c in caches],
+            ) + (False,) * len(rest)
+
+
+def pools_only(caches):
+    """``caches`` with every field but the pools left out (None): what a
+    compiled serving step gives back. The table, the positions and the
+    base are the caller's own inputs, not donated, so a step that returned
+    them would make a fresh device buffer of each for every layer at every
+    launch (2.3 ms of host time a launch for 24 layers on a v5e's host:
+    PERF.md, PR 29)."""
+    return [type(c)(*(t if f in _POOL_FIELDS else None
+                      for f, t in zip(c._fields, c))) for c in caches]
+
+
 # What one layer caches: KV heads, the width of a K row and of a V row,
 # ``window`` (None: the whole context; w: a query sees the last w positions,
 # itself included), and ``fold_heads`` (the pools keep a token's KV heads

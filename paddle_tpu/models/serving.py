@@ -32,8 +32,10 @@ from paddle_tpu.models.kv_cache import (
     PagedCacheSlot,
     StaticCacheSlot,
     cache_geometry,
+    donate_pools,
     make_static_cache,
     pool_shapes,
+    pools_only,
 )
 from paddle_tpu.observability.step_profile import region
 from paddle_tpu.tensor import Tensor
@@ -100,19 +102,23 @@ class SlotStep:
     Shared kernel path for ``DecodeEngine`` (static whole-batch loop) and the
     continuous-batching scheduler (``paddle_tpu.serving``): one instance owns
     one jit program cache, so prefill buckets and the fixed-shape decode step
-    each compile once and are reused across requests/admissions. Cache
-    buffers are donated — callers must thread caches through and never reuse
-    a cache argument after the call.
+    each compile once and are reused across requests/admissions. The KV
+    pools of ``caches`` are donated (``kv_cache.donate_pools``) — callers
+    must thread them through and never reuse a pool after the call.
+    Nothing else is: ``ids``, ``position_ids``, ``gather_idx`` and each
+    slot's ``block_table`` / ``pos`` / ``base`` are ordinary inputs, so one
+    table and one position tensor serve every layer and a constant input
+    may be kept on the device across launches. ``new_caches`` carries the
+    updated pools alone (``kv_cache.pools_only``): the caller states the
+    table and the positions of the next launch itself.
 
     Carry contract (dispatch-ahead decode): ``next_ids`` is a device-
     resident ``[B]`` int32 array sampled in-graph, so a caller can feed it
-    straight back as the NEXT step's ``ids`` without a host round-trip —
-    reshape it to ``[B, 1]`` first (``paddle.reshape`` allocates a fresh
-    buffer, so the donated decode input never aliases the carry a drain
-    thread still has to read). ``splice_carry`` patches admission tokens
+    straight back as the NEXT step's ``ids`` without a host round-trip,
+    reshaped to ``[B, 1]``. ``splice_carry`` patches admission tokens
     into the carry on device.
 
-    ``donate=False`` opts out of arg donation: on TPU donation is a
+    ``donate=False`` opts out of pool donation: on TPU donation is a
     compile-time aliasing hint and composes with async dispatch, but
     XLA:CPU executes a donated call SYNCHRONOUSLY (the runtime hands the
     buffer over on the host), which would re-serialize a dispatch-ahead
@@ -132,7 +138,7 @@ class SlotStep:
         # compiled step at construction; it changes outputs, not programs
         self.telemetry = bool(telemetry)
         self._sf = StaticFunction(self._forward_sample, layer=model,
-                                  donate_args=donate,
+                                  donate_args=donate and donate_pools,
                                   name="serving.SlotStep")
 
     def __call__(self, ids, position_ids, caches, gather_idx):
@@ -194,7 +200,7 @@ class SlotStep:
                     stats = apply("step_telemetry_extra",
                                   lambda a, b: jnp.concatenate([a, b]),
                                   stats, extra, differentiable=False)
-        return next_ids, stats, new_caches
+        return next_ids, stats, pools_only(new_caches)
 
 
 class DecodeEngine:
@@ -227,9 +233,9 @@ class DecodeEngine:
         self.block_size = block_size
         self.num_blocks = num_blocks
         self.cache_dtype = cache_dtype
-        # SlotStep donates args: the decode loop threads cache buffers
-        # through the compiled step and never reuses an input array after
-        # the call, so the KV caches update in place (no 2x cache residency)
+        # SlotStep donates the KV buffers: the decode loop threads them
+        # through the compiled step and never reuses one after the call,
+        # so the KV caches update in place (no 2x cache residency)
         self._step = SlotStep(model, temperature=temperature, top_k=top_k)
         self._sf = self._step._sf  # back-compat alias (recompile tests)
 
@@ -252,15 +258,16 @@ class DecodeEngine:
         for i, blks in enumerate(per_seq_blocks):
             table[i, :len(blks)] = blks
         slots = []
+        # one table and one position vector for every layer: only the
+        # pools are donated to the compiled step
+        table_t = paddle.to_tensor(table)
+        pos_t = paddle.zeros([batch], dtype="int32")
         for g in self.geometry:
             # a window layer keeps its whole table here (one class of
             # blocks): the window is the layer's mask, not a saving
             kp, vp = (paddle.zeros(shape, dtype=self.cache_dtype)
                       for shape in pool_shapes(g, n_blocks, self.block_size))
-            # per-layer copies: cache args are donated to the compiled step,
-            # and a buffer must not appear twice in a donated pytree
-            slots.append(PagedCacheSlot(kp, vp, paddle.to_tensor(table),
-                                        paddle.zeros([batch], dtype="int32")))
+            slots.append(PagedCacheSlot(kp, vp, table_t, pos_t))
         return slots, alloc, per_seq_blocks
 
     # ---- serving loop --------------------------------------------------
@@ -306,31 +313,34 @@ class DecodeEngine:
                 ids = paddle.to_tensor(ids_np.astype(np.int32))
                 pos_ids = paddle.to_tensor(np.arange(Pb, dtype=np.int32))
                 gather = paddle.to_tensor(lens - 1)
+                # the table is the caller's to state at every launch: the
+                # step gives back the pools alone
+                fixed = ({"block_table": caches[0].block_table}
+                         if self.use_paged else {})
                 next_ids, _stats, caches = self._sf(ids, pos_ids, caches,
                                                     gather)
-                # prefill advanced pos by the padded width; the true valid
-                # length is the prompt length (pad rows are masked out).
-                # Per-layer pos copies: donated pytrees must not repeat a
-                # buffer.
-                caches = [c._replace(pos=paddle.to_tensor(lens))
-                          for c in caches]
+                zero_gather = paddle.to_tensor(np.zeros(B, np.int32))
 
                 out_tokens = [np.asarray(next_ids.numpy())]
                 finished = np.zeros(B, dtype=bool)
                 if eos_token_id is not None:
                     finished |= out_tokens[0] == eos_token_id
+                # prefill advanced pos by the padded width; the true valid
+                # length is the prompt length (pad rows are masked out)
                 cur_lens = lens.copy()
 
                 for _ in range(1, max_new_tokens):
                     if finished.all():
                         break
                     tok = paddle.reshape(next_ids, [B, 1])
-                    # per-batch absolute positions for RoPE / pos-embedding
-                    p = paddle.reshape(paddle.to_tensor(cur_lens), [B, 1])
-                    # fresh every step: args are donated to the compiled call
-                    zero_gather = paddle.to_tensor(np.zeros(B, np.int32))
-                    next_ids, _stats, caches = self._sf(tok, p, caches,
-                                                        zero_gather)
+                    # one upload a step, for every layer's cache position
+                    # and, as [B, 1], the absolute positions for RoPE /
+                    # pos-embedding
+                    pos_t = paddle.to_tensor(cur_lens.copy())
+                    caches = [c._replace(pos=pos_t, **fixed) for c in caches]
+                    next_ids, _stats, caches = self._sf(
+                        tok, paddle.reshape(pos_t, [B, 1]), caches,
+                        zero_gather)
                     cur_lens += 1
                     step_np = np.asarray(next_ids.numpy())
                     if eos_token_id is not None:

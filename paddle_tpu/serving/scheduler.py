@@ -168,9 +168,9 @@ def _drain_worker(sched_ref):
 
 
 def _backend_donates() -> bool:
-    """Whether the compiled steps donate their cache arguments: on every
-    backend but XLA:CPU (reasons at the call in ``__init__``). A function
-    so that a CPU test can force the branches the chip takes."""
+    """Whether the compiled steps donate their KV pools: on every backend
+    but XLA:CPU (reasons at the call in ``__init__``). A function so that
+    a CPU test can force the executables the chip runs."""
     import jax
 
     return jax.default_backend() != "cpu"
@@ -229,7 +229,9 @@ class ContinuousBatchingScheduler:
         max_pos = getattr(mcfg, "max_position_embeddings", cfg.max_seq_len)
         self.max_seq_len = min(cfg.max_seq_len, max_pos)
         self.metrics = metrics or ServingMetrics()
-        # donation keeps the KV pools single-resident, and on TPU it is a
+        # the compiled steps donate the KV pools and nothing else (what is
+        # staged for a launch is the same on every backend: ``_caches``).
+        # Donation keeps the pools single-resident, and on TPU it is a
         # compile-time aliasing hint that composes with async dispatch —
         # so the TPU engine donates at every depth. XLA:CPU however
         # executes donated calls SYNCHRONOUSLY (the runtime hands buffers
@@ -385,6 +387,10 @@ class ContinuousBatchingScheduler:
         self.dispatch_depth = max(0, int(cfg.dispatch_depth))
         self._disp_pos = np.zeros(S, np.int32)
         self._disp_emitted = np.zeros(S, np.int32)
+        # a decode launch samples every row at its one position: the
+        # all-zero gather index is a constant, uploaded here once and kept
+        # on the device (the steps donate the pools only)
+        self._zero_gather = paddle.to_tensor(np.zeros(S, np.int32))
         self._elock = threading.Condition(threading.RLock())
         self._inflight: deque = deque()          # _InFlight, FIFO
         self._carry = None
@@ -566,27 +572,25 @@ class ContinuousBatchingScheduler:
     def _caches(self, table: np.ndarray, pos: np.ndarray,
                 wtable: Optional[np.ndarray] = None,
                 wbase: Optional[np.ndarray] = None):
-        """Fresh per-layer PagedCacheSlots over the shared pools; a window
-        layer gets the window class's table (``wtable``) and the position
-        of its first column (``wbase``). When args
-        are donated into the compiled step the table/pos tensors must be
-        rebuilt per layer (a donated pytree must not repeat a buffer); a
-        non-donating step shares ONE tensor across layers — 2 host->device
-        transfers per decode step instead of 2*num_layers, which matters on
-        the dispatch-ahead hot path where staging is the critical-path
-        cost."""
-        up = paddle.to_tensor
+        """Fresh per-layer PagedCacheSlots over the shared pools. What the
+        host built for this launch goes up ONCE, whatever the depth of the
+        model: one block table and one position vector, shared by every
+        layer's slot, and for a model with window layers the window
+        class's table (``wtable``) and the position of its first column
+        (``wbase``), shared by those. The compiled steps donate the pools
+        and nothing else (``kv_cache.donate_pools``), so a shared tensor is
+        an ordinary input on every backend. Each upload is a copy made
+        here: the scheduler mutates its tables and positions in place
+        right after a dispatch, and a long-lived host buffer may not cross
+        the jax boundary while a dispatched step can still read it."""
+        def up(a):
+            return paddle.to_tensor(a.copy())
 
-        def uploads(window: bool):
-            return ((up(wtable), up(pos), up(wbase)) if window
-                    else (up(table), up(pos)))
-
+        pos_t = up(pos)
         kinds = [bool(g.window) for g in self._geometry]
-        if self._donate:
-            return [PagedCacheSlot(kp, vp, *uploads(w))
-                    for (kp, vp), w in zip(self._pools, kinds)]
-        shared = {w: uploads(w) for w in set(kinds)}
-        return [PagedCacheSlot(kp, vp, *shared[w])
+        full = (up(table), pos_t) if False in kinds else None
+        win = (up(wtable), pos_t, up(wbase)) if True in kinds else None
+        return [PagedCacheSlot(kp, vp, *(win if w else full))
                 for (kp, vp), w in zip(self._pools, kinds)]
 
     def _release_blocks(self, req: Request, slot: int = -1):
@@ -1477,8 +1481,7 @@ class ContinuousBatchingScheduler:
                 np.clip(pos, 0, self.max_seq_len - 1, out=pos)
                 args = (paddle.to_tensor(ids),
                         paddle.to_tensor(pos.astype(np.int32)),
-                        self._caches(self._disp_table(),
-                                     self._disp_pos.copy()))
+                        self._caches(self._disp_table(), self._disp_pos))
             with RecordEvent("serving.launch"):
                 out, caches = self._spec_step(*args)
                 self._store_pools(caches)
@@ -1585,9 +1588,7 @@ class ContinuousBatchingScheduler:
     def _decode_ids(self):
         """Token ids [S, 1] for the next decode dispatch: the device-
         resident carry when one exists (no host round-trip), else the
-        committed host tokens. ``paddle.reshape`` allocates a fresh
-        buffer, so donating the result never invalidates the carry the
-        drain thread still has to read."""
+        committed host tokens."""
         S = self.config.max_num_seqs
         if self._carry is not None:
             return paddle.reshape(self._carry, [S, 1])
@@ -1614,20 +1615,17 @@ class ContinuousBatchingScheduler:
         inject("serving.decode_step")
         with RecordEvent("serving.decode_step"), paddle.no_grad():
             with RecordEvent("serving.stage"):
-                # fresh copy: _disp_pos is mutated in place right below,
-                # and a long-lived host buffer crossing the jax boundary
-                # while a dispatched-but-unexecuted step still refers to it
-                # is exactly the stale-transfer hazard async dispatch
-                # exposes
+                # _disp_pos is mutated in place right below: _caches
+                # uploads a copy of it (the stale-transfer hazard async
+                # dispatch exposes), and the step's [S, 1] position ids
+                # are that one upload, reshaped on the device
+                caches = self._caches(
+                    self._disp_table(), self._disp_pos,
+                    *((self._disp_table(self._wtable), self._wbase)
+                      if self._window is not None else ()))
                 args = (self._decode_ids(),
-                        paddle.to_tensor(
-                            self._disp_pos.reshape(S, 1).astype(np.int32)),
-                        self._caches(
-                            self._disp_table(), self._disp_pos.copy(),
-                            *((self._disp_table(self._wtable),
-                               self._wbase.copy())
-                              if self._window is not None else ())),
-                        paddle.to_tensor(np.zeros(S, np.int32)))
+                        paddle.reshape(caches[0].pos, [S, 1]),
+                        caches, self._zero_gather)
             with RecordEvent("serving.launch"):
                 t_call = pc()
                 next_ids, stats, caches = self._step_fn(*args)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.dispatch import apply
 from paddle_tpu.jit.api import StaticFunction
+from paddle_tpu.models.kv_cache import donate_pools, pools_only
 from paddle_tpu.observability.step_profile import region
 
 __all__ = ["ChunkPrefillStep", "SpecVerifyStep"]
@@ -49,7 +50,7 @@ class ChunkPrefillStep:
     def __init__(self, step, donate: bool = True):
         self._step = step
         self._sf = StaticFunction(self._forward, layer=step.model,
-                                  donate_args=donate,
+                                  donate_args=donate and donate_pools,
                                   name="serving.ChunkPrefill")
 
     def __call__(self, ids, position_ids, caches, gather_idx):
@@ -76,7 +77,7 @@ class ChunkPrefillStep:
 
             next_ids = apply("sample_next", pick, logits, gather_idx,
                              differentiable=False)
-        return next_ids, new_caches
+        return next_ids, pools_only(new_caches)
 
 
 class SpecVerifyStep:
@@ -102,7 +103,7 @@ class SpecVerifyStep:
     def __init__(self, step, donate: bool = True):
         self._step = step
         self._sf = StaticFunction(self._forward, layer=step.model,
-                                  donate_args=donate,
+                                  donate_args=donate and donate_pools,
                                   name="serving.SpecVerify")
 
     def __call__(self, ids, position_ids, caches):
@@ -132,4 +133,4 @@ class SpecVerifyStep:
 
             out = apply("spec_verify", verify, logits, ids,
                         differentiable=False)
-        return out, new_caches
+        return out, pools_only(new_caches)

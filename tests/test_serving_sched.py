@@ -374,18 +374,24 @@ def test_fault_backoff_releases_engine_lock(model):
 
 # ------------------------------------------- the branches the chip takes
 
-def test_donation_forced_on_is_token_identical(monkeypatch):
-    """``_donate`` is False on XLA:CPU, so the scheduler's three
-    ``if self._donate:`` branches (decode, admission prefill, chunk pump)
-    are what every TPU run takes and what no CPU test used to. Force
-    donation on — plain, dispatch-ahead, prefix cache, chunked prefill,
-    speculation — and require the undonated plain run's tokens (every one
-    of those features is token-identical to it by contract)."""
-    from paddle_tpu.serving import scheduler as sched_mod
+def _donation_case(kind):
+    """``(model, prompts, scheduler sizes, new tokens, the feature sets)``
+    of one donation run: GPT with every feature, or a toy MiMo-V2 (window
+    and full layers: both block classes, with window rolls and page
+    releases inside the run), whose one-class features refuse."""
+    rng = np.random.default_rng(3)
+    if kind == "window":
+        from paddle_tpu.models.mimo_v2 import MiMoV2ForCausalLM, mimo_v2_tiny
 
+        paddle.seed(0)
+        model = MiMoV2ForCausalLM(mimo_v2_tiny(experts_held=(4, 8)))
+        model.eval()
+        prompts = [rng.integers(0, 256, n) for n in (5, 19, 11, 26, 9)]
+        sizes = dict(max_num_seqs=3, max_seq_len=64, block_size=4,
+                     cache_dtype="float32")
+        return model, prompts, sizes, 14, ({}, dict(dispatch_depth=2))
     paddle.seed(7)
     model = GPTForCausalLM(gpt_tiny(num_layers=1))   # six schedulers: small
-    rng = np.random.default_rng(3)
     shared = rng.integers(0, 1000, 16)            # a cacheable prefix
     pattern = rng.integers(0, 1000, 5)            # n-gram proposals fire
     prompts = [np.concatenate([shared, rng.integers(0, 1000, 5)]),
@@ -393,19 +399,40 @@ def test_donation_forced_on_is_token_identical(monkeypatch):
                np.concatenate([shared, rng.integers(0, 1000, 9)]),
                rng.integers(0, 1000, 40),         # several chunks
                np.concatenate([shared, rng.integers(0, 1000, 5)])]
+    sizes = dict(max_num_seqs=3, max_seq_len=128, block_size=8)
+    return model, prompts, sizes, 6, (
+        {}, dict(dispatch_depth=2), dict(enable_prefix_caching=True),
+        dict(prefill_chunk_size=16), dict(spec_k=3))
+
+
+@pytest.mark.parametrize("kind", ["gpt", "window"])
+def test_donation_forced_on_is_token_identical(monkeypatch, kind):
+    """``_donate`` is False on XLA:CPU, so the executables that donate
+    their KV pools (decode, admission prefill, chunk, verify) are what
+    every TPU run takes and what no CPU test used to. Force donation on —
+    plain, dispatch-ahead, prefix cache, chunked prefill, speculation —
+    and require the undonated plain run's tokens (every one of those
+    features is token-identical to it by contract). What is staged is the
+    same either way: one table and one position tensor for all layers."""
+    from paddle_tpu.serving import scheduler as sched_mod
+
+    model, prompts, sizes, new, feature_sets = _donation_case(kind)
 
     def run(donate, **over):
         monkeypatch.setattr(sched_mod, "_backend_donates", lambda: donate)
         sched = ContinuousBatchingScheduler(model, SchedulerConfig(
-            max_num_seqs=3, max_seq_len=128, block_size=8, **over))
+            **sizes, **over))
         assert sched._donate is donate
-        outs = sched.generate(prompts, max_new_tokens=6)
+        outs = sched.generate(prompts, max_new_tokens=new)
         assert sched.metrics.requests_failed == 0
         assert not sched.metrics.faults_snapshot()
+        if kind == "window":
+            # rows rolled past the window and gave pages back in the run
+            assert sched._window_released.value > 0
+            assert sched.window_allocator.num_used_blocks == 0
         sched.shutdown()
         return [[int(t) for t in o] for o in outs]
 
     undonated = run(False)
-    for over in ({}, dict(dispatch_depth=2), dict(enable_prefix_caching=True),
-                 dict(prefill_chunk_size=16), dict(spec_k=3)):
+    for over in feature_sets:
         assert run(True, **over) == undonated, over
