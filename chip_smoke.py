@@ -17,7 +17,8 @@ weights), and checks what comes out:
   every request finished, no fault, no steady-state recompile, and the
   paged-cache logits agree with the plain eager forward;
 - train:   a few ``TrainStep`` steps in the configuration of
-  ``bench.py::bench_gpt3_1p3b``, loss finite and falling, flash path Pallas.
+  ``perfbench/configs/gpt3_1p3b_train_amp.json``, loss finite and falling,
+  flash path Pallas.
 
 Any phase that raises or any check that fails ends the run with a non-zero
 exit code and no result line. The last line of a passing run is one JSON
@@ -568,7 +569,7 @@ def phase_train(rehearse: bool) -> dict:
     compiles = _CompileCounter()
     t0 = time.perf_counter()
     paddle.seed(0)
-    # bench.py::bench_gpt3_1p3b's chip branch: fp32 params (the master
+    # as the train_pretrain cell's configuration: fp32 params (the master
     # copy; bf16 compute from auto_cast O1), bf16 AdamW moments,
     # dots_saveable recompute, vocab-chunked fused linear-CE
     cfg = _model_config(rehearse, recompute="dots_saveable")

@@ -17,8 +17,8 @@ previous commit stays intact and discoverable. Bit corruption is caught by
 back to the previous commit.
 
 Backpressure: one save may be in flight; the next ``save`` first joins the
-writer and records the wait as ``checkpoint_backpressure_stall_seconds`` —
-the number ``tools/ckpt_bench.py`` pins as train-step stall.
+writer and records the wait as ``checkpoint_backpressure_stall_seconds``:
+the stall a save costs the train loop.
 """
 
 from __future__ import annotations

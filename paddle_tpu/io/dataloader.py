@@ -75,8 +75,7 @@ class DevicePrefetcher:
 
     ``depth=0`` is the single-buffered reference path: no thread, the
     fetch+transfer runs inline on the consumer (and is charged to the same
-    stall metric), which is exactly what ``tools/train_bench.py`` measures
-    the overlap win against.
+    stall metric): what the overlap is compared with.
 
     ``sharding`` is ``None`` (commit to the default device), a
     ``jax.sharding.Sharding`` applied to every array leaf, or a callable

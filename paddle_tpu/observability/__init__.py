@@ -47,9 +47,9 @@ serving, and distributed code:
 - **Program inventory** (``program_inventory.py``): XLA
   ``cost_analysis()``/``memory_analysis()`` for every compiled executable
   the CompileTracker sees (TrainStep, SlotStep decode, prefill buckets) —
-  FLOPs, bytes accessed, peak temp memory, donation map — plus the
-  ``DeviceTimeSampler`` + ``roofline_utilization`` pair that turns them
-  into ``train_mfu`` / ``serving_decode_bandwidth_util``.
+  FLOPs, bytes accessed, peak temp memory, donation map — plus
+  ``chip_specs()`` / ``roofline_utilization`` for a device time taken
+  from a trace.
 - **Fleet observability** (``fleet.py``): cross-replica request journeys
   (``FleetTracer`` — one chrome-trace track per router request spanning
   failovers), tiered metrics time-series history (``MetricsTimeline`` —
@@ -118,7 +118,6 @@ from paddle_tpu.observability.metrics import (  # noqa: F401
     parse_prometheus_text,
 )
 from paddle_tpu.observability.program_inventory import (  # noqa: F401
-    DeviceTimeSampler,
     ProgramInventory,
     chip_specs,
     get_program_inventory,
@@ -157,7 +156,6 @@ __all__ = [
     "CompileTracker",
     "Counter",
     "DeviceMemoryLedger",
-    "DeviceTimeSampler",
     "EvictionThrash",
     "FleetTracer",
     "FlightRecorder",

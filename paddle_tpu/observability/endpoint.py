@@ -257,8 +257,7 @@ class ObservabilityEndpoint:
         """The ``/debug/stepprofile`` payload: each attached scheduler's
         latest named-region capture summary + telemetry snapshot. Read-
         only host state — a scrape NEVER triggers a capture (captures run
-        a device trace; start them from ``capture_step_profile`` /
-        ``serve_bench --profile-steps``)."""
+        a device trace; start them from ``capture_step_profile``)."""
         out = {}
         for name, fn in self._stepprofile_sources.items():
             try:

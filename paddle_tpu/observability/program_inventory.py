@@ -21,13 +21,9 @@ How it stays off the hot path:
   cannot trip from a `/debug/programs` scrape. Results are cached on the
   entry; the jitted reference is dropped after a successful analysis.
 
-``DeviceTimeSampler`` is the roofline's other half: host-timestamped
-decode step times that stay honest at every ``dispatch_depth`` (span =
-dispatch→drain-completion, inter = consecutive drain completions; the
-min of the two medians is the step-time estimate that is right in both
-regimes). Combined with inventory FLOPs/bytes and ``chip_specs()``
-peaks, ``roofline_utilization`` yields ``train_mfu`` and
-``serving_decode_bandwidth_util``.
+``chip_specs()`` and ``roofline_utilization`` turn a program's
+FLOPs/bytes and a device time taken from a trace into utilisation
+(``step_profile.py`` is the caller).
 """
 
 from __future__ import annotations
@@ -35,7 +31,6 @@ from __future__ import annotations
 import os
 import threading
 import warnings
-from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -44,7 +39,6 @@ from paddle_tpu.observability.metrics import MetricsRegistry, get_registry
 from paddle_tpu.profiler import RecordEvent
 
 __all__ = [
-    "DeviceTimeSampler",
     "ProgramEntry",
     "ProgramInventory",
     "chip_specs",
@@ -386,60 +380,3 @@ def get_program_inventory() -> ProgramInventory:
         if _inventory is None:
             _inventory = ProgramInventory(registry=get_registry())
         return _inventory
-
-
-# ------------------------------------------------------- device step timing
-
-class DeviceTimeSampler:
-    """Async-safe decode step-time estimation from host timestamps.
-
-    Two sampled series, both O(1) per observation and bounded:
-
-    - **span**: dispatch → drain-completion of the same step. At
-      ``dispatch_depth=0`` this IS the device step (the fetch blocks
-      inline); at depth>0 it mis-counts in either direction (queue
-      wait inflates it; a fetch landing on an already-finished step
-      deflates it).
-    - **inter**: delta between consecutive completions. In a full
-      depth>0 pipeline this converges to the true device step; at
-      depth 0 it over-counts by host commit work between steps.
-
-    The consumer picks by regime (the scheduler knows its
-    ``dispatch_depth``: span at depth 0, inter at depth>0);
-    ``snapshot()``'s generic ``step_time_s`` falls back to the min of
-    the two medians. No device markers, no extra syncs, no behavior
-    change (pure host timestamping ⇒ tokens bit-identical with the
-    sampler on or off).
-    """
-
-    def __init__(self, window: int = 256):
-        self._lock = threading.Lock()
-        self._spans = deque(maxlen=window)
-        self._inters = deque(maxlen=window)
-        self._last_complete: Optional[float] = None
-        self._count = 0
-
-    def observe(self, t_dispatch: float, t_complete: float) -> None:
-        span = max(0.0, t_complete - t_dispatch)
-        with self._lock:
-            self._spans.append(span)
-            if self._last_complete is not None:
-                delta = t_complete - self._last_complete
-                if 0.0 < delta < 10.0:     # drop idle gaps between bursts
-                    self._inters.append(delta)
-            self._last_complete = t_complete
-            self._count += 1
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            spans, inters = list(self._spans), list(self._inters)
-            count = self._count
-        med_span = float(np.median(spans)) if spans else None
-        med_inter = float(np.median(inters)) if inters else None
-        candidates = [v for v in (med_span, med_inter) if v is not None]
-        return {
-            "steps_observed": count,
-            "span_median_s": med_span,
-            "inter_completion_median_s": med_inter,
-            "step_time_s": min(candidates) if candidates else None,
-        }

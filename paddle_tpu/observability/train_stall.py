@@ -21,7 +21,7 @@ docstring:
   instead of faulting XLA's no-double-donation rule.
 
 All live in the process-wide default registry, so ``Profiler.export_report``
-and ``tools/train_bench.py`` read them with no extra plumbing.
+reads them with no extra plumbing.
 """
 
 from __future__ import annotations
@@ -77,7 +77,7 @@ def set_offload_overlap_ratio(ratio: float):
 
 
 def stall_snapshot() -> dict:
-    """The stall breakdown as one plain dict (train_bench's artifact rows).
+    """The stall breakdown as one plain dict.
 
     Registers the metrics on first read so a snapshot taken before any
     training reports explicit zeros rather than missing keys."""

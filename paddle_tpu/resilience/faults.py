@@ -4,12 +4,11 @@ The serving/checkpoint stack is threaded with runtime-inert ``inject(site)``
 hooks at the places real deployments actually fail (device step dispatch,
 prefill, block allocation, checkpoint shard/manifest/rename I/O, weight
 reload, prefix-cache insert). With no plan armed a hook is one global
-``None`` check — measured well under 1% of the serving smoke bench
-(``BENCH_serving_chaos.json``) and philosophically identical to the
-runtime-inert observability annotations. With a plan armed, the hook raises
-``InjectedFault`` exactly where a crash/device error would surface, so every
-recovery path in the scheduler and the checkpoint commit protocol is
-testable deterministically — no subprocess kills, no timing races.
+``None`` check, like the runtime-inert observability annotations. With a
+plan armed, the hook raises ``InjectedFault`` exactly where a crash/device
+error would surface, so every recovery path in the scheduler and the
+checkpoint commit protocol is testable deterministically — no subprocess
+kills, no timing races.
 
 ``FaultPlan`` is seeded: per-site probability draws come from one
 ``random.Random(seed)``, and ``at=(n, ...)`` fires on exact hit counts, so
@@ -156,7 +155,7 @@ class FaultInjector:
     """Process-wide injection state: the armed plan + hit/fire accounting.
 
     Thread contract: the serving loop and checkpoint writer threads both
-    call ``check()`` while a test (or the chaos bench) arms/disarms —
+    call ``check()`` while a test arms/disarms —
     counters, the event ring, and listeners are touched under ``_lock``.
     The disarmed fast path reads ``_plan`` without the lock: it is a
     single reference read, and the worst race is one extra armed/disarmed
@@ -256,8 +255,8 @@ def get_injector() -> FaultInjector:
 
 def inject(site: str) -> None:
     """The injection hook. Runtime-inert when no plan is armed: one global
-    reference read + ``None`` check (the zero-overhead contract the chaos
-    bench asserts). Armed, it may raise ``InjectedFault``."""
+    reference read + ``None`` check. Armed, it may raise
+    ``InjectedFault``."""
     if _INJECTOR._plan is None:
         return
     _INJECTOR.check(site)

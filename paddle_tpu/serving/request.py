@@ -101,9 +101,9 @@ class SchedulerConfig:
     tpot_slo_s: Optional[float] = None
     ttft_breach_streak: int = 4       # consecutive breaches -> alarm
     # Device-side observability: HBM ledger (owner-tagged device bytes,
-    # OOM forensics) + decode step-time sampling for roofline gauges.
-    # Host-side bookkeeping only — tokens are bit-identical on vs off at
-    # every dispatch_depth (pinned in tests).
+    # OOM forensics) beside the program inventory. Host-side bookkeeping
+    # only — tokens are bit-identical on vs off at every dispatch_depth
+    # (pinned in tests).
     enable_device_observability: bool = True
     # In-program step telemetry: a tiny on-device stats block (slot
     # occupancy, sampled-token entropy/max-prob, kv blocks touched)

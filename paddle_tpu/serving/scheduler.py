@@ -78,12 +78,7 @@ from paddle_tpu.observability.device_memory import (
     tree_nbytes,
 )
 from paddle_tpu.observability.fleet import MetricsTimeline, PostmortemStore
-from paddle_tpu.observability.program_inventory import (
-    DeviceTimeSampler,
-    chip_specs,
-    get_program_inventory,
-    roofline_utilization,
-)
+from paddle_tpu.observability.program_inventory import get_program_inventory
 from paddle_tpu.observability.request_trace import (
     PHASE_ADMIT,
     PHASE_PREEMPTED,
@@ -140,14 +135,13 @@ class _InFlight:
     drain thread fetches ``next_ids`` off the critical path and commits
     the tokens against the snapshot (retired slots discard as stale)."""
 
-    __slots__ = ("kind", "next_ids", "slots", "stats", "t_dispatch")
+    __slots__ = ("kind", "next_ids", "slots", "stats")
 
     def __init__(self, kind: str, next_ids, slots, stats=None):
         self.kind = kind          # "decode" | "admit"
         self.next_ids = next_ids  # device int32: [S] (decode) / [1] (admit)
         self.slots = slots        # [(slot, Request), ...] at dispatch time
         self.stats = stats        # device f32[4] telemetry block (or None)
-        self.t_dispatch = _time.perf_counter()   # DeviceTimeSampler anchor
 
 
 @thread_role("serving-drain")
@@ -401,7 +395,7 @@ class ContinuousBatchingScheduler:
         self._last_telemetry: Optional[dict] = None
         self._drain_thread: Optional[threading.Thread] = None
         self._drain_stop = False
-        # ---- device-side observability (HBM ledger + roofline) ---------
+        # ---- device-side observability (HBM ledger) --------------------
         # Coarse owner-tagged accounting registered HERE, at the one site
         # that constructs the pools — nothing below runs per decode step.
         # (a window layer's bytes do not grow with the tokens)
@@ -419,7 +413,6 @@ class ContinuousBatchingScheduler:
                 "window-class KV blocks returned to the free list while "
                 "their request was still running")
         self.device_ledger: Optional[DeviceMemoryLedger] = None
-        self._device_time: Optional[DeviceTimeSampler] = None
         if cfg.enable_device_observability:
             self.device_ledger = DeviceMemoryLedger(
                 registry=self.metrics.registry)
@@ -431,7 +424,6 @@ class ContinuousBatchingScheduler:
             self.device_ledger.register_arrays(
                 "model_weights", "serving_model",
                 [p for p in model.parameters()])
-            self._device_time = DeviceTimeSampler()
             self.metrics.registry.gauge(
                 "kv_bytes_per_token",
                 "device KV-cache bytes appended per generated token",
@@ -1355,15 +1347,10 @@ class ContinuousBatchingScheduler:
                     pairs = self._live_pairs()
                 if not pairs:
                     return finished
-                t_disp = _time.perf_counter()
                 next_ids, stats, _disp_s = self._dispatch_decode(pairs)
                 dispatched = True
                 arr, stats_np, _sync_s = self._fetch_tokens(next_ids,
                                                             stats=stats)
-                if self._device_time is not None:
-                    # depth 0: the inline fetch blocks until the device is
-                    # done, so dispatch→fetch-return IS the step time
-                    self._device_time.observe(t_disp, _time.perf_counter())
             except Exception as exc:
                 if dispatched:
                     # tokens were lost after the dispatch advanced the
@@ -1439,11 +1426,8 @@ class ContinuousBatchingScheduler:
                     pairs = self._live_pairs()
                 if not pairs:
                     return finished
-                t_disp = _time.perf_counter()
                 out_dev = self._dispatch_spec(props)
                 arr, _stats_np, _sync_s = self._fetch_tokens(out_dev)
-                if self._device_time is not None:
-                    self._device_time.observe(t_disp, _time.perf_counter())
             except Exception as exc:
                 finished += self._absorb_step_fault(
                     exc, [s for s, _r in pairs], attempt)
@@ -1711,11 +1695,6 @@ class ContinuousBatchingScheduler:
                                                   phase="drain",
                                                   stats=entry.stats)
             exc: Optional[BaseException] = None
-            if entry.kind == "decode" and self._device_time is not None:
-                # fetch-return = step completion: pure host timestamping,
-                # thread-safe inside the sampler, no device perturbation
-                self._device_time.observe(entry.t_dispatch,
-                                          _time.perf_counter())
         except BaseException as e:        # noqa: BLE001 — must not die silently
             arr, stats_np, exc = None, None, e
         with self._elock:
@@ -2419,78 +2398,6 @@ class ContinuousBatchingScheduler:
             except AttributeError:
                 pass  # uncommitted host value; keep looking
         return frozenset(devs)
-
-    def device_observability(self, analyze: bool = True) -> Dict[str, object]:
-        """Roofline-attributed device snapshot: sampled decode step time ×
-        the decode program's cost-analysis bytes/FLOPs over the chip peaks
-        (``chip_specs()``), plus the owner-tagged memory census.
-
-        ``analyze=True`` may AOT-compile the decode program for cost
-        analysis the first time — a cold-path compile that does NOT touch
-        the runtime program cache (zero-steady-state-recompile safe), so
-        call it from benches/scrapes, never from the hot loop."""
-        if self._device_time is None:
-            return {"enabled": False}
-        st = self._device_time.snapshot()
-        out: Dict[str, object] = {
-            "enabled": True,
-            "kv_bytes_per_token": int(self._kv_bytes_per_token),
-            "device_step_time": st,
-            "memory": (self.device_ledger.census_report()
-                       if self.device_ledger is not None else None),
-        }
-        # pick the estimator by dispatch regime: at depth 0 the span
-        # (dispatch -> fetch) IS the device step; at depth > 0 the pipeline
-        # is full and the span under-measures (the fetch lands on an
-        # already-finished step) — the inter-completion interval is the
-        # per-step device time there.
-        if self.config.dispatch_depth > 0:
-            step_s = (st.get("inter_completion_median_s")
-                      or st.get("step_time_s"))
-        else:
-            step_s = st.get("span_median_s") or st.get("step_time_s")
-        st["step_time_s"] = step_s
-        if not analyze or not step_s:
-            return out
-        # the decode executable is the one whose token-ids spec is the
-        # [S, 1] grid (prefill buckets run [1, W>=16] chunks)
-        want = f"i32[{self.config.max_num_seqs},1]"
-        entry = None
-        for e in get_program_inventory().entries(
-                name_contains=self._step_fn.tracker_name):
-            if want in e.signature:
-                entry = e
-        if entry is None:
-            return out
-        an = get_program_inventory().analyze(entry)
-        if "flops" not in an:
-            out["decode_program"] = {"name": entry.name,
-                                     "error": an.get("error")}
-            return out
-        out["decode_program"] = dict(
-            name=entry.name, signature=list(entry.signature),
-            **{k: an[k] for k in ("flops", "bytes_accessed",
-                                  "peak_temp_bytes", "argument_bytes",
-                                  "output_bytes", "alias_bytes")
-               if k in an})
-        out["decode_device_step_seconds"] = step_s
-        self.metrics.registry.gauge(
-            "decode_device_step_seconds",
-            "sampled decode device step time", unit="seconds").set(step_s)
-        roof = roofline_utilization(an["flops"], an["bytes_accessed"],
-                                    step_s)
-        if roof is None:
-            # CPU: no peaks, so no utilisation keys and no gauge
-            return out
-        out["decode_bandwidth_util"] = roof["bandwidth_util"]
-        out["decode_bandwidth_util_raw"] = roof["bandwidth_util_raw"]
-        out["decode_mfu"] = roof["mfu"]
-        out["chip"] = roof["chip"]
-        self.metrics.registry.gauge(
-            "decode_bandwidth_util",
-            "decode-program bytes/s over chip peak memory bandwidth"
-        ).set(roof["bandwidth_util"])
-        return out
 
     # ---- in-step profiling (named-region attribution) ------------------
 

@@ -45,7 +45,7 @@ class ChunkPrefillStep:
     wrapping the model call in ``region("prefill_chunk")`` here keeps the
     step-profile attribution deterministic (the bucket programs keep
     their plain forward regions) and makes chunk device-time first-class
-    in ``BENCH_serving_stepprofile.json``."""
+    in a step-profile capture."""
 
     def __init__(self, step, donate: bool = True):
         self._step = step

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives — decided in one place.
 
 ``compile_cache_dir()`` is the one helper every entry point uses
-(``chip_smoke.py``, ``bench.py``'s children, ``tests/conftest.py``):
+(``chip_smoke.py``, ``perfbench/run.py``, ``tests/conftest.py``):
 
 - where ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is the cache;
   this code does not create, stamp or wipe it, and sets no other;

@@ -23,9 +23,8 @@ if "xla_force_host_platform_device_count" not in flags:
 # executables replayed earlier in the process). Measured on
 # the tier-1 box: ~3 corrupt runs in 22 with the cache vs 0 in 8 without,
 # while a cold-cache full suite costs only ~3% more wall than a warm one —
-# determinism of the primary gate wins. Benches keep the cache (bench.py
-# wires it independently). Loaded by file path: importing paddle_tpu here
-# would initialize jax before the env pinning above.
+# determinism of the primary gate wins. Loaded by file path: importing
+# paddle_tpu here would initialize jax before the env pinning above.
 if os.environ.get("PADDLE_TPU_TEST_CACHE") == "1":
     import importlib.util as _ilu
 

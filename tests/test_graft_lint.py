@@ -796,25 +796,6 @@ def test_annotations_are_runtime_inert():
         lock_order("A._la", ">", "B._lb")   # only "<" is a valid op
 
 
-def test_bench_json_canonicalization(tmp_path):
-    """Satellite: bench artifacts write with sorted keys + stable floats,
-    so a no-change re-run is a no-diff."""
-    from tools.bench_io import canonical, write_bench_json
-
-    art_a = {"b": 0.1 + 0.2, "a": [3.0, {"z": 1, "y": 2.0000000001}],
-             "n": None, "t": True}
-    art_b = {"t": True, "n": None,
-             "a": [3, {"y": 2.0, "z": 1}], "b": 0.3}
-    p1, p2 = tmp_path / "a.json", tmp_path / "b.json"
-    write_bench_json(str(p1), art_a)
-    write_bench_json(str(p2), art_b)
-    assert p1.read_text() == p2.read_text()      # byte-identical
-    assert canonical(float("nan")) == "nan"
-    assert canonical(0.123456789) == 0.123457
-    assert canonical(66.0) == 66
-    assert json.loads(p1.read_text())["b"] == 0.3
-
-
 # ------------------------------------- concurrency checkers (PR: lint-conc)
 
 def test_lock_order_cycle_bad_and_clean(tmp_path):

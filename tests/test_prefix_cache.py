@@ -6,12 +6,8 @@ same per-request eager `generate()` oracle the r6 preemption tests pinned.
 Plus: the refcount protocol (shared blocks never freed under a sharer),
 copy-on-write on full-prompt hits, LRU leaf eviction under pool pressure,
 zero steady-state recompiles with the cache enabled, the inference-Config
-bridge, weight-hot-swap flush, and the serve_bench prefix-share artifact.
+bridge, weight-hot-swap flush, and the counts at a given prefix share.
 """
-
-import importlib.util
-import json
-import os
 
 import numpy as np
 import pytest
@@ -28,8 +24,6 @@ from paddle_tpu.serving.prefix_cache import (
     RadixTree,
     RefCountingBlockAllocator,
 )
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -341,31 +335,40 @@ def test_pallas_package_exports_and_manifest():
         assert spec["module"].startswith("paddle_tpu.ops.pallas."), k
 
 
-# -------------------------------------------- serve_bench prefix mode
+# ------------------------------------------ shared-system-prompt shares
 
-def test_serve_bench_prefix_share_writes_artifact(tmp_path):
-    """Offline shared-system-prompt sweep; refreshes the repo-root
-    BENCH_serving_prefix.json artifact (TTFT + hit rate at share
-    0/0.5/0.9, cache on vs off)."""
-    spec = importlib.util.spec_from_file_location(
-        "serve_bench", os.path.join(REPO, "tools", "serve_bench.py"))
-    sb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sb)
+@pytest.mark.parametrize("share", [0.0, 0.9])
+def test_prefix_share_counts_and_token_identity(model, share):
+    """Every prompt is ``shared_prefix + unique_tail``, the prefix ``share``
+    of its 96 tokens. The cache's counts follow from the share alone: no
+    hit without a shared prefix; with one, every request after the first
+    skips the prefix's whole blocks, and those are exactly the prompt
+    tokens the cache-off run prefilled and this one did not. Tokens are
+    identical either way."""
+    n, plen, bs = 6, 96, 16
+    rng = np.random.default_rng(0)
+    shared = rng.integers(0, 1000, int(round(share * plen)))
+    prompts = [np.concatenate([shared,
+                               rng.integers(0, 1000, plen - len(shared))])
+               for _ in range(n)]
 
-    out = tmp_path / "BENCH_serving_prefix.json"
-    artifact = sb.main(["--prefix-share", "--smoke", "--out", str(out)])
-    on_disk = json.loads(out.read_text())
-    assert on_disk["bench"] == "serving_prefix_cache"
-    assert set(on_disk["share"]) == {"0.0", "0.5", "0.9"}
-    assert on_disk["share"]["0.9"]["prefix_cache"]["hit_rate"] > 0
-    assert on_disk["share"]["0.0"]["prefix_cache"]["hit_rate"] == 0
-    assert on_disk["baseline_no_cache"]["0.9"]["prefix_cache"] is None
-    assert on_disk["prefill_tokens_saved_at_top_share"] > 0
-    assert "ttft_reduction_pct_at_top_share" in on_disk
-    # the on-disk form is the canonicalized artifact (sorted keys, stable
-    # floats — no-change re-runs must be no-diff)
-    from tools.bench_io import canonical, write_bench_json
+    def run(enable):
+        sched = _mk(model, enable, block_size=bs, max_seq_len=128)
+        # the first request alone, so the tree holds the prefix when the
+        # others arrive (a server that has been up for a while)
+        outs = sched.generate(prompts[:1], max_new_tokens=4)
+        outs += sched.generate(prompts[1:], max_new_tokens=4)
+        return sched, outs
 
-    assert on_disk == canonical(artifact)
-    root_art = os.path.join(REPO, "BENCH_serving_prefix.json")
-    write_bench_json(root_art, on_disk)
+    off_sched, off = run(False)
+    on_sched, on = run(True)
+    for a, b in zip(off, on):
+        np.testing.assert_array_equal(a, b)
+    assert off_sched.prefix_cache_stats() is None
+    st = on_sched.prefix_cache_stats()
+    hit = (n - 1) * (len(shared) // bs) * bs
+    assert st["hit_tokens"] == hit
+    assert st["miss_tokens"] == n * plen - hit
+    assert st["cached_blocks"] > 0        # retired prompts stay in the tree
+    assert (off_sched.metrics.prefill_tokens
+            - on_sched.metrics.prefill_tokens) == hit

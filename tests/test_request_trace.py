@@ -3,9 +3,9 @@ serving host-stall attribution, flight recorder + alarms, SLO/goodput
 accounting, and the live /metrics + /debug/requests endpoint.
 
 Correctness bar: phase durations partition E2E latency EXACTLY (gapless
-same-timestamp transitions), the token stream is bit-identical with
+same-timestamp transitions), and the token stream is bit-identical with
 observability on vs off (tracing observes the host timeline, never the
-model), and full instrumentation stays under the 5% overhead budget.
+model).
 """
 
 import json
@@ -447,23 +447,54 @@ def test_export_request_trace_chrome_artifact(model, tmp_path):
     assert rep["request_traces"][0][0]["phase_totals_s"]
 
 
-# ------------------------------------------------------ overhead budget
+# --------------------------------------------- everything on vs off
 
-def test_full_observability_overhead_and_token_identity():
-    """The tier-1 face of the <5% budget: deterministic unit-cost
-    attribution of every observability primitive against the smoke run's
-    wall, plus the hard guarantee — token streams identical on vs off."""
-    import importlib.util
-    import os
+def test_full_observability_on_off_token_identity(model):
+    """Everything that watches a request switched on (lifecycle tracing,
+    SLO accounting, step telemetry, the device ledger, a live endpoint
+    scraped between steps) against everything switched off: the same
+    token streams, and the watchers did see the run."""
+    from paddle_tpu.serving import ContinuousBatchingScheduler, \
+        SchedulerConfig
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "serve_bench", os.path.join(repo, "tools", "serve_bench.py"))
-    sb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sb)
-    res = sb.measure_tracing_overhead(repeats=1)
-    assert res["token_identical"], res["outputs_sha1"]
-    assert res["attributed_overhead_pct"] < 5.0, res
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 1000, int(k)) for k in rng.integers(4, 11, 6)]
+    budgets = [int(k) for k in rng.integers(8, 15, 6)]
+
+    def run(on):
+        sched = ContinuousBatchingScheduler(model, SchedulerConfig(
+            max_num_seqs=2, max_seq_len=64, block_size=8,
+            enable_request_tracing=on, enable_step_telemetry=on,
+            enable_device_observability=on,
+            ttft_slo_s=30.0 if on else None,
+            tpot_slo_s=30.0 if on else None))
+        rids = [sched.add_request(p, max_new_tokens=b)
+                for p, b in zip(prompts, budgets)]
+        ep = sched.start_endpoint() if on else None
+        scrapes = []
+        try:
+            it = 0
+            while sched.has_unfinished():
+                sched.step()
+                it += 1
+                if ep is not None and it % 4 == 0:
+                    scrapes.append(urllib.request.urlopen(
+                        ep.url + "/metrics", timeout=5).read().decode())
+                assert it < 1000
+        finally:
+            if ep is not None:
+                ep.stop()
+        return sched, [list(sched._finished[r].token_ids) for r in rids], \
+            scrapes
+
+    off_sched, off, _ = run(False)
+    on_sched, on, scrapes = run(True)
+    assert on == off
+    assert len(on_sched.tracer.completed()) == 6
+    assert not off_sched.tracer.completed()
+    assert on_sched.metrics.slo_snapshot()["judged_tokens"] == sum(budgets)
+    assert on_sched.telemetry_snapshot()["steps"] > 0
+    assert scrapes and "serving_generated_tokens" in scrapes[-1]
 
 
 # ---------------------------------------------- live export + failover resume

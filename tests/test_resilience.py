@@ -8,11 +8,10 @@ donates the cache, and ``allocator.extend`` is idempotent per position,
 so a retried step rewrites identical KV). Plus: the degradation ladder's
 ordered shed + hysteresis, the step-latency watchdog's StallStorm, the
 truthful ``/healthz`` (ok -> degraded -> ok, and a dead driver thread
-answering 503 instead of hanging), request validation, and the
-serve_bench partial-artifact-on-death contract.
+answering 503 instead of hanging), request validation, and a seeded
+open load under faults and cancellations.
 """
 
-import json
 import threading
 import time
 import urllib.error
@@ -34,7 +33,9 @@ from paddle_tpu.resilience import (
     LEVEL_SHRINK,
     StallStorm,
     StepWatchdog,
+    arm,
     classify_error,
+    disarm,
     fault_plan,
     get_injector,
     inject,
@@ -568,32 +569,125 @@ def test_add_request_validation(model):
     assert sched.metrics.snapshot()["requests_received"] == 0
 
 
-# ----------------------------------------------------- serve_bench chaos
+# ------------------------------------------------- an open load under chaos
 
-def test_chaos_load_census_and_zero_leak():
-    from tools.serve_bench import run_chaos_load
+def _chaos_load(sched, n, *, seed=0, rate=1.0, new_tokens=(3, 5),
+                cancel_rate=0.0, plan=None, window=(0, 10 ** 9)):
+    """``n`` seeded requests arriving over scheduler iterations, ``plan``
+    armed for the iterations in ``window``, a seeded share of the requests
+    cancelled a few iterations after they arrive. Returns the outputs by
+    request id in arrival order, the number refused at admission and the
+    tokens streamed in each iteration."""
+    rng = np.random.default_rng(seed)
+    arrive_at = np.cumsum(rng.exponential(1.0 / rate, n))
+    prompts = [rng.integers(0, 1000, int(k)) for k in rng.integers(4, 11, n)]
+    budgets = rng.integers(new_tokens[0], new_tokens[1] + 1, n)
+    crng = np.random.default_rng(seed + 1)
+    will_cancel = crng.random(n) < cancel_rate
+    cancel_delay = crng.integers(1, 5, n)
+    streamed = [0]
 
-    art = run_chaos_load(num_requests=5, rate=1.0, seed=0,
-                         fault_rate=0.3, cancel_rate=0.3,
-                         new_tokens=(3, 5), max_step_faults=2)
-    terminal = set(art["census"]) | {"rejected"}
-    assert terminal <= {"length", "eos", "cancelled", "failed", "rejected"}
-    assert sum(art["census"].values()) + art["rejected"] == 5
-    assert not get_injector().armed          # the bench disarms on exit
+    def on_token(rid, tok):
+        streamed[0] += 1
+
+    rids, cancels, per_it = [], [], []
+    offered = rejected = it = 0
+    try:
+        while offered < n or sched.has_unfinished():
+            if plan is not None:
+                if it == window[0]:
+                    arm(plan)
+                elif it == window[1]:
+                    disarm()
+            while offered < n and arrive_at[offered] <= it:
+                try:
+                    rid = sched.add_request(
+                        prompts[offered],
+                        max_new_tokens=int(budgets[offered]),
+                        on_token=on_token)
+                    rids.append(rid)
+                    if will_cancel[offered]:
+                        cancels.append((it + int(cancel_delay[offered]), rid))
+                except SchedulerOverloaded:
+                    rejected += 1
+                offered += 1
+            for due, rid in list(cancels):
+                if due <= it:
+                    sched.cancel(rid)        # idempotent once finished
+                    cancels.remove((due, rid))
+            streamed[0] = 0
+            sched.step()
+            per_it.append(streamed[0])
+            it += 1
+            assert it < 3000, "chaos load did not drain"
+    finally:
+        disarm()
+    return {r: sched._finished[r] for r in rids}, rejected, per_it
 
 
-def test_serve_bench_writes_partial_artifact_on_death(tmp_path,
-                                                      monkeypatch):
-    import tools.serve_bench as sb
+def _busy_median(per_it):
+    busy = sorted(t for t in per_it if t > 0)
+    return busy[len(busy) // 2]
 
-    def boom(**kw):
-        raise RuntimeError("mid-bench death")
 
-    monkeypatch.setattr(sb, "run_load", boom)
-    out = tmp_path / "BENCH_dead.json"
-    with pytest.raises(RuntimeError, match="mid-bench death"):
-        sb.main(["--smoke", "--out", str(out)])
-    art = json.loads(out.read_text())
-    assert art["completed"] is False
-    assert "RuntimeError: mid-bench death" in art["error"]
-    assert art["bench"] == "serving_smoke" and art["config"]["smoke"]
+def test_chaos_load_census_and_zero_leak(model):
+    """Faults at every serving site and cancellations over one seeded
+    load: every request offered ends in a terminal state, the survivors
+    hold their exact count, the pool is whole and the injector disarmed."""
+    plan = FaultPlan(seed=0)
+    for site in ("serving.decode_step", "serving.prefill",
+                 "serving.block_alloc"):
+        plan.on(site, prob=0.3)
+    sched = _sched(model, max_step_faults=2)
+    outs, rejected, _ = _chaos_load(sched, 5, cancel_rate=0.3, plan=plan)
+    census = {}
+    for o in outs.values():
+        census[o.finish_reason] = census.get(o.finish_reason, 0) + 1
+    assert set(census) <= {"length", "eos", "cancelled", "failed"}
+    assert sum(census.values()) + rejected == 5
+    assert sum(sched.metrics.faults_snapshot().values()) >= 1
+    _assert_pool_clean(sched)
+    assert not get_injector().armed
+
+
+def test_fault_window_tokens_identical_and_step_yield_recovers(model):
+    """Decode-step faults over iterations 4..11 only: retries absorb every
+    one (token streams identical to the fault-free load), and once the
+    window closes an iteration yields as many tokens as before it."""
+    kw = dict(seed=0, rate=0.8, new_tokens=(6, 10))
+    base, _, base_per_it = _chaos_load(_sched(model), 12, **kw)
+
+    sched = _sched(model, max_step_faults=6)
+    plan = FaultPlan(seed=0).on("serving.decode_step", prob=0.3)
+    outs, rejected, per_it = _chaos_load(sched, 12, plan=plan,
+                                         window=(4, 12), **kw)
+    assert rejected == 0
+    assert any("serving.decode_step" in k
+               for k in sched.metrics.faults_snapshot())
+    for (_, a), (_, b) in zip(sorted(base.items()), sorted(outs.items())):
+        assert b.finish_reason == "length"
+        np.testing.assert_array_equal(a.token_ids, b.token_ids)
+    assert _busy_median(per_it[12:]) == _busy_median(base_per_it)
+    _assert_pool_clean(sched)
+
+
+def test_goodput_does_not_rise_with_the_fault_rate(model):
+    """One seeded load at fault rates 0 / 0.1 / 0.25 / 0.4 on the three
+    serving sites: all of it completes without faults, and a higher rate
+    never completes more requests than a lower one."""
+    done = []
+    for rate in (0.0, 0.1, 0.25, 0.4):
+        plan = None
+        if rate:
+            plan = FaultPlan(seed=0)
+            for site in ("serving.decode_step", "serving.prefill",
+                         "serving.block_alloc"):
+                plan.on(site, prob=rate)
+        sched = _sched(model, max_step_faults=3)
+        outs, rejected, _ = _chaos_load(sched, 12, rate=0.8,
+                                        new_tokens=(6, 10), plan=plan)
+        assert len(outs) + rejected == 12
+        done.append(sum(o.finish_reason == "length" for o in outs.values()))
+        _assert_pool_clean(sched)
+    assert done[0] == 12
+    assert done == sorted(done, reverse=True), done

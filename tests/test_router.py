@@ -8,11 +8,10 @@ async dispatch. Pinned here across a replica kill mid-decode, plus: zero
 block leaks after supervisor reap, the circuit-breaker open→half_open→
 closed lifecycle, deadlines measured from FIRST admission across
 failover, affinity-vs-health routing precedence, zero-downtime rolling
-weight reload, the three router fault sites, and serve_bench's
-quiesce-every-replica partial artifact.
+weight reload, the three router fault sites, and ``shutdown()`` with
+requests live on every replica.
 """
 
-import json
 import time
 
 import numpy as np
@@ -439,43 +438,51 @@ def test_export_import_resumes_token_identical(model):
     src.shutdown()
 
 
-# --------------------------------------- serve_bench router death drain
+# ------------------------------------ shutdown with work on every replica
 
-def test_serve_bench_router_mode_quiesces_replicas_on_death(
-        tmp_path, monkeypatch):
-    """Router-mode bench dying mid-run must quiesce EVERY replica behind
-    every live router before the ``completed: false`` artifact lands."""
-    import tools.serve_bench as sb
+@pytest.mark.parametrize("depth", [0, 2])
+def test_shutdown_with_live_requests_frees_every_replica_pool(model, depth):
+    """``shutdown()`` while every replica still holds queued and running
+    requests (and, at depth 2, dispatched steps): all of them are
+    cancelled, every replica's pool is whole, no engine has work left."""
+    router = _router(model, n=2, sched={"dispatch_depth": depth})
+    for p in _prompts(6, seed=5):
+        router.submit(p, max_new_tokens=30)
+    for _ in range(2):
+        router.step()
+    assert all(rep.sched.has_unfinished() for rep in router.replicas)
+    counts = router.shutdown()
+    assert counts["cancelled"] == 6
+    assert not any(rep.sched.has_unfinished() for rep in router.replicas)
+    _pools_clean(router)
 
-    paddle.seed(7)
-    model = GPTForCausalLM(gpt_tiny(num_layers=1))
 
-    def boom(**kw):
-        router = sb._track_router(ServingRouter(
-            _factory(model), num_replicas=2, cooldown_s=0.05))
-        rng = np.random.default_rng(0)
-        for _ in range(3):
-            router.submit(rng.integers(0, 1000, 6), max_new_tokens=30)
-        for _ in range(2):
+def test_affinity_hit_rate_at_least_round_robin_on_prefix_groups(model):
+    """Three prompt families, each request drawn into one at random (a
+    cyclic draw would line up with round-robin placement): placement by
+    affinity serves at least as many prompt tokens from the replicas'
+    radix trees as round-robin does over the same requests."""
+    rng = np.random.default_rng(0)
+    shared = [rng.integers(0, 1000, 16) for _ in range(3)]
+    prompts = [np.concatenate([shared[int(g)], rng.integers(0, 1000, 5)])
+               for g in rng.integers(0, 3, 12)]
+
+    def hit_tokens(policy):
+        router = _router(model, n=3, policy=policy,
+                         sched=dict(enable_prefix_caching=True))
+        for p in prompts:
+            router.submit(p, max_new_tokens=3)
             router.step()
-        assert router.has_unfinished()
-        raise RuntimeError("mid-bench death with replicas live")
+        router.run()
+        hits = sum(rep.sched.prefix_cache.stats()["hit_tokens"]
+                   for rep in router.replicas)
+        router.shutdown()
+        _pools_clean(router)
+        return hits
 
-    sb._LIVE_SCHEDS.clear()
-    sb._LIVE_ROUTERS.clear()
-    monkeypatch.setattr(sb, "run_router_suite", boom)
-    out = tmp_path / "BENCH_dead_router.json"
-    with pytest.raises(RuntimeError, match="mid-bench death"):
-        sb.main(["--smoke", "--replicas", "2", "--out", str(out)])
-    art = json.loads(out.read_text())
-    assert art["completed"] is False
-    entries = art["quiesced_routers"]
-    assert len(entries) == 1
-    q = entries[0]
-    assert q["error"] is None
-    assert q["replicas"] == 2
-    assert q["cancelled"] >= 1
-    assert q["blocks_leaked"] == 0
+    affinity = hit_tokens("affinity")
+    assert affinity > 0
+    assert affinity >= hit_tokens("round_robin")
 
 
 # ------------------------------------------- fleet journey kill drill
@@ -529,6 +536,10 @@ def test_kill_drill_single_journey_track_token_identical(model, depth):
 
     trace = router.export_fleet_trace()
     ev = trace["traceEvents"]
+    # one track for every request, hopped or not
+    tracks = [e["tid"] for e in ev
+              if e.get("ph") == "M" and e.get("name") == "thread_name"]
+    assert sorted(tracks) == sorted(rids)
     for j in hopped:
         tid = j.router_rid
         # exactly ONE track for the failed-over request
@@ -566,6 +577,15 @@ def test_kill_drill_single_journey_track_token_identical(model, depth):
               if b["kind"] == "breaker_open"][-1]
     assert "journeys" in bundle and "timeline_window" in bundle
     assert "router" in bundle
+    # an alarm raised by a replica's own flight recorder lands in the
+    # fleet's store through the wired callback: on a survivor, and on the
+    # incarnation that replaced the dead one (re-bound after the restart)
+    for rep, kind in ((router.replicas[-1], "ttft_breach_storm"),
+                      (router.replicas[0], "eviction_thrash")):
+        before = router.postmortems.captures
+        rep.sched.flight.alarm(kind, "raised by the test")
+        assert router.postmortems.captures == before + 1
+        assert router.postmortems.last()["kind"] == kind
 
     router.shutdown()
     assert not router.timeline.snapshot()["sampler_alive"]

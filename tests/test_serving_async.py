@@ -8,11 +8,10 @@ here under plain load, forced preemption, prefix-cache eviction
 pressure, mid-flight cancel/deadline, and injected transient faults.
 Plus: the one-compiled-decode-program / zero-steady-state-recompile
 invariant at depth > 0, the engine block in ``debug_state()`` and the
-flight ring, shutdown's drain-everything contract, and serve_bench's
-quiesce-on-death partial artifact.
+flight ring, and shutdown's drain-everything contract.
 """
 
-import json
+import threading
 
 import numpy as np
 import pytest
@@ -132,6 +131,33 @@ def test_depths_identical_under_prefix_cache_eviction(model):
         _pool_clean(sched)
 
 
+@pytest.mark.parametrize("depth", [1, 2])
+def test_streamed_tokens_in_order_under_churn(model, depth):
+    """More requests than slots and short outputs, so rows retire and
+    admit while steps are in flight and the drain thread does the
+    streaming: each request's callbacks carry its tokens once, in order,
+    and they are the synchronous engine's tokens."""
+    rng = np.random.default_rng(10)
+    prompts = [rng.integers(0, 1000, int(n))
+               for n in rng.integers(4, 14, 10)]
+
+    def run(d):
+        streamed = {}
+        sched = _sched(model, d, max_num_seqs=3)
+        rids = [sched.add_request(
+                    p, max_new_tokens=4 + i % 3,
+                    on_token=lambda r, t: streamed.setdefault(r, []).append(t))
+                for i, p in enumerate(prompts)]
+        outs = _drain(sched)
+        sched.shutdown()
+        _pool_clean(sched)
+        for r in rids:
+            assert streamed[r] == list(outs[r].generated_ids)
+        return [streamed[r] for r in rids]
+
+    assert run(depth) == run(0)
+
+
 # ------------------------------------------------- mid-flight lifecycle
 
 def test_cancel_mid_flight_exact_parity(model):
@@ -245,57 +271,42 @@ def test_debug_state_and_flight_expose_engine(model):
     assert all("dispatch_depth" not in r for r in sync.flight.dump())
 
 
-def test_shutdown_drains_in_flight_and_frees(model):
+def test_shutdown_drains_in_flight_and_frees(model, monkeypatch):
+    """``shutdown()`` with a step dispatched and not yet drained. The test
+    holds the drain thread at its fetch until ``shutdown()`` has counted
+    the pipeline, so the step is in flight whatever the host is doing."""
     rng = np.random.default_rng(9)
     sched = _sched(model, 2)
     for _ in range(3):
         sched.add_request(rng.integers(0, 1000, 8), max_new_tokens=20)
-    for _ in range(4):
+    for _ in range(3):
         sched.step()
+    with sched._elock:                   # let the pipeline run dry first:
+        sched._drain_all()               # the next step must not back up
+
+    gate = threading.Event()
+    fetch, drain_all = sched._fetch_tokens, sched._drain_all
+
+    def held_fetch(next_ids, phase="sampling_sync", stats=None):
+        if phase == "drain":
+            assert gate.wait(60), "shutdown() never reached its barrier"
+        return fetch(next_ids, phase=phase, stats=stats)
+
+    def counted_then_drain():
+        gate.set()                       # shutdown() has read the count
+        return drain_all()
+
+    monkeypatch.setattr(sched, "_fetch_tokens", held_fetch)
+    monkeypatch.setattr(sched, "_drain_all", counted_then_drain)
+    sched.step()                         # one decode step, held undrained
+    with sched._elock:
+        in_flight = len(sched._inflight)
+    assert in_flight == 1
     counts = sched.shutdown()
-    assert counts["drained_in_flight"] >= 1, "pipeline should be in flight"
-    assert counts["cancelled"] >= 1
+    assert counts["drained_in_flight"] == in_flight
+    assert counts["cancelled"] == 3
     assert not sched.has_unfinished()
     _pool_clean(sched)
     # idempotent: nothing left to drain or cancel
     again = sched.shutdown()
     assert again == {"drained_in_flight": 0, "cancelled": 0}
-
-
-# --------------------------------------------- serve_bench death drain
-
-def test_serve_bench_quiesces_live_engines_on_death(tmp_path, monkeypatch):
-    """A bench dying with dispatched-but-unobserved steps in flight must
-    drain and release them BEFORE the partial artifact is written, and
-    the artifact must record that nothing leaked."""
-    import tools.serve_bench as sb
-
-    paddle.seed(7)
-    model = GPTForCausalLM(gpt_tiny(num_layers=1))
-
-    def boom(**kw):
-        sched = sb._track(ContinuousBatchingScheduler(
-            model, SchedulerConfig(max_num_seqs=2, max_seq_len=64,
-                                   block_size=8, dispatch_depth=2)))
-        rng = np.random.default_rng(0)
-        for _ in range(2):
-            sched.add_request(rng.integers(0, 1000, 6), max_new_tokens=30)
-        for _ in range(4):
-            sched.step()
-        assert len(sched._inflight) >= 1
-        raise RuntimeError("mid-bench death with steps in flight")
-
-    sb._LIVE_SCHEDS.clear()
-    monkeypatch.setattr(sb, "run_load", boom)
-    out = tmp_path / "BENCH_dead.json"
-    with pytest.raises(RuntimeError, match="mid-bench death"):
-        sb.main(["--smoke", "--out", str(out)])
-    art = json.loads(out.read_text())
-    assert art["completed"] is False
-    entries = art["quiesced_schedulers"]
-    assert len(entries) == 1
-    q = entries[0]
-    assert q["error"] is None
-    assert q["drained_in_flight"] >= 1
-    assert q["cancelled"] == 2
-    assert q["blocks_leaked"] == 0

@@ -6,12 +6,8 @@ iteration-level batching over the paged slot grid must be token-identical
 under greedy decoding, including under forced preemption (tiny block pool)
 and EOS early-exit. Plus: zero steady-state recompiles across admissions,
 allocator hardening, admission control, metrics/streaming/profiler spans,
-the inference-Config bridge, and the offline serve_bench smoke artifact.
+the inference-Config bridge, and a seeded open load's counts.
 """
-
-import importlib.util
-import json
-import os
 
 import numpy as np
 import pytest
@@ -26,8 +22,6 @@ from paddle_tpu.serving import (
     RequestQueue,
     SchedulerConfig,
 )
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -293,39 +287,63 @@ def test_eager_generate_streams_tokens(model):
     np.testing.assert_array_equal(np.stack(steps, 1), out_np[:, 5:])
 
 
-# ------------------------------------------------------- serve_bench smoke
+# ------------------------------------------------------- seeded open load
 
-def test_serve_bench_smoke_writes_artifact(tmp_path):
-    """Fast offline load check; writes BENCH_serving_smoke.json so the perf
-    axis has a serving trajectory artifact every round."""
-    spec = importlib.util.spec_from_file_location(
-        "serve_bench", os.path.join(REPO, "tools", "serve_bench.py"))
-    sb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sb)
-
-    out = tmp_path / "BENCH_serving_smoke.json"
-    artifact = sb.main(["--smoke", "--out", str(out)])
-    on_disk = json.loads(out.read_text())
-    assert on_disk["bench"] == "serving_continuous_batching"
-    # Prometheus text export lands alongside the JSON and parses back
+def test_seeded_load_counts_streams_and_prometheus_round_trip(model):
+    """A seeded load arriving over scheduler iterations (Poisson gaps,
+    mixed prompt and output lengths): every request finishes with its
+    exact count, every token is streamed once, the counters agree with
+    the outputs and survive the Prometheus text round trip, and a second
+    load after ``mark_steady()`` compiles nothing."""
     from paddle_tpu.observability import parse_prometheus_text
 
-    prom = parse_prometheus_text(
-        (tmp_path / "BENCH_serving_smoke.prom").read_text())
-    assert (prom["serving_generated_tokens"]["value"]
-            == on_disk["metrics"]["generated_tokens"])
-    assert (prom["serving_ttft_seconds"]["count"]
-            == on_disk["metrics"]["ttft_s"]["count"])
-    m = artifact["metrics"]
-    assert m["requests_finished"] == artifact["config"]["num_requests"]
-    assert m["tokens_per_s"] > 0
-    assert m["ttft_s"]["count"] == m["requests_finished"]
+    sched = ContinuousBatchingScheduler(
+        model, SchedulerConfig(max_num_seqs=2, max_seq_len=64, block_size=8))
+
+    def drive(seed, n=6, rate=1.0):
+        rng = np.random.default_rng(seed)
+        arrive_at = np.cumsum(rng.exponential(1.0 / rate, n))
+        prompts = [rng.integers(0, 1000, int(k))
+                   for k in rng.integers(4, 11, n)]
+        budgets = [int(k) for k in rng.integers(3, 7, n)]
+        streamed, rids = {}, []
+
+        def on_token(rid, tok):
+            streamed[rid] = streamed.get(rid, 0) + 1
+
+        it = 0
+        while len(rids) < n or sched.has_unfinished():
+            while len(rids) < n and arrive_at[len(rids)] <= it:
+                rids.append(sched.add_request(
+                    prompts[len(rids)], max_new_tokens=budgets[len(rids)],
+                    on_token=on_token))
+            sched.step()
+            it += 1
+            assert it < 1000, "load did not drain"
+        outs = [sched._finished[r] for r in rids]
+        for out, budget in zip(outs, budgets):
+            assert out.finish_reason == "length"
+            assert len(out.generated_ids) == budget
+            assert streamed[out.request_id] == budget
+        return sum(budgets)
+
+    generated = drive(seed=0)
+    m = sched.metrics.snapshot()
+    assert m["requests_finished"] == 6
+    assert m["generated_tokens"] == generated
+    assert m["ttft_s"]["count"] == 6
     assert 0.0 <= m["kv_utilization"] <= 1.0
-    assert artifact["compiled_programs"] <= 3
-    # the round artifact the driver tracks (repo root, default path)
-    root_art = os.path.join(REPO, "BENCH_serving_smoke.json")
-    with open(root_art, "w") as f:
-        json.dump(on_disk, f, indent=2)
+    assert m["free_blocks"] == m["total_blocks"]
+    assert sched.num_programs() <= 3     # prefill buckets 8/16 + decode
+    prom = parse_prometheus_text(sched.metrics.prometheus_text())
+    assert prom["serving_generated_tokens"]["value"] == generated
+    assert prom["serving_ttft_seconds"]["count"] == 6
+
+    programs = sched.num_programs()
+    sched.mark_steady()
+    drive(seed=0)                        # the same buckets again
+    assert sched.compile_stats()["steady_state_recompiles"] == 0
+    assert sched.num_programs() == programs
 
 
 # ------------------------------------------- fault-backoff lock regression

@@ -211,9 +211,7 @@ def test_per_device_ledger_census_matches_ground_truth():
 
 def test_device_observability_carries_per_chip_memory():
     sched = _sched(tp=2)
-    obs = sched.device_observability(analyze=False)
-    assert obs["enabled"]
-    per_dev = obs["memory"]["per_device"]
+    per_dev = sched.device_ledger.census_report()["per_device"]
     assert len(per_dev) == 2
     assert all(v > 0 for v in per_dev.values())
     sched.shutdown()

@@ -305,6 +305,95 @@ def test_router_kill_drill_with_chunk_frontier():
     router.shutdown()
 
 
+# ---------------------------------------- what the two features are for
+
+@pytest.mark.parametrize("k", [2, 4])
+def test_speculation_finishes_in_fewer_device_steps(k):
+    """Prompts that repeat a pattern three times, where the n-gram
+    proposer has something to propose: drafts are accepted, a verify step
+    yields more than one token, and the same tokens come out of fewer
+    device steps than one-token decode needs."""
+    rng = np.random.default_rng(0)
+    prompts = [np.tile(rng.integers(2, 40, 6), 3) for _ in range(3)]
+
+    def run(spec_k):
+        sched = _sched(k=spec_k)
+        outs = sched.generate(prompts, max_new_tokens=24)
+        steps = sched.metrics.snapshot()["decode_steps"]
+        stats = sched.spec_stats()
+        sched.shutdown()
+        _pool_clean(sched)
+        return outs, steps, stats
+
+    ref, base_steps, _ = run(0)
+    outs, steps, st = run(k)
+    for o, r in zip(outs, ref):
+        np.testing.assert_array_equal(o, r)
+    assert st["accepted_tokens"] > 0
+    assert st["tokens_per_verify_step"] > 1.0
+    assert steps < base_steps
+
+
+@pytest.fixture(scope="module")
+def storm_reference():
+    return _prefill_storm()
+
+
+def _prefill_storm(chunk=0, k=0):
+    """Two rows decode 32 tokens each; once they are under way two
+    96-token prompts arrive for the one free slot. Returns every
+    request's tokens in arrival order, the flight-recorder rows of the
+    iterations in which both decoding rows were live after the long
+    prompts arrived, and the steady-state recompile count."""
+    rng = np.random.default_rng(0)
+    pat = rng.integers(2, 40, 8)
+    decoders = [np.concatenate([pat, pat]) for _ in range(2)]
+    storms = [rng.integers(0, 1000, 96) for _ in range(2)]
+    sched = _sched(chunk=chunk, k=k, max_num_seqs=3, max_seq_len=128)
+    # every program shape once before steady state, one request at a time:
+    # a random context alone takes the no-proposal [S, 1] decode program, a
+    # repetitive one the verify grid
+    sched.generate([rng.integers(0, 1000, 96)], max_new_tokens=4)
+    sched.generate([np.concatenate([pat, pat])], max_new_tokens=6)
+    sched.mark_steady()
+
+    rids = [sched.add_request(p, max_new_tokens=32) for p in decoders]
+    for _ in range(5):
+        sched.step()
+    rids += [sched.add_request(p, max_new_tokens=4) for p in storms]
+    rows = []
+    guard = 2000
+    while sched.has_unfinished():
+        both_live = all(r not in sched._finished for r in rids[:2])
+        sched.step()
+        if both_live and all(r not in sched._finished for r in rids[:2]):
+            rows.append(sched.flight.dump(last=1)[0])
+        guard -= 1
+        assert guard > 0
+    recompiles = sched.compile_stats()["steady_state_recompiles"]
+    tokens = [list(sched._finished[r].token_ids) for r in rids]
+    chunk_size = sched._chunk_size if chunk else None
+    sched.shutdown()
+    _pool_clean(sched)
+    return tokens, rows, recompiles, chunk_size
+
+
+@pytest.mark.parametrize("k", [0, 3], ids=["chunked", "chunked+spec"])
+def test_prefill_storm_is_taken_a_chunk_an_iteration(storm_reference, k):
+    """Whole-prompt prefill takes a 96-token prompt in one iteration; the
+    chunk pump never takes more than one chunk, every iteration still
+    yields a token for each decoding row, the tokens are the same and
+    nothing compiles in steady state."""
+    ref_tokens, ref_rows, _, _ = storm_reference
+    assert max(r["prefill_tokens"] for r in ref_rows) == 96
+    tokens, rows, recompiles, chunk_size = _prefill_storm(chunk=16, k=k)
+    assert tokens == ref_tokens
+    assert recompiles == 0
+    assert sum(r["chunked_tokens"] for r in rows) > 0
+    assert max(r["prefill_tokens"] for r in rows) <= chunk_size
+    assert min(r["generated_tokens"] for r in rows) >= 2
+
+
 # ------------------------------------------------- compiled-program pins
 
 def test_zero_steady_state_recompiles_both_features():

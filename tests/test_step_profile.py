@@ -290,6 +290,42 @@ def test_capture_live_attributes_decode_regions(profiled_sched):
     assert "bandwidth_util_by_region" not in roof
 
 
+def test_capture_attributes_chunk_and_verify_regions():
+    """Chunked prefill and speculation on, and two long prompts arriving
+    for the one free slot just before the captured steps: the chunk
+    program and the verify grid run inside the window, their regions
+    show in the attribution, and the capture compiles nothing."""
+    rng = np.random.default_rng(0)
+    paddle.seed(7)
+    sched = ContinuousBatchingScheduler(
+        GPTForCausalLM(gpt_tiny(num_layers=1)),
+        SchedulerConfig(max_num_seqs=2, max_seq_len=64, block_size=8,
+                        prefill_chunk_size=16, spec_k=3))
+    # every program shape once, one request at a time (a random context
+    # alone takes the no-proposal decode program)
+    sched.generate([rng.integers(0, 1000, 20)], max_new_tokens=4)
+    pat = rng.integers(2, 40, 5)
+    sched.add_request(np.concatenate([pat, pat]), max_new_tokens=24)
+    for _ in range(4):
+        sched.step()
+    for _ in range(2):
+        sched.add_request(rng.integers(0, 1000, 48), max_new_tokens=4)
+    n_before = sched.num_programs()
+    summary = sched.capture_step_profile(steps=6)
+    assert sched.num_programs() == n_before
+    while sched.has_unfinished():
+        sched.step()
+    sched.shutdown()
+    assert summary["enabled"], summary.get("error")
+    # the chunk program wraps a whole forward, so its inner operations
+    # attribute to the nested leaves under the prefill_chunk group
+    seen = {**summary["group_shares"], **{
+        k: v for k, v in summary["region_shares"].items() if v > 0}}
+    assert seen.get("prefill_chunk", 0.0) > 0.0, summary
+    assert seen.get("spec_verify", 0.0) > 0.0, summary
+    assert sched.spec_stats()["verify_steps"] > 0
+
+
 def test_capture_feeds_endpoint_and_postmortem(profiled_sched):
     sched, summary, _ = profiled_sched
     # postmortem bundles attach the LATEST capture (capture-on-alarm)
@@ -370,8 +406,8 @@ def test_telemetry_token_identity_sharded():
 
 def test_trainstep_hlo_carries_phase_regions():
     """The compiled TrainStep's op_name metadata carries the
-    forward/backward/optimizer group regions (train_bench attributes a
-    live trace against exactly this map)."""
+    forward/backward/optimizer group regions (``StepProfiler`` attributes
+    a live trace against exactly this map)."""
     import paddle_tpu.optimizer as opt
     from paddle_tpu.jit.api import TrainStep
     from paddle_tpu.models import (

@@ -379,62 +379,6 @@ def test_compile_cache_dir_respects_the_callers_directory(tmp_path):
         == cc.cache_key()
 
 
-def _load_bench_module():
-    import importlib.util
-
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench.py")
-    spec = importlib.util.spec_from_file_location("_bench_under_test", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench
-
-
-def test_bench_parent_is_jax_free_and_has_no_probe():
-    """One process for each chip: importing bench.py (what the parent
-    does) pulls in neither jax nor paddle_tpu, and the probe / CPU
-    fallback machinery is gone."""
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    code = ("import sys, importlib.util as u\n"
-            f"s = u.spec_from_file_location('b', {repo + '/bench.py'!r})\n"
-            "m = u.module_from_spec(s); s.loader.exec_module(m)\n"
-            "bad = [n for n in ('jax', 'paddle_tpu') if n in sys.modules]\n"
-            "assert not bad, bad\n"
-            "assert not [n for n in dir(m) if 'probe' in n.lower()]\n")
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=120)
-    assert r.returncode == 0, r.stderr
-
-
-def test_bench_exits_nonzero_when_a_bench_fails(monkeypatch, capsys):
-    """A failing bench child still lets the rest run (its error line is
-    printed), and the whole run then exits non-zero."""
-    bench = _load_bench_module()
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    monkeypatch.setattr(bench, "_BENCHES",
-                        {"no_such_bench": None, "bench_lint": None})
-    calls = []
-    real_run = __import__("subprocess").run
-
-    def fake_run(cmd, **kw):
-        calls.append(cmd[-1])
-        if cmd[-1] == "bench_lint":      # keep the test quick: a stub ok
-            cmd = [cmd[0], "-c", "print('{\"metric\": \"stub\"}')"]
-        return real_run(cmd, **kw)
-
-    monkeypatch.setattr(__import__("subprocess"), "run", fake_run)
-    with pytest.raises(SystemExit) as ei:
-        bench.main()
-    assert calls == ["no_such_bench", "bench_lint"]   # the rest still ran
-    assert ei.value.code not in (0, None)
-    assert "no_such_bench" in str(ei.value.code)
-    out = capsys.readouterr().out
-    assert '"metric": "stub"' in out and '"error"' in out
-
-
 # ------------------------------------------------------- loop integrations
 
 

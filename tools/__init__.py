@@ -1,2 +1,2 @@
-"""Repo tooling (benchmarks, lint). A package so ``tools.graft_lint`` and
-``tools.bench_io`` import cleanly once the repo root is on ``sys.path``."""
+"""Repo tooling (lint). A package so ``tools.graft_lint`` imports cleanly
+once the repo root is on ``sys.path``."""

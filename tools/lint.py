@@ -11,8 +11,8 @@
     python tools/lint.py --write-baseline # accept current findings
 
 Runs on stdlib only (ast + regex text scans — no jax, no import of the
-scanned modules), so the full-repo pass stays well under the 10 s tier-1
-budget (pinned by ``bench_lint`` in bench.py and tests/test_graft_lint.py).
+scanned modules), so the full-repo pass takes seconds
+(tests/test_graft_lint.py runs it in tier-1).
 
 Exit code 0 iff every finding is suppressed in-source
 (``# graft-lint: disable=<rule>``) or accepted in
