@@ -26,7 +26,7 @@ from paddle_tpu.jit.functional import (
     tree_unwrap,
     tree_wrap,
 )
-from paddle_tpu.nn.layer_base import Layer
+from paddle_tpu.nn.layer_base import Layer, structure_epoch
 from paddle_tpu.observability.annotations import hot_path
 from paddle_tpu.observability.compile_tracker import (
     abstract_signature,
@@ -98,6 +98,11 @@ class StaticFunction:
         self._donate_flags = {}   # arguments' treedef -> one bool a leaf
         self._seen_programs = 0   # ProgramInventory capture high-water mark
         self._traces = 0          # times jax traced self._traced (cache misses)
+        # the call plan: the layer's (params, buffers) lists, kept until
+        # the Layer registries' structure epoch moves (_state_tensors)
+        self._state = None
+        self._state_epoch = -1
+        self.state_walks = 0      # times the Layer tree was walked for them
         self._jitted = jax.jit(self._traced, static_argnames=("training",),
                                donate_argnums=donate)
         self._jitted_checked = None  # built lazily when nan/inf debug is on
@@ -149,10 +154,22 @@ class StaticFunction:
                                    for l, f in zip(leaves, flags)]))
 
     def _state_tensors(self):
+        """The layer's ``(params, buffers)`` in the order the traced
+        program takes their values. Walks the tree (``collect_state``) once
+        and again only after a registry of some Layer was written; the
+        tensors' values are read fresh from the handles at every call."""
         if self._layer is None:
             return [], []
-        p, b = collect_state(self._layer)
-        return list(p.values()), [t for t in b.values() if t is not None]
+        epoch = structure_epoch()
+        if epoch != self._state_epoch:
+            # the epoch read before the walk: a write racing the walk
+            # leaves it behind and the next call walks again
+            p, b = collect_state(self._layer)
+            self._state = (list(p.values()), list(b.values()))
+            self._state_epoch = epoch
+            self.state_walks += 1
+            get_compile_tracker().state_walks_total.inc()
+        return self._state
 
     def __call__(self, *args, **kwargs):
         if not _GLOBAL_TO_STATIC_ENABLED:
@@ -217,12 +234,19 @@ class StaticFunction:
         key = rng.next_key()
         training = self._layer.training if self._layer is not None else False
 
-        orig_leaves = jax.tree_util.tree_leaves(
-            (args, kwargs), is_leaf=lambda x: isinstance(x, Tensor))
-        arg_tensors = [l for l in orig_leaves if isinstance(l, Tensor)]
-        diff_params = [p for p in params if not p.stop_gradient]
-        needs_grad = _tape.is_grad_enabled() and (
-            diff_params or any(not t.stop_gradient for t in arg_tensors))
+        # the differentiable path's bookkeeping, only where a tape could
+        # record it: a launch under no_grad (serving) pays for none of it.
+        # ``stop_gradient`` is a plain attribute that flips without any
+        # epoch noticing, so ``diff_idx`` is per call
+        needs_grad = False
+        if _tape.is_grad_enabled():
+            orig_leaves = jax.tree_util.tree_leaves(
+                (args, kwargs), is_leaf=lambda x: isinstance(x, Tensor))
+            arg_tensors = [l for l in orig_leaves if isinstance(l, Tensor)]
+            diff_idx = [i for i, p in enumerate(params)
+                        if not p.stop_gradient]
+            needs_grad = bool(diff_idx) or any(
+                not t.stop_gradient for t in arg_tensors)
 
         if not needs_grad:
             from paddle_tpu.amp import debugging as _dbg
@@ -275,7 +299,6 @@ class StaticFunction:
         # through a @to_static forward). The vjp runs the same XLA program,
         # differentiating only the trainable params (frozen ones are closed
         # over like buffers — no wasted backward compute/residuals).
-        diff_idx = [i for i, p in enumerate(params) if not p.stop_gradient]
         diff_set = set(diff_idx)
         diff_vals = [param_vals[i] for i in diff_idx]
 

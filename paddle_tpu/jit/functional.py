@@ -20,7 +20,10 @@ from paddle_tpu.tensor import Tensor
 
 
 def collect_state(layer) -> Tuple[Dict[str, Tensor], Dict[str, Tensor]]:
-    """(params, buffers) name->Tensor for a Layer."""
+    """(params, buffers) name->Tensor for a Layer: the uncached walk, two
+    recursive passes over the whole tree. A caller on a hot path reads
+    ``StaticFunction._state_tensors()`` instead, which keeps the lists
+    until a Layer registry is written (``nn/layer_base.py::_TreeEpoch``)."""
     params = dict(layer.named_parameters())
     buffers = dict(layer.named_buffers())
     return params, buffers

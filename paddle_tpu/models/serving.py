@@ -153,6 +153,12 @@ class SlotStep:
         """Entries in the jit program cache (recompile accounting)."""
         return self._sf._jitted._cache_size()
 
+    @property
+    def state_walks(self) -> int:
+        """Times the step walked the model's Layer tree for its parameter
+        and buffer lists: constant while the model's structure stands."""
+        return self._sf.state_walks
+
     def _model_call(self, ids, position_ids, caches):
         """The model-forward half of the compiled step. Subclasses override
         this to re-stage the forward (e.g. ``ShardedSlotStep`` lowers it

@@ -41,14 +41,18 @@ def _build() -> str | None:
     if os.path.exists(out):
         return out
     srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
+    # a temporary name of this process's own: test workers that import
+    # this module at once each build and rename their own file (one
+    # shared name let the first rename take another's output away)
+    tmp = f"{out}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-pthread",
-           *srcs, "-lrt", "-o", out + ".tmp"]
+           *srcs, "-lrt", "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
     except (subprocess.CalledProcessError, FileNotFoundError,
             subprocess.TimeoutExpired):
         return None
-    os.replace(out + ".tmp", out)
+    os.replace(tmp, out)
     return out
 
 

@@ -32,13 +32,88 @@ class HookRemoveHelper:
         self._hooks.pop(self._id, None)
 
 
+class _TreeEpoch:
+    """Process-wide change counters of every Layer tree. ``structure``
+    advances whenever what some ``named_parameters()`` / ``named_buffers()``
+    / ``sublayers()`` would return may have changed (a write to any Layer's
+    registries); ``mode`` whenever a ``training`` flag changes or the
+    structure does (a sublayer brings its own flag). A reader that derived
+    something from a tree keeps it with the counter it read BEFORE the walk
+    and re-derives when the counter has moved: nobody polls the tree. A
+    tensor's value (``_replace_value``, an optimizer step, ``.to(dtype)``)
+    is not structure and advances neither."""
+
+    __slots__ = ("structure", "mode")
+
+    def __init__(self):
+        self.structure = 0
+        self.mode = 0
+
+
+_epoch = _TreeEpoch()
+
+
+def structure_epoch() -> int:
+    return _epoch.structure
+
+
+def mode_epoch() -> int:
+    return _epoch.mode
+
+
+def _structure_changed():
+    _epoch.structure += 1
+    _epoch.mode += 1
+
+
+class _Registry(dict):
+    """A Layer's parameter, buffer or sublayer table: a dict (insertion-
+    ordered) whose every mutating method advances the structure epoch, so
+    direct writes (``layer._sub_layers[name] = q``, a container's
+    ``clear()`` + refill) invalidate as registration does."""
+
+    __slots__ = ()
+
+    def __setitem__(self, key, value):
+        _structure_changed()
+        dict.__setitem__(self, key, value)
+
+    def __delitem__(self, key):
+        _structure_changed()
+        dict.__delitem__(self, key)
+
+    def pop(self, *args):
+        _structure_changed()
+        return dict.pop(self, *args)
+
+    def popitem(self):
+        _structure_changed()
+        return dict.popitem(self)
+
+    def clear(self):
+        _structure_changed()
+        dict.clear(self)
+
+    def update(self, *args, **kwargs):
+        _structure_changed()
+        dict.update(self, *args, **kwargs)
+
+    def setdefault(self, key, default=None):
+        _structure_changed()
+        return dict.setdefault(self, key, default)
+
+    def __ior__(self, other):
+        _structure_changed()
+        return dict.__ior__(self, other)
+
+
 class Layer:
     def __init__(self, name_scope=None, dtype="float32"):
         self.training = True
         self._dtype = dtypes.convert_dtype(dtype)
-        self._parameters: Dict[str, Parameter] = collections.OrderedDict()
-        self._buffers: Dict[str, Tensor] = collections.OrderedDict()
-        self._sub_layers: Dict[str, "Layer"] = collections.OrderedDict()
+        self._parameters: Dict[str, Parameter] = _Registry()
+        self._buffers: Dict[str, Tensor] = _Registry()
+        self._sub_layers: Dict[str, "Layer"] = _Registry()
         self._forward_pre_hooks: Dict[int, Callable] = collections.OrderedDict()
         self._forward_post_hooks: Dict[int, Callable] = collections.OrderedDict()
         self._name_scope = name_scope or self.__class__.__name__.lower()
@@ -46,6 +121,11 @@ class Layer:
 
     # ------------------------------------------------------------ attribute routing
     def __setattr__(self, name, value):
+        if name == "training":
+            if self.__dict__.get("training") is not value:
+                _epoch.mode += 1
+            object.__setattr__(self, name, value)
+            return
         params = self.__dict__.get("_parameters")
         if params is not None and isinstance(value, Parameter):
             params[name] = value

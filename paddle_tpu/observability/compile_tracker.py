@@ -107,6 +107,12 @@ class CompileTracker:
             "steady_state_recompiles_total",
             "compilations of functions already declared steady-state "
             "(recompile storms)")
+        self.state_walks_total = reg.counter(
+            "jit_state_walks_total",
+            "walks of a Layer tree by a StaticFunction collecting its "
+            "parameter and buffer lists (one after each structural change; "
+            "growth in steady state means something rewrites a registry "
+            "every step)")
         self._lock = threading.Lock()
         self._counts: Dict[str, int] = {}
         self._steady_counts: Dict[str, int] = {}

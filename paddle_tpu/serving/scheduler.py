@@ -67,6 +67,7 @@ from paddle_tpu.models.kv_cache import (
     window_blocks_per_seq,
 )
 from paddle_tpu.models.serving import SlotStep, _bucket, splice_carry
+from paddle_tpu.nn.layer_base import mode_epoch
 from paddle_tpu.observability.annotations import (
     guarded_by,
     holds_lock,
@@ -244,6 +245,9 @@ class ContinuousBatchingScheduler:
         # param values from the first call; written once here, read-only
         # for the scheduler's lifetime.
         self.sharding = sharding
+        # layer_base.mode_epoch() when step() last put the model in eval
+        # mode: while it stands no training flag has changed since
+        self._eval_epoch = -1
         if sharding is not None:
             sharding.prepare_model(model)
             self._step_fn = sharding.make_step(model, cfg,
@@ -2016,7 +2020,11 @@ class ContinuousBatchingScheduler:
         dispatched, never later than the next barrier."""
         with RecordEvent("serving.step"):
             was_training = self.model.training
-            self.model.eval()
+            if self._eval_epoch != mode_epoch():
+                # some Layer's training flag (or tree) changed since this
+                # scheduler last put the model in eval mode: walk it again
+                self.model.eval()
+                self._eval_epoch = mode_epoch()
             t0 = _time.perf_counter()
             pre_prefill = self.metrics.prefill_tokens
             pre_gen = self.metrics.generated_tokens
@@ -2369,11 +2377,15 @@ class ContinuousBatchingScheduler:
 
         t = get_compile_tracker()
         names = [fn.tracker_name for fn in self._step_fns()]
+        walks = [fn.state_walks for fn in self._step_fns()]
         return {
             "fn": names[0] if len(names) == 1 else names,
             "compiles": sum(t.compiles(n) for n in names),
             "steady_state_recompiles": sum(
                 t.steady_state_recompiles(n) for n in names),
+            # Layer-tree walks of each step program (as ``fn``): one after
+            # each change of the model's structure, none in steady state
+            "state_walks": walks[0] if len(walks) == 1 else walks,
         }
 
     # ---- device-side observability ------------------------------------

@@ -63,6 +63,10 @@ class ChunkPrefillStep:
     def num_programs(self):
         return self._sf._jitted._cache_size()
 
+    @property
+    def state_walks(self) -> int:
+        return self._sf.state_walks
+
     def _forward(self, ids, position_ids, caches, gather_idx):
         with region("prefill_chunk"):
             logits, new_caches = self._step._model_call(
@@ -115,6 +119,10 @@ class SpecVerifyStep:
 
     def num_programs(self):
         return self._sf._jitted._cache_size()
+
+    @property
+    def state_walks(self) -> int:
+        return self._sf.state_walks
 
     def _forward(self, ids, position_ids, caches):
         logits, new_caches = self._step._model_call(

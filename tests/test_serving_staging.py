@@ -23,6 +23,7 @@ from paddle_tpu import tensor as tensor_mod
 from paddle_tpu.jit.api import StaticFunction
 from paddle_tpu.models import GPTForCausalLM, gpt_tiny
 from paddle_tpu.models.mimo_v2 import MiMoV2ForCausalLM, mimo_v2_tiny
+from paddle_tpu.nn import layer_base as nn_layer
 from paddle_tpu.profiler import RecordEvent
 from paddle_tpu.serving import ContinuousBatchingScheduler, SchedulerConfig
 from paddle_tpu.serving import scheduler as sched_mod
@@ -281,3 +282,114 @@ def test_a_probe_round_the_step_leaves_the_tokens_unchanged(monkeypatch,
     for logits, gather_idx, sampled in probe.calls:
         rows = np.arange(len(gather_idx))
         assert (logits[rows, gather_idx].argmax(-1) == sampled).all()
+
+
+# ------------------------------- what a steady-state launch does not redo
+
+def _steady(model, monkeypatch, vocab, **over):
+    """A scheduler past its admissions, over a model in eval mode as a
+    server's is: every prompt prefilled, decode launches only from here
+    on."""
+    model.eval()
+    sched = _sched(model, monkeypatch, **over)
+    for p in _prompts(vocab, lens=(5, 21, 9)):
+        sched.add_request(p, max_new_tokens=40)
+    for _ in range(4):
+        sched.step()
+    assert len(sched.queue) == 0
+    return sched
+
+
+def _count_eval(monkeypatch):
+    calls = []
+    real = nn_layer.Layer.eval
+
+    def counted(self):
+        calls.append(self)
+        return real(self)
+
+    monkeypatch.setattr(nn_layer.Layer, "eval", counted)
+    return calls
+
+
+@pytest.mark.parametrize("kind, depth", [("gpt", 0), ("gpt", 2), ("mimo", 0)])
+def test_steady_state_steps_walk_no_layer_tree(monkeypatch, kind, depth):
+    """Neither for the step program's parameter list (``state_walks``)
+    nor for the mode flags (``Layer.eval``)."""
+    model, vocab, over = _case(kind)
+    sched = _steady(model, monkeypatch, vocab, dispatch_depth=depth, **over)
+    walks = sched.compile_stats()["state_walks"]
+    assert walks == sched._step_fn.state_walks == 1
+    generated = sched.metrics.generated_tokens
+    evals = _count_eval(monkeypatch)
+    for _ in range(20):
+        sched.step()
+    assert sched.metrics.generated_tokens >= generated + 3 * 15
+    assert sched.compile_stats()["state_walks"] == walks
+    assert evals == []
+    sched.shutdown()
+
+
+def _modes_at_launch(sched):
+    """Wraps the step: each launch's set of ``training`` flags over the
+    whole tree."""
+    seen = []
+    step = sched._step_fn
+
+    class Spy(_Recorder):
+        def __call__(self, *args):
+            seen.append({l.training for l in
+                         sched.model.sublayers(include_self=True)})
+            return self.step(*args)
+
+    sched._step_fn = Spy(step)
+    return seen
+
+
+def test_a_sublayer_set_to_training_is_in_eval_at_the_launch(monkeypatch):
+    model, vocab, over = _case("gpt")
+    sched = _steady(model, monkeypatch, vocab, **over)
+    seen = _modes_at_launch(sched)
+    evals = _count_eval(monkeypatch)
+    sched.step()
+    assert evals == []
+    inner = model.sublayers()[-1]
+    inner.training = True
+    sched.step()
+    assert evals == [model] and not inner.training
+    model.sublayers()[3].train()
+    sched.step()
+    assert len(evals) == 2
+    sched.step()
+    assert len(evals) == 2
+    # a layer built in training mode and hung on the tree afterwards
+    model.add_sublayer("late", paddle.nn.Dropout(0.5))
+    assert model.late.training
+    sched.step()
+    assert len(evals) == 3 and not model.late.training
+    assert seen == [{False}] * 5
+    sched.shutdown()
+
+
+def test_a_training_model_is_in_training_mode_after_every_step(monkeypatch):
+    """``was_training`` is restored as before, and the launch between is
+    in eval mode: the tokens are the eval model's, dropout or not."""
+    paddle.seed(7)
+    model = GPTForCausalLM(gpt_tiny(num_layers=2, hidden_dropout=0.5))
+    prompts = _prompts(1000)
+    model.eval()
+    plain = _tokens(_sched(model, monkeypatch), prompts)
+
+    model.train()
+    sched = _sched(model, monkeypatch)
+    seen = _modes_at_launch(sched)
+    rids = [sched.add_request(p, max_new_tokens=6) for p in prompts]
+    outs = {}
+    while sched.has_unfinished():
+        for o in sched.step():
+            outs[o.request_id] = o
+        assert {l.training for l in model.sublayers(include_self=True)} \
+            == {True}
+    assert seen and all(modes == {False} for modes in seen)
+    assert [[int(t) for t in outs[r].token_ids] for r in rids] == plain
+    sched.shutdown()
