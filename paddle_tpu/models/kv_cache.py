@@ -22,6 +22,16 @@ python/paddle/incubate/nn/functional/ — re-designed TPU-first:
   its table holds only the pages the window still reaches (a second class
   of blocks in the scheduler), and a layer passes its ``window`` and
   ``sink`` to ``cache_update_attend``.
+- A layer may cache one latent row a token in place of K and V rows per
+  head (``LayerCacheGeometry.latent``: multi-head latent attention): one
+  pool a layer, ``[num_blocks, block_size, row]``, no V pool (``v_pool`` /
+  ``v`` is None), every head reads the same row and a position's value is
+  the row's first ``v_dim`` lanes. ``latent_cache_write`` puts a chunk's
+  rows there (a prefill attends over its own expanded K and V) and
+  ``latent_cache_update_attend`` writes one and attends over the rows with
+  the query the model has absorbed its K up-projection into: on a TPU
+  through ``ops/pallas/paged_mla_decode.py``, elsewhere over the gathered
+  table. The latent rows live in the full class of blocks.
 """
 
 from __future__ import annotations
@@ -46,6 +56,9 @@ StaticCacheSlot = namedtuple("StaticCacheSlot", ["k", "v", "pos"])
 # differ in width), or with the KV heads folded into the row, [num_blocks,
 # block_size, KVH * D] (``LayerCacheGeometry.fold_heads``); block_table:
 # [B, max_blocks] int32 (block ids, -1 = unallocated); pos: [B] int32.
+# A latent layer has one pool, ``k_pool [num_blocks, block_size, row]``, and
+# ``v_pool`` None (a static slot likewise: ``k [B, max_len, row]``, ``v``
+# None).
 # ``base`` is None where column c of the table is the row's page c (a layer
 # that keeps its whole context), else [B] int32: the position of column 0's
 # first token (a multiple of the block size), for a window layer's table,
@@ -89,10 +102,16 @@ def pools_only(caches):
 # itself included), and ``fold_heads`` (the pools keep a token's KV heads
 # side by side in one row, ``[NB, bs, KVH * D]``: a model whose KV heads do
 # not fill a sublane tile asks for it, so that a page is whole tiles).
+# ``latent``: the layer caches one row of ``k_dim`` a token that every query
+# head reads (``kv_heads`` 1), whose first ``v_dim`` lanes are the position's
+# value; there is no V pool, and the pool's rows are ``k_dim`` padded to
+# whole lane tiles (``latent_row_width``).
 LayerCacheGeometry = namedtuple(
     "LayerCacheGeometry",
-    ["kv_heads", "k_dim", "v_dim", "window", "fold_heads"],
-    defaults=(None, False))
+    ["kv_heads", "k_dim", "v_dim", "window", "fold_heads", "latent"],
+    defaults=(None, False, False))
+
+_LANES = 128
 
 _NEG = -1e30
 
@@ -126,8 +145,18 @@ def window_blocks_per_seq(window: int, block_size: int) -> int:
     return -(-window // block_size) + 1
 
 
+def latent_row_width(k_dim: int) -> int:
+    """The row width a latent layer's pool is allocated with: ``k_dim``
+    rounded up to whole lane tiles (576 -> 640), zeros in the padding, so
+    that the decode kernel contracts and slices at tile boundaries only."""
+    return -(-k_dim // _LANES) * _LANES
+
+
 def pool_shapes(geom: LayerCacheGeometry, num_blocks: int, block_size: int):
-    """``(k_pool shape, v_pool shape)`` of one layer."""
+    """``(k_pool shape, v_pool shape)`` of one layer; a latent layer has
+    one pool and ``None`` for the other."""
+    if geom.latent:
+        return ([num_blocks, block_size, latent_row_width(geom.k_dim)], None)
     if geom.fold_heads:
         return ([num_blocks, block_size, geom.kv_heads * geom.k_dim],
                 [num_blocks, block_size, geom.kv_heads * geom.v_dim])
@@ -135,26 +164,43 @@ def pool_shapes(geom: LayerCacheGeometry, num_blocks: int, block_size: int):
             [num_blocks, block_size, geom.kv_heads, geom.v_dim])
 
 
-def _attend(q, keys, values, q_pos, k_pos, window=None, sink=None):
+def zero_pools(geom: LayerCacheGeometry, num_blocks: int, block_size: int,
+               dtype):
+    """``(k_pool, v_pool)`` of one layer, zeroed (``v_pool`` None for a
+    latent layer): what the scheduler and ``DecodeEngine`` thread through
+    their compiled steps."""
+    import paddle_tpu as paddle
+
+    return tuple(None if shape is None else paddle.zeros(shape, dtype=dtype)
+                 for shape in pool_shapes(geom, num_blocks, block_size))
+
+
+def _attend(q, keys, values, q_pos, k_pos, window=None, sink=None,
+            scale=None):
     """q [B,s,H,D] at positions q_pos [B,s] against keys [B,L,KVH,D] and
     values [B,L,KVH,Dv] at positions k_pos [B,L]: key j is visible to query
     i iff ``k_pos[j] <= q_pos[i]`` and, under a window, ``q_pos[i] -
     k_pos[j] < window``. GQA by grouping the query heads of a KV head, never
     by repeating K or V. ``sink [H]`` is a logit a head that joins the
     softmax's denominator and carries no value. Scores and softmax in
-    float32, scale 1/sqrt(D), probabilities cast to q's dtype."""
+    float32, scale 1/sqrt(D) unless ``scale`` states another,
+    probabilities cast to q's dtype."""
     B, s, H, D = q.shape
     L, kvh = keys.shape[1], keys.shape[2]
     g = H // kvh
     keys32 = keys.astype(jnp.float32)
+    # the division is what the programs of every model without a stated
+    # scale have always held
+    scaled = ((lambda x: x / math.sqrt(D)) if scale is None
+              else (lambda x: x * scale))
 
     def block(qb, qp):
         # qb [B,c,H,D], qp [B,c]
         c = qb.shape[1]
-        scores = jnp.einsum(
+        scores = scaled(jnp.einsum(
             "bskgd,blkd->bkgsl",
             qb.astype(jnp.float32).reshape(B, c, kvh, g, D),
-            keys32) / math.sqrt(D)
+            keys32))
         d = qp[:, :, None] - k_pos[:, None, :]                  # [B,c,L]
         mask = d >= 0
         if window is not None:
@@ -193,8 +239,8 @@ def _attend(q, keys, values, q_pos, k_pos, window=None, sink=None):
         def body(j, carry):
             m, l, acc = carry
             cut = lambda x: jax.lax.dynamic_slice_in_dim(x, j * c, c, axis=1)
-            scores = jnp.einsum("bskgd,blkd->bkgsl", q5,
-                                cut(keys).astype(jnp.float32)) / math.sqrt(D)
+            scores = scaled(jnp.einsum("bskgd,blkd->bkgsl", q5,
+                                       cut(keys).astype(jnp.float32)))
             d = qp[:, :, None] - cut(k_pos)[:, None, :]
             mask = d >= 0
             if window is not None:
@@ -230,7 +276,7 @@ def _attend(q, keys, values, q_pos, k_pos, window=None, sink=None):
 
 
 def _masked_attention(q, keys, values, pos, window=None, sink=None,
-                      base=None):
+                      base=None, scale=None):
     """q [B,s,H,D], the tokens at positions pos[b] .., against the buffer
     keys/values [B,L,KVH,D] whose entry j holds position base[b] + j
     (base None: j)."""
@@ -238,7 +284,7 @@ def _masked_attention(q, keys, values, pos, window=None, sink=None,
     q_pos = pos[:, None] + jnp.arange(s)[None, :]
     k_pos = jnp.arange(L)[None, :] + (0 if base is None else base[:, None])
     k_pos = jnp.broadcast_to(k_pos, (q.shape[0], L))
-    return _attend(q, keys, values, q_pos, k_pos, window, sink)
+    return _attend(q, keys, values, q_pos, k_pos, window, sink, scale)
 
 
 def _banded_attention(q, k, v, window, sink=None):
@@ -375,17 +421,23 @@ def _paged_attend_xla(qv, k_pool, v_pool, block_table, pos, window=None,
 _last_path = None
 
 
-def _paged_kernel(qv, k_pool, v_pool):
+def _paged_kernel(qv, k_pool, v_pool, v_dim=None):
     """The one gate, decided at trace time from what the code can observe:
     the decode kernel of the pools' layout (``paged_attention`` for
     ``[NB, bs, KVH, D]`` pools, ``paged_attention_gqa`` for pools with the
-    heads folded into the row) iff there is one query token a row, the
-    platform is a TPU (or a test runs that kernel through the interpreter),
-    and the shapes are ones that kernel supports; else ``None``. A kernel
-    that was selected and fails raises."""
-    from paddle_tpu.ops.pallas import paged_attention, paged_attention_gqa
+    heads folded into the row, ``paged_mla_decode`` for a latent layer's one
+    pool, whose values are its rows' first ``v_dim`` lanes) iff there is one
+    query token a row, the platform is a TPU (or a test runs that kernel
+    through the interpreter), and the shapes are ones that kernel supports;
+    else ``None``. A kernel that was selected and fails raises."""
+    from paddle_tpu.ops.pallas import (
+        paged_attention,
+        paged_attention_gqa,
+        paged_mla_decode,
+    )
 
-    ker = paged_attention if k_pool.ndim == 4 else paged_attention_gqa
+    ker = (paged_mla_decode if v_pool is None
+           else paged_attention if k_pool.ndim == 4 else paged_attention_gqa)
     if qv.shape[1] != 1:
         return None
     if not ker._interpret:
@@ -394,7 +446,8 @@ def _paged_kernel(qv, k_pool, v_pool):
         if not is_tpu():
             return None
     ok = ker.supports((qv.shape[0],) + tuple(qv.shape[2:]), qv.dtype,
-                      k_pool.shape, k_pool.dtype, v_pool.shape)
+                      k_pool.shape, k_pool.dtype,
+                      v_dim if v_pool is None else v_pool.shape)
     return ker if ok else None
 
 
@@ -479,6 +532,83 @@ def cache_update_attend(q, k, v, slot, window=None, sink=None):
     raise TypeError(f"not a cache slot: {type(slot)!r}")
 
 
+def _latent_write_raw(rows, buf, pos, *table):
+    """A chunk's latent rows into a static buffer ``[B, max_len, row]`` at
+    each row's offset, or into a pool through its block table; the rows
+    are padded with zeros to the buffer's row width."""
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, buf.shape[-1] - rows.shape[-1]))
+                   ).astype(buf.dtype)
+    with region("kv_gather"):
+        if table:
+            buf2 = _paged_write(buf, rows, table[0], pos)
+        else:
+            buf2 = jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
+                c, n, (p, 0)))(buf, rows, pos)
+    return buf2, pos + rows.shape[1]
+
+
+def _latent_slot(slot):
+    """``(field, table)``: the name of a latent slot's one buffer, and what
+    beside ``pos`` locates a row in it (a paged slot's block table)."""
+    if isinstance(slot, PagedCacheSlot):
+        return "k_pool", (slot.block_table,)
+    return "k", ()
+
+
+def latent_cache_write(rows, slot):
+    """Write a chunk's latent rows ``[B, s, k_dim]`` at the slot's
+    positions and return the new slot: the cache's half of a prefill that
+    attends over its own expanded K and V (such a chunk starts its row, as
+    a window layer's does)."""
+    field, table = _latent_slot(slot)
+    buf2, pos2 = apply("latent_cache_write", _latent_write_raw, rows,
+                       getattr(slot, field), slot.pos, *table)
+    return slot._replace(**{field: buf2, "pos": pos2})
+
+
+def _latent_attend_raw(qv, rows, buf, pos, *table, v_dim, scale):
+    """Write, then attention of the absorbed query qv [B,s,H,Dq] over the
+    updated rows: head h's score against a position is ``qv[h] . row *
+    scale`` and the position's value the row's first ``v_dim`` lanes."""
+    global _last_path
+
+    buf2, pos2 = _latent_write_raw(rows, buf, pos, *table)
+    ker = _paged_kernel(qv, buf2, None, v_dim) if table else None
+    if ker is not None:
+        _last_path = "pallas"
+        with region("mla_decode"):
+            out = ker.paged_mla_decode(qv[:, 0], buf2, table[0], pos + 1,
+                                       v_dim=v_dim, scale=scale)[:, None]
+        return out, buf2, pos2
+    _last_path = "xla"
+    with region("mla_decode"):
+        if table:
+            with region("kv_gather"):
+                keys = buf2[jnp.maximum(table[0], 0)].reshape(
+                    qv.shape[0], -1, 1, buf2.shape[-1])
+        else:
+            keys = buf2[:, :, None, :]
+        q = jnp.pad(qv, ((0, 0),) * 3 + ((0, keys.shape[-1] - qv.shape[-1]),))
+        out = _masked_attention(q, keys, keys[..., :v_dim], pos, scale=scale)
+    return out, buf2, pos2
+
+
+def latent_cache_update_attend(q, rows, slot, v_dim: int, scale: float):
+    """Latent-cache write + attend in the absorbed form: ``q [B,s,H,Dq]``
+    is the query with the layer's K up-projection folded in (``Dq`` <= the
+    row), ``rows [B,s,k_dim]`` the new tokens' latent rows. Returns (out
+    ``[B,s,H,v_dim]``: each head's weighted sum of the rows' first
+    ``v_dim`` lanes, before the V up-projection; the new slot). One query
+    token a row over a paged slot on a TPU reads the live pages in place
+    (``ops/pallas/paged_mla_decode.py``); everything else gathers."""
+    field, table = _latent_slot(slot)
+    out, buf2, pos2 = apply(
+        "latent_cache_attention", _latent_attend_raw, q, rows,
+        getattr(slot, field), slot.pos, *table, v_dim=int(v_dim),
+        scale=float(scale))
+    return out, slot._replace(**{field: buf2, "pos": pos2})
+
+
 def make_static_cache(num_layers: int, batch: int, max_len: int,
                       kv_heads: int, head_dim: int,
                       dtype="bfloat16", geometry=None) -> List[StaticCacheSlot]:
@@ -490,9 +620,14 @@ def make_static_cache(num_layers: int, batch: int, max_len: int,
                             ] * num_layers
     slots = []
     for g in geometry:
+        pos = paddle.zeros([batch], dtype="int32")
+        if g.latent:
+            slots.append(StaticCacheSlot(paddle.zeros(
+                [batch, max_len, latent_row_width(g.k_dim)], dtype=dtype),
+                None, pos))
+            continue
         k = paddle.zeros([batch, max_len, g.kv_heads, g.k_dim], dtype=dtype)
         v = paddle.zeros([batch, max_len, g.kv_heads, g.v_dim], dtype=dtype)
-        pos = paddle.zeros([batch], dtype="int32")
         slots.append(StaticCacheSlot(k, v, pos))
     return slots
 

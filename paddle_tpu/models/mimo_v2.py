@@ -42,14 +42,12 @@ from __future__ import annotations
 from dataclasses import dataclass, fields
 from typing import Optional, Tuple
 
-import jax.numpy as jnp
-
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
-from paddle_tpu.core.dispatch import apply
 from paddle_tpu.incubate.nn import functional as IF
 from paddle_tpu.models import kv_cache
 from paddle_tpu.nn import initializer as I
+from paddle_tpu.nn import moe
 from paddle_tpu.nn.moe import DroplessMoE
 from paddle_tpu.nn.param_attr import ParamAttr
 
@@ -301,12 +299,6 @@ class MiMoV2ForCausalLM(nn.Layer):
         compiled serving step's telemetry block: pairs routed to held
         experts and the largest held expert's load, each a mean over the
         expert layers."""
-        stats = [l.mlp.last_stats for l in self.model.layers
-                 if isinstance(l.mlp, DroplessMoE)]
-        if not stats or any(s is None for s in stats):
-            return (), None
-        mean = apply("moe_step_stats",
-                     lambda *s: jnp.mean(jnp.stack(s), axis=0), *stats,
-                     differentiable=False)
-        return ("moe_pairs_held", "moe_load_max"), mean
+        return moe.step_stats([l.mlp for l in self.model.layers
+                               if isinstance(l.mlp, DroplessMoE)])
 

@@ -34,7 +34,7 @@ from paddle_tpu.models.kv_cache import (
     cache_geometry,
     donate_pools,
     make_static_cache,
-    pool_shapes,
+    zero_pools,
     pools_only,
 )
 from paddle_tpu.observability.step_profile import region
@@ -271,8 +271,8 @@ class DecodeEngine:
         for g in self.geometry:
             # a window layer keeps its whole table here (one class of
             # blocks): the window is the layer's mask, not a saving
-            kp, vp = (paddle.zeros(shape, dtype=self.cache_dtype)
-                      for shape in pool_shapes(g, n_blocks, self.block_size))
+            kp, vp = zero_pools(g, n_blocks, self.block_size,
+                                self.cache_dtype)
             slots.append(PagedCacheSlot(kp, vp, table_t, pos_t))
         return slots, alloc, per_seq_blocks
 

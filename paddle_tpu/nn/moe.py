@@ -8,12 +8,16 @@ capacity dropped). This one is built for a compiled serving step:
 - the router keeps its published width: sigmoid scores over all ``E``
   experts in float32, the chosen set is the top ``k`` of ``score + bias``
   (the ``noaux_tc`` correction bias chooses, it does not weigh), and the
-  weights are the chosen scores normalised over all ``k`` chosen;
+  weights are the chosen scores normalised over all ``k`` chosen, times
+  ``routed_scaling_factor`` where a model states one;
 - the layer is told which experts it holds (``first, count``: expert
   parallelism's share of one chip) and computes
   ``sum_{e chosen and held} w_e E_e(x)``: what the absent experts would have
   added is left out, and that partial sum goes on to the next layer. Nothing
   here stands in for the other chips or their exchange;
+- a shared expert (``shared_width``), where the model has one, is a dense
+  SwiGLU over every token that every chip of the deployment computes alike:
+  it is computed whole here and added to the held experts' partial sum;
 - no capacity and no drops: the ``T * k`` (token, expert) pairs are sorted by
   expert, pairs of experts held elsewhere go to the end, and one grouped
   product over the stacked weights ``[count, H, 2F]`` / ``[count, F, H]``
@@ -48,15 +52,19 @@ _last_path = None
 _GMM_ROWS = 128
 
 
-def route(x, router_w, bias, top_k: int):
+def route(x, router_w, bias, top_k: int, scaling: float = 1.0):
     """``(experts [T, k] int32, weights [T, k] float32)`` of tokens
     ``x [T, H]``: sigmoid scores in float32, top ``k`` of score + bias,
-    weights normalised over the chosen."""
+    weights normalised over the chosen, times ``scaling`` (at 1.0 the
+    program is the one without it)."""
     scores = jax.nn.sigmoid(jnp.dot(
         x, router_w, preferred_element_type=jnp.float32).astype(jnp.float32))
     _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
-    return experts.astype(jnp.int32), chosen / chosen.sum(-1, keepdims=True)
+    weights = chosen / chosen.sum(-1, keepdims=True)
+    if scaling != 1.0:
+        weights = weights * scaling
+    return experts.astype(jnp.int32), weights
 
 
 def _gmm_tiles(m: int, k: int, n: int):
@@ -95,7 +103,8 @@ def grouped_matmul(x, w, group_sizes):
     return jax.lax.ragged_dot(x, w, group_sizes)
 
 
-def _dropless_raw(x, router_w, bias, w_in, w_out, *, top_k: int, first: int):
+def _dropless_raw(x, router_w, bias, w_in, w_out, *, top_k: int, first: int,
+                  scaling: float = 1.0):
     """``(partial sum [T, H], stats f32[2], experts [T, k])`` of tokens
     ``x [T, H]`` over the experts ``first .. first + count`` whose stacked
     weights are ``w_in [count, H, 2F]`` (gate | up) and ``w_out [count, F,
@@ -104,7 +113,7 @@ def _dropless_raw(x, router_w, bias, w_in, w_out, *, top_k: int, first: int):
     tokens, hidden = x.shape
     count, width = w_out.shape[0], w_out.shape[1]
     with region("moe_route"):
-        experts, weights = route(x, router_w, bias, top_k)
+        experts, weights = route(x, router_w, bias, top_k, scaling)
         local = experts - first
         held = (local >= 0) & (local < count)
         key = jnp.where(held, local, count).reshape(-1)        # [T * k]
@@ -150,24 +159,51 @@ def _dropless_raw(x, router_w, bias, w_in, w_out, *, top_k: int, first: int):
     return out.astype(x.dtype), stats, experts
 
 
+def step_stats(layers):
+    """``(names, traced f32 values)`` of the last forward of a model whose
+    ``layers`` hold these expert layers, for the compiled serving step's
+    telemetry block (``model.step_stats()``): pairs routed to held experts
+    and the largest held expert's load, each a mean over the layers."""
+    stats = [l.last_stats for l in layers]
+    if not stats or any(s is None for s in stats):
+        return (), None
+    mean = apply("moe_step_stats",
+                 lambda *s: jnp.mean(jnp.stack(s), axis=0), *stats,
+                 differentiable=False)
+    return ("moe_pairs_held", "moe_load_max"), mean
+
+
+def _shared_raw(x, w_gate, w_up, w_down):
+    """The shared expert: SwiGLU of ``x [T, H]``, the activation in float32
+    as in the routed experts."""
+    with region("moe_shared"):
+        act = (jax.nn.silu(jnp.dot(x, w_gate).astype(jnp.float32))
+               * jnp.dot(x, w_up).astype(jnp.float32)).astype(x.dtype)
+        return jnp.dot(act, w_down)
+
+
 class DroplessMoE(nn.Layer):
     """Router over ``num_experts`` and the stacked weights of the
     ``experts_held = (first, count)`` experts this layer holds (all of them
     by default). ``forward(x [..., H])`` returns the held experts' partial
-    sum; ``last_stats`` is then the f32[2] ``[pairs to held experts,
+    sum (the routed weights times ``routed_scaling_factor``) plus, where
+    ``shared_width`` is given, a shared expert of that width over every
+    token; ``last_stats`` is then the f32[2] ``[pairs to held experts,
     largest held expert's load]`` of that call and ``last_experts`` the
     router's choice ``[T, k]`` (traced values inside a compiled step, for
     whoever reads them in the same trace; unread, they cost nothing)."""
 
     def __init__(self, hidden_size: int, expert_width: int, num_experts: int,
                  top_k: int, experts_held=None, initializer_range: float = 0.02,
-                 dtype=None):
+                 dtype=None, routed_scaling_factor: float = 1.0,
+                 shared_width: int = 0):
         super().__init__()
         first, count = experts_held or (0, num_experts)
         if not (0 <= first and first + count <= num_experts and count > 0):
             raise ValueError(f"experts_held {experts_held} is not a share of "
                              f"{num_experts} experts")
         self.top_k, self.first, self.count = int(top_k), int(first), int(count)
+        self.scaling = float(routed_scaling_factor)
         normal = ParamAttr(initializer=I.Normal(0.0, initializer_range))
         self.router = self.create_parameter(
             [hidden_size, num_experts], attr=normal, dtype=dtype)
@@ -178,6 +214,13 @@ class DroplessMoE(nn.Layer):
             [count, hidden_size, 2 * expert_width], attr=normal, dtype=dtype)
         self.w_out = self.create_parameter(
             [count, expert_width, hidden_size], attr=normal, dtype=dtype)
+        self.shared_width = int(shared_width)
+        if shared_width:
+            self.shared_gate, self.shared_up, self.shared_down = (
+                self.create_parameter(shape, attr=normal, dtype=dtype)
+                for shape in ([hidden_size, shared_width],
+                              [hidden_size, shared_width],
+                              [shared_width, hidden_size]))
         self.last_stats = self.last_experts = None
 
     def forward(self, x):
@@ -186,5 +229,9 @@ class DroplessMoE(nn.Layer):
         out, self.last_stats, self.last_experts = apply(
             "dropless_moe", _dropless_raw, flat, self.router,
             self.e_score_correction_bias, self.w_in, self.w_out,
-            top_k=self.top_k, first=self.first)
+            top_k=self.top_k, first=self.first, scaling=self.scaling)
+        if self.shared_width:
+            out = out + apply("moe_shared_expert", _shared_raw, flat,
+                              self.shared_gate, self.shared_up,
+                              self.shared_down)
         return out.reshape(shape)

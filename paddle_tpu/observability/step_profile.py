@@ -78,6 +78,13 @@ REGION_MANIFEST = {
     # dropless expert layer (nn/moe.py): routing and sort, grouped products
     "moe_route": {"owner": "models", "category": "Forward"},
     "moe_experts": {"owner": "models", "category": "Forward"},
+    "moe_shared": {"owner": "models", "category": "Forward"},
+    # multi-head latent attention (models/joyai_flash.py, models/kv_cache.py):
+    # the K up-projection folded into a decode query, attention over the
+    # latent rows, a prefill's K and V expanded from its own rows
+    "mla_absorb": {"owner": "models", "category": "Forward"},
+    "mla_decode": {"owner": "models", "category": "Forward"},
+    "mla_expand": {"owner": "models", "category": "Forward"},
     "logits": {"owner": "models", "category": "Forward"},
     "sampling": {"owner": "serving", "category": "Forward"},
     "telemetry": {"owner": "serving", "category": "UserDefined"},

@@ -63,7 +63,7 @@ from paddle_tpu.models.kv_cache import (
     KVPoolExhausted,
     PagedCacheSlot,
     cache_geometry,
-    pool_shapes,
+    zero_pools,
     window_blocks_per_seq,
 )
 from paddle_tpu.models.serving import SlotStep, _bucket, splice_carry
@@ -204,11 +204,19 @@ class ContinuousBatchingScheduler:
             raise ValueError(f"window layers of one model must share one "
                              f"window; got {windows}")
         self._window: Optional[int] = windows[0] if windows else None
-        if self._window is not None:
-            # these assume one class of blocks whose pages live as long as
-            # their request: a window layer's table forgets a row's early
-            # pages, so a shared prefix, a chunk or a draft verified against
-            # it would read what is no longer there
+        # these assume one class of blocks whose pages live as long as their
+        # request and are read by every later token: a window layer's table
+        # forgets a row's early pages, so a shared prefix, a chunk or a
+        # draft verified against it would read what is no longer there; a
+        # latent layer's chunk attends over its own expanded K and V (it
+        # starts its row), and its one pool has no heads to shard
+        refuses = (
+            "sliding-window layers: it assumes one class of KV blocks that "
+            "keep a row's whole context" if self._window is not None else
+            "latent-cache layers: a chunk of several tokens does not read "
+            "the rows cached before it" if any(g.latent for g in geometry)
+            else None)
+        if refuses:
             for on, feature in (
                     (cfg.enable_prefix_caching,
                      "prefix caching (enable_prefix_caching)"),
@@ -217,10 +225,8 @@ class ContinuousBatchingScheduler:
                     (cfg.spec_k, "speculative decoding (spec_k)"),
                     (sharding is not None, "the sharded step (sharding)")):
                 if on:
-                    raise ValueError(
-                        f"{feature} is not supported for a model with "
-                        f"sliding-window layers: it assumes one class of "
-                        f"KV blocks that keep a row's whole context")
+                    raise ValueError(f"{feature} is not supported for a "
+                                     f"model with {refuses}")
         max_pos = getattr(mcfg, "max_position_embeddings", cfg.max_seq_len)
         self.max_seq_len = min(cfg.max_seq_len, max_pos)
         self.metrics = metrics or ServingMetrics()
@@ -326,9 +332,8 @@ class ContinuousBatchingScheduler:
         for g in geometry:
             n = (self.window_allocator.num_blocks if g.window
                  else cfg.total_blocks)
-            self._pools.append(tuple(
-                paddle.zeros(shape, dtype=cfg.cache_dtype)
-                for shape in pool_shapes(g, n, cfg.block_size)))
+            self._pools.append(zero_pools(g, n, cfg.block_size,
+                                          cfg.cache_dtype))
         if sharding is not None:
             # head-shard the K/V pools over the replica's mesh (~1/tp of
             # the KV bytes per chip); block tables and positions stay tiny

@@ -325,23 +325,40 @@ def test_window_row_never_holds_more_than_its_pages(model):
 
 # ---- (i): features that assume one class of blocks refuse ------------------
 
+@pytest.fixture(scope="module")
+def latent_model():
+    from paddle_tpu.models.joyai_flash import (
+        JoyAIFlashForCausalLM, joyai_flash_tiny)
+
+    paddle.seed(0)
+    return JoyAIFlashForCausalLM(joyai_flash_tiny())
+
+
+@pytest.mark.parametrize("family, why", [
+    ("model", "sliding-window layers"), ("latent_model", "latent-cache")])
 @pytest.mark.parametrize("kw, feature", [
     (dict(enable_prefix_caching=True), "prefix caching"),
     (dict(spec_k=2), "speculative decoding"),
     (dict(prefill_chunk_size=16), "chunked prefill"),
 ])
-def test_one_class_features_refuse_a_windowed_model(model, kw, feature):
-    with pytest.raises(ValueError, match=feature):
-        ContinuousBatchingScheduler(model, SchedulerConfig(
-            max_num_seqs=2, max_seq_len=64, block_size=BS,
-            cache_dtype="float32", **kw))
+def test_one_class_features_refuse_a_windowed_model(request, family, why, kw,
+                                                    feature):
+    """... and a model whose layers cache latent rows (a chunk of several
+    tokens does not read the rows cached before it)."""
+    with pytest.raises(ValueError, match=f"{feature}.*{why}"):
+        ContinuousBatchingScheduler(
+            request.getfixturevalue(family), SchedulerConfig(
+                max_num_seqs=2, max_seq_len=64, block_size=BS,
+                cache_dtype="float32", **kw))
 
 
-def test_sharded_step_refuses_a_windowed_model(model):
+@pytest.mark.parametrize("family", ["model", "latent_model"])
+def test_sharded_step_refuses_a_windowed_model(request, family):
     with pytest.raises(ValueError, match="sharded step"):
-        ContinuousBatchingScheduler(model, SchedulerConfig(
-            max_num_seqs=2, max_seq_len=64, block_size=BS,
-            cache_dtype="float32"), sharding=object())
+        ContinuousBatchingScheduler(
+            request.getfixturevalue(family), SchedulerConfig(
+                max_num_seqs=2, max_seq_len=64, block_size=BS,
+                cache_dtype="float32"), sharding=object())
 
 
 def test_gpt_and_llama_answer_with_one_class_of_layers():

@@ -1,4 +1,5 @@
-"""The Pallas paged decode-attention kernel (ops/pallas/paged_attention.py)
+"""The Pallas paged decode-attention kernels (ops/pallas/paged_attention.py,
+its siblings for folded pools and for a latent layer's one pool)
 and its gate in models/kv_cache.py.
 
 On the CPU the kernel runs through the Pallas interpreter (``_interpret``,
@@ -411,3 +412,125 @@ def test_folded_kernel_compiles_for_v5e(one_chip, kind, kvh, max_blocks,
     pool_bytes = num_blocks * 16 * kvh * 192 * 2
     if batch > 1:      # the pools are read in place
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+# ---- the sibling for a latent layer's one pool (ops/pallas/paged_mla_decode.py)
+
+from paddle_tpu.ops.pallas import paged_mla_decode as pm  # noqa: E402
+
+
+@pytest.fixture()
+def mla_interpreted():
+    pm._interpret = True
+    yield
+    pm._interpret = False
+
+
+def _latent_case(seed, batch, max_blocks, dtype, lengths, n_heads=4, row=40,
+                 v_dim=32, bs=4):
+    """One pool of rows padded to a lane tile (zeros in the padding),
+    shuffled pages, the absorbed query of every head against them."""
+    rng = np.random.default_rng(seed)
+    nb = batch * max_blocks + 3
+    width = kv_cache.latent_row_width(row)
+    pool = jnp.zeros((nb, bs, width), dtype).at[:, :, :row].set(
+        jnp.asarray(rng.standard_normal((nb, bs, row)), dtype))
+    q = jnp.asarray(rng.standard_normal((batch, 1, n_heads, row)), dtype)
+    table = (1 + rng.permutation(nb - 1)[:batch * max_blocks]).reshape(
+        batch, max_blocks).astype(np.int32)
+    return q, pool, table, np.asarray(lengths, np.int32) - 1, v_dim, 0.25
+
+
+def _latent_oracle(q, pool, table, pos, v_dim, scale):
+    """The XLA formulation: the table's pages gathered, every head against
+    the same rows, the value a row's first ``v_dim`` lanes."""
+    keys = pool[jnp.maximum(jnp.asarray(table), 0)].reshape(
+        q.shape[0], -1, 1, pool.shape[-1])
+    wide = jnp.pad(q, ((0, 0),) * 3 + ((0, pool.shape[-1] - q.shape[-1]),))
+    return np.asarray(kv_cache._masked_attention(
+        wide, keys, keys[..., :v_dim], jnp.asarray(pos), scale=scale),
+        np.float32)
+
+
+def _latent_kernel(q, pool, table, pos, v_dim, scale, **kw):
+    return np.asarray(pm.paged_mla_decode(
+        q[:, 0], pool, jnp.asarray(table), jnp.asarray(pos) + 1, v_dim=v_dim,
+        scale=scale, **kw), np.float32)[:, None]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("lengths, max_blocks, pages", [
+    ([1, 3, 4, 5, 23, 39], 10, 4),            # ragged, 3 groups
+    ([17, 3], 6, None),                       # one group holds the table
+], ids=["groups", "one_group"])
+def test_latent_kernel_matches_gather(mla_interpreted, dtype, lengths,
+                                      max_blocks, pages):
+    case = _latent_case(13, len(lengths), max_blocks, dtype, lengths)
+    got = _latent_kernel(*case, pages_per_group=pages)
+    assert got.shape == (len(lengths), 1, 4, 32)
+    np.testing.assert_allclose(got, _latent_oracle(*case), atol=TOL[dtype],
+                               rtol=0)
+
+
+def test_latent_kernel_idle_rows_are_finite_and_dead_pages_are_not_read(
+        mla_interpreted):
+    q, pool, table, pos, v_dim, scale = _latent_case(
+        5, 3, 4, "float32", [14, 1, 9])
+    table[1], pos[1] = -1, -1                         # an idle row
+    run = lambda p: _latent_kernel(q, p, table, pos, v_dim, scale,
+                                   pages_per_group=2)
+    got = run(pool)
+    assert np.isfinite(got).all()
+    # pages past a row's length are never copied: poison every block the
+    # live rows' live pages do not name and the result does not move
+    used = np.concatenate([table[0, :4], table[2, :3], [0]])
+    poison = np.setdiff1d(np.arange(pool.shape[0]), used)
+    np.testing.assert_allclose(run(pool.at[poison].set(np.nan))[[0, 2]],
+                               got[[0, 2]], atol=0)
+
+
+@pytest.mark.parametrize("forced, s, dtype, want", [
+    (False, 1, "float32", "xla"), (True, 1, "float32", "pallas"),
+    (True, 2, "float32", "xla"), (True, 1, "float16", "xla")],
+    ids=["cpu", "forced", "s2", "fp16"])
+def test_gate_takes_the_latent_kernel_by_the_pools_layout(forced, s, dtype,
+                                                          want):
+    pm._interpret = forced
+    try:
+        kv_cache._last_path = None
+        out, pool2, pos2 = kv_cache._latent_attend_raw(
+            jnp.zeros((2, s, 4, 40), dtype), jnp.ones((2, s, 40), dtype),
+            jnp.zeros((4, 4, 128), dtype), jnp.ones((2,), jnp.int32),
+            jnp.asarray([[0, 1], [2, 3]], jnp.int32), v_dim=32, scale=0.25)
+    finally:
+        pm._interpret = False
+    assert kv_cache._last_path == want
+    assert out.shape == (2, s, 4, 32) and list(pos2) == [1 + s, 1 + s]
+    # the new rows landed at position 1 of each row's first page, padded
+    assert (np.asarray(pool2, np.float32)[[0, 2], 1, :40] == 1).all()
+    assert not np.asarray(pool2, np.float32)[:, :, 40:].any()
+
+
+@pytest.mark.parametrize("batch, max_blocks, num_blocks", [
+    (128, 512, 36000),      # the JoyAI-LLM-Flash cell's decode program
+    (1, 35, 35),
+], ids=["decode", "one_row"])
+def test_latent_kernel_compiles_for_v5e(one_chip, batch, max_blocks,
+                                        num_blocks):
+    def shape(dims, dt="bfloat16"):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+
+    fn = lambda q, pool, t, n: pm.paged_mla_decode(
+        q, pool, t, n, v_dim=512, scale=192 ** -0.5)
+    compiled = jax.jit(fn).lower(
+        shape((batch, 32, 576)), shape((num_blocks, 16, 640)),
+        shape((batch, max_blocks), "int32"),
+        shape((batch,), "int32")).compile()
+    text = compiled.as_text()
+    assert "paged_mla_decode" in text and "tpu_custom_call" in text
+    if batch > 1:      # the pool is read in place
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < num_blocks * 16 * 640 * 2 // 8)
+    # a row of the published width is not whole lane tiles: refused
+    assert not pm.supports((batch, 32, 576), "bfloat16",
+                           (num_blocks, 16, 576), "bfloat16", 512)
