@@ -429,7 +429,8 @@ def mla_interpreted():
 def _latent_case(seed, batch, max_blocks, dtype, lengths, n_heads=4, row=40,
                  v_dim=32, bs=4):
     """One pool of rows padded to a lane tile (zeros in the padding),
-    shuffled pages, the absorbed query of every head against them."""
+    pages in shuffled pool order (no row's pages are neighbours), the
+    absorbed query of every head against them."""
     rng = np.random.default_rng(seed)
     nb = batch * max_blocks + 3
     width = kv_cache.latent_row_width(row)
@@ -459,17 +460,24 @@ def _latent_kernel(q, pool, table, pos, v_dim, scale, **kw):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("lengths, max_blocks, pages", [
-    ([1, 3, 4, 5, 23, 39], 10, 4),            # ragged, 3 groups
-    ([17, 3], 6, None),                       # one group holds the table
-], ids=["groups", "one_group"])
+@pytest.mark.parametrize("lengths, max_blocks, pages, bs", [
+    ([1, 3, 4, 5, 23, 39], 10, 4, 4),         # ragged, 3 groups
+    ([17, 3], 6, None, 4),                    # one group holds the table
+    ([23, 0, 39, 0, 0, 5], 10, 4, 4),         # idle rows (0) between live ones
+    ([511, 512, 513, 700], 44, 32, 16),       # a group's edges; 513: one page
+    ([1100, 40], 72, 32, 16),                 # a row longer than two groups
+], ids=["groups", "one_group", "idle_rows", "group_edges", "three_groups"])
 def test_latent_kernel_matches_gather(mla_interpreted, dtype, lengths,
-                                      max_blocks, pages):
-    case = _latent_case(13, len(lengths), max_blocks, dtype, lengths)
+                                      max_blocks, pages, bs):
+    case = _latent_case(13, len(lengths), max_blocks, dtype, lengths, bs=bs)
+    _, _, table, pos, _, _ = case
+    idle = np.asarray(lengths) == 0
+    table[idle], pos[idle] = -1, -1           # as the scheduler frees a slot
     got = _latent_kernel(*case, pages_per_group=pages)
     assert got.shape == (len(lengths), 1, 4, 32)
-    np.testing.assert_allclose(got, _latent_oracle(*case), atol=TOL[dtype],
-                               rtol=0)
+    assert not got[idle].any()                # exact zeros, never scratch
+    np.testing.assert_allclose(got[~idle], _latent_oracle(*case)[~idle],
+                               atol=TOL[dtype], rtol=0)
 
 
 def test_latent_kernel_idle_rows_are_finite_and_dead_pages_are_not_read(
@@ -480,13 +488,13 @@ def test_latent_kernel_idle_rows_are_finite_and_dead_pages_are_not_read(
     run = lambda p: _latent_kernel(q, p, table, pos, v_dim, scale,
                                    pages_per_group=2)
     got = run(pool)
-    assert np.isfinite(got).all()
-    # pages past a row's length are never copied: poison every block the
-    # live rows' live pages do not name and the result does not move
-    used = np.concatenate([table[0, :4], table[2, :3], [0]])
+    assert np.isfinite(got).all() and not got[1].any()
+    # an idle row reads nothing, and pages past a live row's length are
+    # never copied: poison every block the live rows' live pages do not
+    # name and the result does not move
+    used = np.concatenate([table[0, :4], table[2, :3]])
     poison = np.setdiff1d(np.arange(pool.shape[0]), used)
-    np.testing.assert_allclose(run(pool.at[poison].set(np.nan))[[0, 2]],
-                               got[[0, 2]], atol=0)
+    np.testing.assert_array_equal(run(pool.at[poison].set(np.nan)), got)
 
 
 @pytest.mark.parametrize("forced, s, dtype, want", [
